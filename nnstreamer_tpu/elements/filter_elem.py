@@ -180,7 +180,7 @@ class TensorFilter(Element):
                         "double-buffered (one collecting, one dispatched)"
                         ".  Deeper overlaps K dispatch round-trips — the "
                         "lever when dispatch latency, not device compute,"
-                        " bounds throughput (remote/tunneled chips); "
+                        " bounds throughput; "
                         "costs K batches of output HBM+latency"),
         "workers": (1, "parallel invoke workers: N>1 spawns a pool that "
                        "consumes frames concurrently (per-worker backend "
@@ -536,10 +536,11 @@ class TensorFilter(Element):
 
         def _mfu() -> float:
             flops, _ = estimate_jit_cost(fw)
-            peak, _ = device_peaks(fw._device)
-            if not flops or not peak:
-                return 0.0
-            return mfu_rate() * flops / peak
+            try:
+                peak, _ = device_peaks(fw._device)
+            except LookupError:
+                return 0.0   # a device with no known peak: no claim
+            return mfu_rate() * flops / peak if flops else 0.0
 
         def _bytes_per_s() -> float:
             _, nbytes = estimate_jit_cost(fw)

@@ -254,8 +254,14 @@ class JitExecMixin:
 
     @staticmethod
     def _pick_device(accelerators):
+        """The device this backend serves on — every jit-exec backend's
+        first touch of JAX at open, so the shared compile cache is
+        switched on here, before the model build's first compile."""
         import jax
 
+        from ...utils.platform import enable_compile_cache
+
+        enable_compile_cache()
         want = accelerators[0] if accelerators else Accelerator.AUTO
         if want is Accelerator.CPU:
             return jax.devices("cpu")[0]

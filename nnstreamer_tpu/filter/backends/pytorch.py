@@ -121,9 +121,7 @@ class PyTorchFilter(JitExecMixin, FilterFramework):
 
     def _open_xla(self, props: FilterProperties) -> None:
         from ..torchscript import lower_torchscript
-        from .xla import _enable_compilation_cache
 
-        _enable_compilation_cache()
         fn, ts_params = lower_torchscript(self._module,
                                           self._in_info.num_tensors)
         device = self._pick_device(props.accelerators)

@@ -221,9 +221,9 @@ def main(argv=None) -> int:
         _os.environ["NNS_FUSE"] = {"interpret": "0", "python": "1",
                                    "xla": "xla"}[args.fuse]
 
-    from .utils.platform import honor_jax_platforms
+    from .utils.platform import enable_compile_cache
 
-    honor_jax_platforms()
+    enable_compile_cache()
 
     from . import parse_launch
 

@@ -282,9 +282,11 @@ class TensorLLM(Element):
         from ..models.streamformer_lm import config_from_custom
         from ..obs.clock import mono_ns
         from ..parallel.train_step import init_params
+        from ..utils.platform import enable_compile_cache
         from .engine import DecodeEngine
         from .pool import KVCachePool
 
+        enable_compile_cache()
         custom = FilterProperties.parse_custom(self.custom)
         self.cfg = config_from_custom(custom)
         # for slots/batch/max_new_tokens, 0 and unset both clamp to 1:

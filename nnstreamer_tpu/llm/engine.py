@@ -183,7 +183,18 @@ class DecodeEngine:
                  clock=None, chunk: int = 0) -> None:
         import jax
 
-        self.params = params
+        # the weights live where the pool lives, placed ONCE: params
+        # built under models.registry.host_init and kept as given are
+        # host arrays that every prefill and decode dispatch would copy
+        # to the device again
+        device = next(iter(pool.k.devices()))
+        self.params = jax.device_put(params, device)
+        # ... and the fresh pool arrays are committed there too, like
+        # every later generation (a donated call's outputs are
+        # committed): jit keys its executables on commitment, so an
+        # uncommitted first generation would make the first shape
+        # warmed compile a second time on its first live dispatch
+        pool.k, pool.v = jax.device_put((pool.k, pool.v), device)
         self.cfg = cfg
         self.pool = pool
         self.capacity = max(1, int(capacity))

@@ -111,19 +111,9 @@ def build_mobilenet_v2(custom_props: Dict[str, str]) -> Model:
     variables = host_init(lambda: module.init(
         jax.random.PRNGKey(seed), jnp.zeros((1, size, size, 3), dtype)))
 
-    from ..utils.conf import parse_bool
-
-    use_pallas = parse_bool(custom_props.get("use_pallas", "0"))
-
     def forward(variables, frame):
-        """frame: uint8 (H, W, 3) — preprocessing fused into the graph
-        (optionally as a Pallas VMEM kernel, ``use_pallas:1``)."""
-        if use_pallas:
-            from ..ops.preprocess import normalize_frame
-
-            x = normalize_frame(frame, dtype=dtype)
-        else:
-            x = frame.astype(dtype) * (1.0 / 127.5) - 1.0
+        """frame: uint8 (H, W, 3) — preprocessing fused into the graph."""
+        x = frame.astype(dtype) * (1.0 / 127.5) - 1.0
         logits = module.apply(variables, x[None])
         return (logits[0],)
 

@@ -6,8 +6,10 @@ native/tensorwire/tensorwire.cc for the file-level mapping).  Every entry
 point has a numpy fallback so the framework works without the toolchain;
 ``available()`` reports which path is active.
 
-The library is built on demand (``make -C native``) the first time it's
-requested, then cached.
+The library is built on demand (``make -C native``, about a second) in
+the FOREGROUND the first time it is requested, then cached: a process
+runs either the native path or the fallback from its first frame to its
+last, never a mix that depends on when a build happened to finish.
 """
 
 from __future__ import annotations
@@ -27,58 +29,46 @@ _SO_PATH = os.path.join(_NATIVE_DIR, "libnnstw.so")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
-_building: Optional[threading.Thread] = None
 
 # dtype kind codes shared with tensorwire.cc
 _KIND = {"float32": 8, "float64": 9}
 
 
-def _build() -> bool:
+def _build() -> None:
     """Build to a process-unique name, then atomically rename into place:
     concurrent builders (pytest -n, parallel pipelines) each produce a
-    whole .so and the last rename wins — never a torn file."""
+    whole .so and the last rename wins — never a torn file.  A failed
+    build leaves whatever .so was there (or none) for the caller's load
+    to decide."""
     tmp = f"libnnstw.so.tmp.{os.getpid()}"
     try:
         subprocess.run(["make", "-C", _NATIVE_DIR, f"TARGET={tmp}"],
                        check=True, capture_output=True, timeout=120)
         os.replace(os.path.join(_NATIVE_DIR, tmp), _SO_PATH)
-        return True
     except (subprocess.SubprocessError, OSError):
         try:
             os.unlink(os.path.join(_NATIVE_DIR, tmp))
         except OSError:
             pass
-        return False
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    """Load libnnstw.so if present; if absent, kick off a BACKGROUND build
-    and serve the numpy fallback meanwhile (a first-use build must not
-    stall a streaming hot path)."""
-    global _lib, _tried, _building
+    """Load libnnstw.so, building it first when it is absent or older
+    than its sources; ``None`` (the numpy fallback, for the life of the
+    process) when it cannot be built or loaded."""
+    global _lib, _tried
     with _lock:
         if _lib is not None or _tried:
             return _lib
-        stale = False
-        if os.path.exists(_SO_PATH):
-            try:
-                so_m = os.path.getmtime(_SO_PATH)
-                src_dir = os.path.join(_NATIVE_DIR, "tensorwire")
-                stale = any(os.path.getmtime(os.path.join(src_dir, f)) > so_m
-                            for f in os.listdir(src_dir))
-            except OSError:
-                stale = False
-        if not os.path.exists(_SO_PATH) or stale:
-            if _building is None:
-                _building = threading.Thread(target=_build, daemon=True,
-                                             name="nnstw-build")
-                _building.start()
-            if _building.is_alive():
-                return None  # fallback while the compile runs
-            if not os.path.exists(_SO_PATH):
-                _tried = True  # build finished and failed
-                return None
-            # rebuild finished: fall through and load the fresh .so
+        src_dir = os.path.join(_NATIVE_DIR, "tensorwire")
+        try:
+            so_m = os.path.getmtime(_SO_PATH)
+            stale = any(os.path.getmtime(os.path.join(src_dir, f)) > so_m
+                        for f in os.listdir(src_dir))
+        except OSError:
+            stale = True   # no .so (or unreadable sources): try a build
+        if stale:
+            _build()
         _tried = True
         try:
             lib = ctypes.CDLL(_SO_PATH)
@@ -111,13 +101,8 @@ def _load() -> Optional[ctypes.CDLL]:
 
 
 def available() -> bool:
-    """Explicit probe: waits for an in-flight background build (hot-path
-    callers never come through here — they just get the fallback)."""
-    lib = _load()
-    if lib is None and _building is not None and _building.is_alive():
-        _building.join(timeout=120)
-        lib = _load()
-    return lib is not None
+    """Whether the native path is the active one (builds on first call)."""
+    return _load() is not None
 
 
 def _u8(arr: np.ndarray):

@@ -104,7 +104,6 @@ first scrape that wants it.
 
 from __future__ import annotations
 
-import os
 from bisect import bisect_left
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
@@ -124,37 +123,31 @@ STATE_PREFIX = "state:"
 SRC_PREFIX = "src:"
 
 # -- per-chip peaks (the single source bench.py imports) ---------------------
-#: bf16 peak FLOP/s per chip, keyed by device_kind substring; unknown
-#: TPU kinds assume v5e, non-TPU platforms make no MFU claim (0.0).
-PEAK_FLOPS: Dict[str, float] = {
-    "v5e": 197e12, "v5litepod": 197e12, "v5p": 459e12,
-    "v4": 275e12, "v6e": 918e12}
-#: HBM bandwidth (bytes/s) per chip
-PEAK_BW: Dict[str, float] = {
-    "v5e": 819e9, "v5litepod": 819e9, "v5p": 2765e9,
-    "v4": 1228e9, "v6e": 1640e9}
-
-
-def _peak_lookup(device, table: Dict[str, float]) -> float:
-    kind = (getattr(device, "device_kind", "") or "").lower()
-    kind = kind.replace(" ", "")
-    for key, peak in table.items():
-        if key in kind:
-            return peak
-    plat = getattr(device, "platform", "")
-    return table["v5e"] if plat == "tpu" else 0.0
+#: ``device_kind`` as JAX reports it -> (bf16 peak FLOP/s, HBM bytes/s)
+#: per chip.  Keyed by the exact string, read on the chip itself: a kind
+#: that is not here has no MFU or roofline until someone reads its name
+#: off the hardware and adds the row with the source of its figures.
+DEVICE_PEAKS: Dict[str, Tuple[float, float]] = {
+    # TPU v5e — the chip names itself "TPU v5 lite" (chip_smoke.py
+    # phase 0, PR 21).  Figures: Google Cloud documentation, "TPU v5e":
+    # 197 TFLOP/s bf16, 819 GB/s HBM per chip.
+    "TPU v5 lite": (197e12, 819e9),
+}
 
 
 def device_peaks(device) -> Tuple[float, float]:
-    """(peak FLOP/s, peak HBM bytes/s) for ``device`` — the bench.py
-    MFU denominators.  ``NNS_PEAK_FLOPS`` / ``NNS_PEAK_BW`` override
-    (e.g. to compute an *assumed-chip* MFU on a CPU-only host; the
-    override is an explicit assumption, surfaced by callers)."""
-    env_f = os.environ.get("NNS_PEAK_FLOPS")
-    env_b = os.environ.get("NNS_PEAK_BW")
-    flops = float(env_f) if env_f else _peak_lookup(device, PEAK_FLOPS)
-    bw = float(env_b) if env_b else _peak_lookup(device, PEAK_BW)
-    return flops, bw
+    """(peak FLOP/s, peak HBM bytes/s) for ``device`` — the MFU and
+    roofline denominators.  An unknown ``device_kind`` raises
+    ``LookupError``: whoever asks for a utilization must be on a chip
+    the table knows (live gauges catch it and make no claim)."""
+    kind = getattr(device, "device_kind", "") or ""
+    try:
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise LookupError(
+            f"no peak figures for device_kind {kind!r} (known: "
+            f"{sorted(DEVICE_PEAKS)}); a utilization needs a row in "
+            "obs/attrib.py DEVICE_PEAKS") from None
 
 
 def estimate_jit_cost(fw) -> Tuple[float, float]:

@@ -1,7 +1,5 @@
 """TPU ops: pallas kernels + jitted primitives for stream hot paths."""
 
 from .classify import top1, topk_indices
-from .preprocess import normalize_frame, normalize_frame_reference
 
-__all__ = ["normalize_frame", "normalize_frame_reference", "top1",
-           "topk_indices"]
+__all__ = ["top1", "topk_indices"]

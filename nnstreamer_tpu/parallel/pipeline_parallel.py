@@ -35,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import shard_map
 from .ring_attention import ring_attention
 from .train_step import StreamFormerConfig, _ln
 
@@ -203,7 +202,7 @@ def make_pp_train_step(mesh: Mesh, cfg: Optional[StreamFormerConfig] = None,
         return params, {"m": m, "v": v, "step": step}, loss
 
     data_spec = P("dp", "sp")
-    shard_step = shard_map(
+    shard_step = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(specs, opt_specs, data_spec, data_spec),
         out_specs=(specs, opt_specs, P()),

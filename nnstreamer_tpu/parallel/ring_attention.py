@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import jax
 
-from .compat import axis_size
 import jax.numpy as jnp
 
 
@@ -46,7 +45,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         from ..ops.flash_attention import flash_is_default
 
         flash = flash_is_default()
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     t_local, n_heads, head_dim = q.shape
     if flash:

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import shard_map
 from .ring_attention import ring_attention
 
 
@@ -270,7 +269,7 @@ def make_train_step(mesh: Mesh, cfg: Optional[StreamFormerConfig] = None,
             (jnp.sqrt(vv) + eps), params, m, v)
         return params, {"m": m, "v": v, "step": step}, loss
 
-    shard_step = shard_map(
+    shard_step = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(specs, opt_specs, P("dp", "sp"), P("dp", "sp")),
         out_specs=(specs, opt_specs, P()),

@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import jax
 
-from .compat import axis_size
 import jax.numpy as jnp
 
 from .ring_attention import local_attention
@@ -45,7 +44,7 @@ def ulysses_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     Returns: (T_local, n_heads, head_dim).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     t_local, n_heads, _ = q.shape
     if n_heads % n:
         raise ValueError(

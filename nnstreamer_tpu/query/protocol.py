@@ -167,21 +167,14 @@ _CRC_FN = None  # resolved once: callable | False (unavailable)
 
 def _crc_fn():
     """Native CRC-32C, resolved once so the per-message hot path is
-    lock-free afterwards.  While a background build of the native lib is
-    still running this returns None without caching, so CRC kicks in as
-    soon as the build lands."""
+    lock-free afterwards (None when the native lib is unavailable)."""
     global _CRC_FN
-    if _CRC_FN is not None:
-        return _CRC_FN or None
-    from .. import native
+    if _CRC_FN is None:
+        from .. import native
 
-    fn = native.crc32c_fn()   # closure over the loaded lib: no locks/frame
-    if fn is not None:
-        _CRC_FN = fn
-        return fn
-    if native._tried:   # definitively unavailable (build failed/absent)
-        _CRC_FN = False
-    return None
+        # closure over the loaded lib: no locks per frame
+        _CRC_FN = native.crc32c_fn() or False
+    return _CRC_FN or None
 
 
 def _payload_crc(payload: bytes) -> int:

@@ -86,5 +86,8 @@ FLASH_MIN_T_PROVENANCE = (
 FLASH_WIN_TABLE = ((2048,True),(8192,True),(16384,False),)
 
 FLASH_WIN_TABLE_PROVENANCE = (
-    "measured: BENCH_flash_r05.json \u2014 2048:1.365x, 8192:1.011x, 16384:0.795x, 32768:no-evidence; TPU v5 lite0; applied by flash_tpu_bench --apply-crossover"
+    "measured: BENCH_flash_r05.json (2026-08-01, before PR 1, another "
+    "JAX; record deleted in PR 21) \u2014 2048:1.365x, 8192:1.011x, "
+    "16384:0.795x, 32768:no-evidence; TPU v5 lite0; applied by "
+    "flash_tpu_bench --apply-crossover"
 )

@@ -393,10 +393,14 @@ class TestGauges:
             self, tiny_model, monkeypatch):
         """nns_mfu = frame_rate x flops / peak — the BENCH mfu_stream
         formula over the same peak table (bench.py imports it from
-        obs/attrib.py, so the two cannot drift)."""
+        obs/attrib.py, so the two cannot drift).  The table knows no
+        CPU, so the test lends it a row for this host's device."""
+        import jax
+
         from nnstreamer_tpu.obs.metrics import REGISTRY
 
-        monkeypatch.setenv("NNS_PEAK_FLOPS", "1e9")
+        monkeypatch.setitem(attrib.DEVICE_PEAKS,
+                            jax.devices()[0].device_kind, (1e9, 1e9))
         p = parse_launch(
             f"appsrc caps={CAPS4} name=in ! "
             "tensor_filter framework=xla model=tiny_attrib name=f ! "
@@ -424,29 +428,45 @@ class TestGauges:
                 mfu, rate, flops)
             assert any(k.startswith("nns_device_mem_bytes")
                        for k in report)
+            # a device the table does not know: the gauge makes no claim
+            monkeypatch.delitem(attrib.DEVICE_PEAKS,
+                                jax.devices()[0].device_kind)
+            assert [v for k, v in REGISTRY.report().items()
+                    if k.startswith("nns_mfu")] == [0.0]
         finally:
             p.stop()
         assert not any(k.startswith("nns_mfu")
                        for k in REGISTRY.report())
 
-    def test_device_peaks_env_override(self, monkeypatch):
-        class FakeDev:
+    def test_device_peaks_keyed_by_what_the_chip_says(self, monkeypatch):
+        class Dev:
             platform = "tpu"
-            device_kind = "TPU v5e"
+            device_kind = "TPU v5 lite"   # a v5e, in its own words
 
-        flops, bw = attrib.device_peaks(FakeDev())
-        assert flops == attrib.PEAK_FLOPS["v5e"]
+        assert attrib.device_peaks(Dev()) == (197e12, 819e9)
+        # the removed overrides are not read any more
         monkeypatch.setenv("NNS_PEAK_FLOPS", "42.0")
-        flops, _ = attrib.device_peaks(FakeDev())
-        assert flops == 42.0
+        monkeypatch.setenv("NNS_PEAK_BW", "42.0")
+        assert attrib.device_peaks(Dev()) == (197e12, 819e9)
 
-    def test_bench_imports_the_same_peak_tables(self):
+    def test_unknown_device_kind_is_an_error_not_a_default(self):
+        class Dev:
+            platform = "tpu"
+            device_kind = "TPU v5e"       # not what any chip answered
+
+        with pytest.raises(LookupError, match="TPU v5e"):
+            attrib.device_peaks(Dev())
+        import jax
+
+        with pytest.raises(LookupError):   # this host's CPU: no claim
+            attrib.device_peaks(jax.devices()[0])
+
+    def test_bench_uses_the_same_peak_lookup(self):
         sys.path.insert(0, os.path.dirname(TOOLS))
         try:
             import bench
 
-            assert bench.PEAK_FLOPS is attrib.PEAK_FLOPS
-            assert bench.PEAK_BW is attrib.PEAK_BW
+            assert bench.device_peaks is attrib.device_peaks
         finally:
             sys.path.remove(os.path.dirname(TOOLS))
 

@@ -3,7 +3,7 @@
 The ``batch`` property coalesces N frames into ONE device dispatch
 (double-buffered, so batch k's d2h overlaps batch k+1's collection) — the
 answer to per-frame dispatch RTT bounding streaming throughput on
-remote/tunneled devices.  The reference's hot loop is strictly
+accelerators.  The reference's hot loop is strictly
 one-buffer-one-invoke (tensor_filter.c:631-894); this is a TPU-native
 extension, so correctness parity is against the batch=1 path itself:
 identical outputs, order, timestamps, and EOS semantics.

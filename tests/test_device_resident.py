@@ -6,8 +6,8 @@ device-cache=N`` stages N rendered frames to the default jax device ONCE,
 then cycles the device handles; tensor_converter passes device payloads
 through untouched; the filter's micro-batch path stacks device inputs ON
 DEVICE (one tiny dispatch) instead of syncing to host and re-uploading.
-Net effect on a remote/tunneled device: zero h2d payload bytes per frame —
-throughput is bound by dispatch RTT and device compute, not link bandwidth.
+Net effect: zero h2d payload bytes per frame — throughput is bound by
+dispatch latency and device compute, not host-to-device bandwidth.
 
 All tests run on the CPU jax backend (conftest): a CPU jax.Array exercises
 the identical handle-passthrough/stacking code paths.

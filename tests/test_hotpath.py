@@ -47,9 +47,8 @@ HEADER_BUDGET_1T = protocol.HEADER.size + 4 + 128   # hdr + count + 1 meta
 # assert on the ratio; on a single-core host the contending threads (or
 # back-to-back timed loops under suite load) serialize and the ratio
 # measures scheduler interleaving, not the optimization.  A noise
-# measurement is neither a pass nor a fail — same honesty rule as
-# bench.py's infra_dead => vs_baseline: null — so these skip rather
-# than flake.  Cheap absolute-budget smokes (serialize/dispatch/admit)
+# measurement is neither a pass nor a fail, so these skip rather than
+# flake.  Cheap absolute-budget smokes (serialize/dispatch/admit)
 # stay on everywhere.
 _needs_cores = pytest.mark.skipif(
     (os.cpu_count() or 1) < 2,

@@ -487,12 +487,27 @@ class TestEngine:
     def test_bounded_executables_across_fills(self):
         """Sequences joining/leaving between steps never recompile:
         after warmup, every fill level hits a warm padded executable."""
+        import jax
+
+        from nnstreamer_tpu.models.registry import host_init
+
         cfg = _cfg()
-        params = init_params(cfg, 5)
+        # host-built weights, as tensor_llm builds them: the engine
+        # places them — and the pool — on the pool's device, committed
+        params = host_init(lambda: init_params(cfg, 5))
         pool = KVCachePool(cfg, 8)
         eng = DecodeEngine(params, cfg, pool, capacity=8)
+        device = next(iter(pool.k.devices()))
+        for leaf in jax.tree_util.tree_leaves(eng.params) + [pool.k,
+                                                             pool.v]:
+            assert leaf.committed and leaf.devices() == {device}
         eng.warmup()
         compiled = eng.compiles
+        built = []   # XLA's own count, not the engine's bookkeeping
+        jax.monitoring.register_event_duration_secs_listener(
+            lambda event, _secs, **kw: built.append(kw.get("fun_name"))
+            if event == "/jax/core/compile/backend_compile_duration"
+            else None)
         sessions = [pool.acquire(i) for i in range(5)]
         for s in sessions:
             s.max_new = 4
@@ -500,6 +515,7 @@ class TestEngine:
         for fill in (5, 3, 1, 4, 2):
             eng.step(sessions[:fill])
         assert eng.compiles == compiled
+        assert built == [], built
         assert eng.steps_total == 5
 
     def test_retry_after_hint_tracks_soonest_finisher(self):

@@ -5,9 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from nnstreamer_tpu.parallel.compat import shard_map
 from nnstreamer_tpu.parallel import (StreamFormerConfig, local_attention,
                                      make_mesh, make_train_step, mesh_info,
                                      ring_attention, make_data_sharding)
@@ -413,7 +413,7 @@ import jax
 jax.config.update("jax_platforms", "cpu")
 coord, nproc, pid = sys.argv[1], int(sys.argv[2]), int(sys.argv[3])
 from nnstreamer_tpu.parallel import multihost
-from nnstreamer_tpu.parallel.compat import shard_map
+from jax import shard_map
 multihost.initialize(coordinator=coord, num_processes=nproc,
                      process_id=pid)
 assert multihost.is_initialized()

@@ -996,16 +996,15 @@ class TestSoakSmoke:
 
 
 # ==========================================================================
-# shared infra-dead detector (tools/tunnel_probe.py diagnose_endpoint)
+# staged endpoint liveness check (tools/soak.py diagnose_endpoint)
 # ==========================================================================
 
 class TestEndpointDiagnosis:
     def test_live_query_server_all_stages_pass(self, loopback_server):
-        import tunnel_probe
+        import soak
 
         _, port = loopback_server
-        d = tunnel_probe.diagnose_endpoint("127.0.0.1", port,
-                                           timeout=5.0)
+        d = soak.diagnose_endpoint("127.0.0.1", port, timeout=5.0)
         assert d["ok"] and d["stage_failed"] is None
         for stage in ("dns", "connect", "rtt", "throughput"):
             assert d["stages"][stage]["ok"], d
@@ -1013,16 +1012,15 @@ class TestEndpointDiagnosis:
         assert d["stages"]["throughput"]["MBps"] > 0
 
     def test_connect_failure_with_retries(self):
-        import tunnel_probe
+        import soak
 
         sock = socket.socket()
         sock.bind(("127.0.0.1", 0))
         port = sock.getsockname()[1]
         sock.close()                    # nothing listens here now
         t0 = time.monotonic()
-        d = tunnel_probe.diagnose_endpoint("127.0.0.1", port,
-                                           timeout=0.5, retries=2,
-                                           backoff=0.05)
+        d = soak.diagnose_endpoint("127.0.0.1", port, timeout=0.5,
+                                   retries=2, backoff=0.05)
         assert not d["ok"]
         assert d["stage_failed"] == "connect"
         assert d["attempts"] == 3
@@ -1030,14 +1028,14 @@ class TestEndpointDiagnosis:
         assert time.monotonic() - t0 < 10
 
     def test_dns_failure(self):
-        import tunnel_probe
+        import soak
 
-        d = tunnel_probe.diagnose_endpoint(
+        d = soak.diagnose_endpoint(
             "no-such-host-xyz.invalid", 80, timeout=0.5)
         assert not d["ok"] and d["stage_failed"] == "dns"
 
     def test_tcp_but_not_query_server_fails_rtt(self):
-        import tunnel_probe
+        import soak
 
         lst = socket.socket()
         lst.bind(("127.0.0.1", 0))
@@ -1048,7 +1046,7 @@ class TestEndpointDiagnosis:
             daemon=True)
         th.start()
         try:
-            d = tunnel_probe.diagnose_endpoint(
+            d = soak.diagnose_endpoint(
                 "127.0.0.1", lst.getsockname()[1], timeout=0.5)
             assert not d["ok"] and d["stage_failed"] == "rtt"
             assert d["stages"]["connect"]["ok"]

@@ -5,8 +5,8 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
-from nnstreamer_tpu.parallel.compat import shard_map
 from nnstreamer_tpu.models.streamformer_lm import (decode_step,
                                                    forward_logits, generate,
                                                    init_cache)

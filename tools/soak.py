@@ -8,10 +8,10 @@ Composes the ``nnstreamer_tpu.slo`` harness end to end:
    serving pipeline built in-process (``tensor_query_serversrc !
    tensor_transform ! tensor_query_serversink``) with span recording
    enabled so the flight recorder has a timeline to dump.
-2. **Infra gate** — the shared infra-dead detector
-   (``tools/tunnel_probe.py diagnose_endpoint``): a dead target yields
-   a ``status: infra_dead`` verdict row (same taxonomy as bench.py) and
-   exit 2, never a FAIL that would read as a regression.
+2. **Infra gate** — a staged TCP liveness check of the target
+   (:func:`diagnose_endpoint`): a dead target yields a ``status:
+   infra_dead`` verdict row and exit 2, never a FAIL that would read as
+   a regression.
 3. **Chaos** — a ``testing/faults.py`` :class:`ChaosProxy` between the
    clients and the server, driven by a staged
    :class:`ChaosSchedule` (``--chaos "21:kill;36:disconnect_once"``).
@@ -53,11 +53,125 @@ import sys
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(_HERE))   # repo root: nnstreamer_tpu
-sys.path.insert(0, _HERE)                    # sibling tools (tunnel_probe)
+sys.path.insert(0, _HERE)                    # sibling tools
 
 DEMO_CAPS = ("other/tensors,format=static,num_tensors=1,dimensions=4,"
              "types=float32,framerate=0/1")
 DEMO_SERVER_ID = 91
+
+
+def _diagnose_once(host: str, port: int, timeout: float,
+                   stages: dict) -> "str | None":
+    """One staged pass over a TCP endpoint; fills ``stages`` and
+    returns the name of the FIRST failed stage (or None when healthy).
+    Stages name what broke:
+
+    - ``dns``        — name resolution
+    - ``connect``    — TCP dial
+    - ``rtt``        — T_PING/T_PONG round trips over the query
+      protocol (fails on a port that accepts but isn't a live
+      ``QueryServer`` — the half-up failure mode)
+    - ``throughput`` — one 256 KiB ping payload echo (the server echoes
+      ping payloads), a bulk-bytes sanity number
+    """
+    import socket
+    import time as _time
+
+    def _ms(t0):
+        return round((_time.monotonic() - t0) * 1e3, 2)
+
+    t0 = _time.monotonic()
+    try:
+        infos = socket.getaddrinfo(str(host), int(port),
+                                   type=socket.SOCK_STREAM)
+    except OSError as exc:
+        stages["dns"] = {"ok": False, "ms": _ms(t0),
+                         "error": f"{type(exc).__name__}: {exc}"[:200]}
+        return "dns"
+    stages["dns"] = {"ok": True, "ms": _ms(t0), "addrs": len(infos)}
+
+    t0 = _time.monotonic()
+    try:
+        sock = socket.create_connection((str(host), int(port)),
+                                        timeout=timeout)
+    except OSError as exc:
+        stages["connect"] = {"ok": False, "ms": _ms(t0),
+                             "error":
+                                 f"{type(exc).__name__}: {exc}"[:200]}
+        return "connect"
+    stages["connect"] = {"ok": True, "ms": _ms(t0)}
+
+    from nnstreamer_tpu.query.protocol import (Message, T_PING, T_PONG,
+                                               recv_msg, send_msg,
+                                               shutdown_close)
+
+    try:
+        sock.settimeout(timeout)
+
+        def _ping(payload: bytes, seq: int) -> float:
+            t = _time.monotonic()
+            send_msg(sock, Message(T_PING, seq=seq, payload=payload))
+            msg = recv_msg(sock)
+            if msg is None or msg.type != T_PONG or msg.seq != seq:
+                raise ConnectionError("no matching T_PONG "
+                                      "(not a live QueryServer?)")
+            return _time.monotonic() - t
+
+        t0 = _time.monotonic()
+        try:
+            rtts = [_ping(b"", seq) for seq in (1, 2, 3)]
+        except (OSError, ValueError, ConnectionError) as exc:
+            stages["rtt"] = {"ok": False, "ms": _ms(t0),
+                             "error":
+                                 f"{type(exc).__name__}: {exc}"[:200]}
+            return "rtt"
+        stages["rtt"] = {"ok": True,
+                         "rtt_ms_p50": round(sorted(rtts)[1] * 1e3, 2)}
+
+        blob = b"\x5a" * (256 << 10)
+        t0 = _time.monotonic()
+        try:
+            took = _ping(blob, 4)
+        except (OSError, ValueError, ConnectionError) as exc:
+            stages["throughput"] = {
+                "ok": False, "ms": _ms(t0),
+                "error": f"{type(exc).__name__}: {exc}"[:200]}
+            return "throughput"
+        stages["throughput"] = {
+            "ok": True,
+            "MBps": round(2 * len(blob) / (1 << 20) / max(took, 1e-9),
+                          2)}
+        return None
+    finally:
+        shutdown_close(sock)
+
+
+def diagnose_endpoint(host: str, port: int, timeout: float = 2.0,
+                      retries: int = 0, backoff: float = 1.0) -> dict:
+    """Structured liveness diagnosis of a ``QueryServer`` endpoint: the
+    returned dict names the exact stage that failed
+    (dns/connect/rtt/throughput) instead of a bare refused-connection
+    string.  ``retries``/``backoff`` retry the whole staged pass with
+    exponential spacing (a soak launched while a server restarts should
+    wait out the restart, not report it dead)."""
+    import time as _time
+
+    out = {"metric": "endpoint_diagnosis", "target": f"{host}:{port}",
+           "ok": False, "stage_failed": None, "attempts": 0,
+           "stages": {}}
+    for attempt in range(max(0, int(retries)) + 1):
+        out["attempts"] = attempt + 1
+        out["stages"] = {}
+        out["stage_failed"] = _diagnose_once(host, int(port),
+                                             float(timeout),
+                                             out["stages"])
+        if out["stage_failed"] is None:
+            out["ok"] = True
+            return out
+        if attempt <= retries - 1:
+            _time.sleep(min(30.0, float(backoff) * (2 ** attempt)))
+    return out
+
 
 
 def _register_delay_element():
@@ -284,17 +398,9 @@ def demo_rate_from_capacity(capacity_rps: float, clients: int) -> float:
 
 
 XBATCH_SERVER_ID = 92
-#: PROFILE_r08.json streaming baselines the --xbatch gate compares
-#: against: admission-wait share of per-frame streaming e2e, and the
-#: live nns_mfu gauge under assumed v5e peaks
+#: the PR 8 profile's streaming baseline the --xbatch gate compares
+#: against: admission-wait share of per-frame streaming e2e (a CPU host)
 R08_ADMISSION_WAIT_PCT = 82.55
-R08_STREAM_MFU = 5.58e-06
-#: assumed TPU v5e peaks (obs/attrib.py PEAK_FLOPS/PEAK_BW — the same
-#: table bench.py imports), asserted via env so nns_mfu computes the
-#: BENCH-comparable MFU on cpu-only hosts.  An explicit assumption,
-#: recorded in the verdict.
-V5E_PEAK_FLOPS = 197e12
-V5E_PEAK_BW = 819e9
 
 XBATCH_CAPS = ("other/tensors,format=static,num_tensors=1,dimensions=64,"
                "types=float32,framerate=0/1")
@@ -312,11 +418,6 @@ XBATCH_CAPS = ("other/tensors,format=static,num_tensors=1,dimensions=64,"
 #: bucket invoke (~145 ms) already busts the two-cycle latency path no
 #: matter the bucket size.
 XBATCH_MLP = "custom=in_dim:64,width:2048,depth:32,out_dim:16"
-#: FLOPs per frame of XBATCH_MLP (2 x MACs: 64x2048 in, 31x2048x2048
-#: hidden, 2048x16 out) — turns the >=10x-r08 nns_mfu acceptance floor
-#: into the request rate that clears it
-XBATCH_FLOPS_PER_FRAME = 2.0 * (64 * 2048 + 31 * 2048 * 2048
-                                + 2048 * 16)
 
 
 def mlp_server_line(port: int, batch: int = 0,
@@ -538,30 +639,23 @@ def run_xbatch(args, ap) -> int:
        capacity;
     4. gate: SLO PASS at that load (>=4x rps at held latency), the
        server-side attribution's admission-wait share reduced from the
-       PROFILE_r08 82.55 %, live ``nns_mfu`` (scraped mid-run over the
-       wire) >= 10x the r08 streaming gauge (same assumed v5e peaks),
-       buckets actually formed, and zero pending pool slabs server-side.
+       PR 8 profile's 82.55 %, buckets actually formed, and zero pending
+       pool slabs server-side.
 
     The verdict carries perf_diff-consumable ``rows`` (with the
     attribution block) so the regression gate can name the stage if the
     win ever erodes."""
-    import threading as _threading
     import time as _time
 
     import numpy as np
 
     from nnstreamer_tpu.slo import Evaluator, LoadGenerator, SLOMonitor, \
         load_spec
-    from tunnel_probe import diagnose_endpoint
 
     bucket = int(args.xbatch)
     if bucket < 2:
         ap.error("--xbatch BUCKET must be >= 2")
     os.makedirs(args.out, exist_ok=True)
-    # the r08-comparable MFU assumption (cpu-only hosts): assumed v5e
-    # peaks via env — inherited by the server subprocesses
-    os.environ.setdefault("NNS_PEAK_FLOPS", str(V5E_PEAK_FLOPS))
-    os.environ.setdefault("NNS_PEAK_BW", str(V5E_PEAK_BW))
     clients = args.clients or 64
     duration = args.duration
     probe_payload = np.random.default_rng(7).standard_normal(
@@ -657,22 +751,16 @@ def run_xbatch(args, ap) -> int:
                                        payload=probe_payload,
                                        concurrency=probe_conc)
 
-        # 3. the soak: offer the HIGHER of the two acceptance floors —
-        # 4x the per-frame server's held-latency goodput (4.4x for
-        # loadgen-jitter margin on the >=4.0 check), and the >=10x-r08
-        # nns_mfu floor, which IS a request rate (mfu = rps x
-        # flops/frame / peak; 1.15x headroom).  Cap at 85% of measured
-        # capacity: past the knee an open-loop soak measures queueing
-        # collapse, not the server.
-        peak = float(os.environ["NNS_PEAK_FLOPS"])
-        mfu_floor_rps = (10.0 * R08_STREAM_MFU * peak
-                         / XBATCH_FLOPS_PER_FRAME)
-        offered = max(4.4 * baseline_rps, 1.15 * mfu_floor_rps)
+        # 3. the soak: offer 4x the per-frame server's held-latency
+        # goodput (4.4x for loadgen-jitter margin on the >=4.0 check).
+        # Cap at 85% of measured capacity: past the knee an open-loop
+        # soak measures queueing collapse, not the server.
+        offered = 4.4 * baseline_rps
         if offered > 0.85 * capacity_xb:
             print(json.dumps({
                 "note": "offered rate capped at 85% of measured "
-                        "batching capacity; the 4x/mfu floors may not "
-                        "both be reachable on this host",
+                        "batching capacity; the 4x floor may not be "
+                        "reachable on this host",
                 "uncapped_rps": round(offered, 1),
                 "capacity_xbatch_rps": round(capacity_xb, 1)}),
                 flush=True)
@@ -685,32 +773,10 @@ def run_xbatch(args, ap) -> int:
             duration_s=duration, schedule=args.schedule, seed=args.seed,
             timeout=max(args.timeout, 5.0), payload=probe_payload)
 
-        # LIVE nns_mfu over the wire: each /metrics scrape advances the
-        # gauge's scrape-to-scrape frame window, so periodic mid-run
-        # scrapes ARE the live readings; report the median of the
-        # middle-of-run samples
-        mfu_samples = []
-        mfu_stop = _threading.Event()
-
-        def _mfu_sampler():
-            while not mfu_stop.wait(4.0):
-                val = xb.metric(xb.scrape(), "nns_mfu")
-                if val:
-                    mfu_samples.append(val)
-
-        sampler = _threading.Thread(target=_mfu_sampler, daemon=True,
-                                    name="mfu-sampler")
         monitor.start()
-        sampler.start()
         try:
             summary = gen.run()
         finally:
-            mfu_stop.set()
-            sampler.join(timeout=5)
-            mid = sorted(mfu_samples[len(mfu_samples) // 4:
-                                     max(1,
-                                         3 * len(mfu_samples) // 4 + 1)])
-            mfu = mid[len(mid) // 2] if mid else 0.0
             monitor.stop(final_tick=True)
         final = xb.scrape()
         batched = int(xb.metric(final, "nns_xbatch_batched_total"))
@@ -766,7 +832,7 @@ def run_xbatch(args, ap) -> int:
                     "offered rate on its own server instance (the "
                     "traced instance serves ~30% slower — observer "
                     "tax — so the full rate would saturate it); "
-                    "headline rps/latency/mfu come from the untraced "
+                    "headline rps/latency come from the untraced "
                     "soak (see PERFORMANCE.md)"}
     admission_pct = attribution.get("states", {}).get(
         "admission-wait", 0.0)
@@ -787,7 +853,6 @@ def run_xbatch(args, ap) -> int:
         "latency_held": bool(verdict["pass"]),
         "admission_wait_reduced":
             bool(attribution) and admission_pct < R08_ADMISSION_WAIT_PCT,
-        "mfu_10x_r08_stream": mfu >= 10.0 * R08_STREAM_MFU,
         "buckets_formed": batched > 0 and xb_frames > batched,
         "no_leaked_slabs": pool_pending == 0,
     }
@@ -805,11 +870,6 @@ def run_xbatch(args, ap) -> int:
                     "in-process demo's GIL contention suppressed the "
                     "very capacity under test); loadgen = PR 6 "
                     "open-loop soak, this process"},
-        "assumptions": {
-            "NNS_PEAK_FLOPS": float(os.environ["NNS_PEAK_FLOPS"]),
-            "NNS_PEAK_BW": float(os.environ["NNS_PEAK_BW"]),
-            "note": "assumed TPU v5e peaks, identical to PROFILE_r08 — "
-                    "the MFU ratio below compares like with like"},
         "xbatch": {
             "bucket": bucket,
             "batch_timeout_ms": args.xbatch_timeout_ms,
@@ -819,7 +879,6 @@ def run_xbatch(args, ap) -> int:
             "perframe_latency_us": pf_summary["latency_us"],
             "perframe_offered_frac": pf_frac,
             "baseline_rps": round(baseline_rps, 1),
-            "mfu_floor_rps": round(mfu_floor_rps, 1),
             "capacity_xbatch_rps": round(capacity_xb, 1),
             "capacity_speedup": round(capacity_xb
                                       / max(1e-9, capacity_pf), 2),
@@ -830,10 +889,6 @@ def run_xbatch(args, ap) -> int:
             "buckets": {"batched": batched, "solo": solo,
                         "frames": xb_frames,
                         "mean_fill": round(mean_fill, 2)},
-            "nns_mfu": mfu,
-            "mfu_samples": len(mfu_samples),
-            "mfu_r08_stream": R08_STREAM_MFU,
-            "mfu_ratio_vs_r08": round(mfu / R08_STREAM_MFU, 1),
             "admission_wait_pct": admission_pct,
             "admission_wait_r08_pct": R08_ADMISSION_WAIT_PCT,
             "pool_pending_slabs": pool_pending,
@@ -861,8 +916,6 @@ def run_xbatch(args, ap) -> int:
          "unit": "x_higher_better", "status": "live"},
         {"metric": "soak_xbatch_mean_fill", "value": round(mean_fill, 2),
          "unit": "frames_per_bucket", "status": "live"},
-        {"metric": "soak_xbatch_mfu", "value": mfu, "unit": "mfu_ratio",
-         "status": "live"},
     ]
     with open(os.path.join(args.out, "verdict.json"), "w",
               encoding="utf-8") as fh:
@@ -877,8 +930,6 @@ def run_xbatch(args, ap) -> int:
             "rps_vs_perframe_at_slo": round(
                 ok_rps / max(1e-9, baseline_rps), 2),
             "mean_fill": round(mean_fill, 2),
-            "nns_mfu": mfu,
-            "mfu_ratio_vs_r08": round(mfu / R08_STREAM_MFU, 1),
             "admission_wait_pct": admission_pct,
             "latency_us": summary["latency_us"],
             "errors": summary["errors"],
@@ -2275,8 +2326,8 @@ def main(argv=None) -> int:
                          "capacity, rebuild it with batch=BUCKET, soak "
                          "the batching server at >=4x the per-frame "
                          "capacity under the same SLO spec, and gate "
-                         "on rps/admission-wait/nns_mfu vs the "
-                         "PROFILE_r08 streaming baselines")
+                         "on rps and admission-wait vs the PR 8 "
+                         "per-frame streaming baseline")
     ap.add_argument("--federate", action="store_true",
                     help="telemetry-federation acceptance mode (demo "
                          "only): spawn a SECOND serving process "
@@ -2344,7 +2395,6 @@ def main(argv=None) -> int:
                                     LoadGenerator, SLOMonitor, load_spec)
     from nnstreamer_tpu.slo.spec import Objective, SLOSpec
     from nnstreamer_tpu.testing.faults import ChaosProxy, ChaosSchedule
-    from tunnel_probe import diagnose_endpoint
 
     if args.xbatch is not None:
         return run_xbatch(args, ap)
@@ -2381,9 +2431,8 @@ def main(argv=None) -> int:
         else:
             host, port = args.host, args.port
 
-        # shared infra-dead detector (satellite: one taxonomy with
-        # bench.py) — a dead target is status infra_dead, exit 2, and
-        # must never masquerade as an SLO FAIL
+        # a dead target is status infra_dead, exit 2, and must never
+        # masquerade as an SLO FAIL
         diagnosis = diagnose_endpoint(host, port,
                                       timeout=min(5.0, args.timeout * 2))
         if not diagnosis["ok"]:

@@ -66,18 +66,11 @@ def _bench(fw, x):
 
 
 def main() -> int:
-    from bench import _enable_compile_cache, emit_dead_row_if_gated
-
-    rc = emit_dead_row_if_gated("tflite_quant_native_tpu",
-                                "x_vs_emulation", {"ok": False})
-    if rc is not None:
-        return rc
     import jax
 
-    _enable_compile_cache()
+    from nnstreamer_tpu.utils.platform import enable_compile_cache
 
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
+    enable_compile_cache()
     dev = jax.devices()[0]
     result = {"metric": "tflite_quant_native_tpu", "unit": "x_vs_emulation",
               "device": str(dev)}
