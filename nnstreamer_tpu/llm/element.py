@@ -556,7 +556,10 @@ class TensorLLM(Element):
 
     def _decode_loop(self) -> None:
         try:
-            self._decode_loop_inner()
+            # the loop's phases go into the profiler's trace as spans
+            # of this thread, when a session records (PhaseClock)
+            with self.engine.phases.on_this_thread():
+                self._decode_loop_inner()
         except Exception as exc:  # noqa: BLE001 — surfaced as pipeline err
             if self.pipeline is not None:
                 self.pipeline.post_error(self, exc)
@@ -608,7 +611,10 @@ class TensorLLM(Element):
         eng, pool = self.engine, self.pool
         requeue: List[_Request] = []
         for req in reqs:
-            prev = eng.phases.enter("admit")
+            # waited_us: chain() → the one decode thread taking the
+            # request, the wait for the running step included
+            prev = eng.phases.enter(
+                "admit", waited_us=int((self._now() - req.born_s) * 1e6))
             try:
                 if req.prompt is None \
                         or len(req.prompt) + req.max_new \
@@ -616,6 +622,7 @@ class TensorLLM(Element):
                     # deterministic refusal (malformed / over-length):
                     # a retry can never succeed, so this is a terminal
                     # stop-token answer, not a shed
+                    eng.phases.note(outcome="reject")
                     self.rejected_total += 1
                     self._obs_counters["nns_llm_rejected_total"].inc()
                     if self._tok_obs is not None:
@@ -632,10 +639,13 @@ class TensorLLM(Element):
                             and self._now() - req.born_s \
                             < self._admit_timeout \
                             and not pool.admission.draining:
+                        eng.phases.note(outcome="requeue")
                         requeue.append(req)
                     else:
+                        eng.phases.note(outcome="shed")
                         self._shed(req, verdict)
                     continue
+                eng.phases.note(outcome="admit")
                 sess = pool.acquire(req.key, qos=req.qos,
                                     extra=req.extra, prompt=req.prompt,
                                     max_new=req.max_new)

@@ -26,6 +26,7 @@ inside.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -49,6 +50,11 @@ from .pool import KVCachePool, Session
 #: exactly the warmup, never growing after).
 PHASES = ("idle", "admit", "prefill", "llm-prefill-chunk", "decode",
           "egress", "compile")
+
+#: the name each state takes as a span in the JAX profiler's trace
+#: (``llm.idle`` … ``llm.prefill_chunk`` … ``llm.compile``)
+SPANS = {p: "llm." + p.replace("llm-", "").replace("-", "_")
+         for p in PHASES}
 
 
 def quantize_pages(n: int, table_max: int) -> int:
@@ -93,9 +99,21 @@ class PhaseClock:
     transitions stamp ``mono_ns`` once, accumulate the outgoing state's
     interval, and by construction the per-state sums partition the
     thread's total wall time — conservation is an identity, not a
-    measurement."""
+    measurement.
 
-    def __init__(self, clock_ns=None) -> None:
+    ``annotate=True`` (the engine's clock) also makes the partition
+    visible on the device's clock: inside :meth:`on_this_thread` every
+    transition closes the open ``jax.profiler.TraceAnnotation`` and
+    opens ``llm.<state>`` (:data:`SPANS`), so a profiler session that is
+    recording — the benchmark's traced slice, ``launch.py --jax-trace``
+    — holds the thread's phases as flat spans, never overlapping, one
+    open at any instant.  With no session an annotation records nothing.
+    A phase an inner phase interrupts (``admit`` around ``prefill`` and
+    ``egress``) shows as several pieces; arguments ride on the piece
+    ``enter(state, **kw)`` opens, a restore passes none.  A bare clock
+    never imports ``jax``."""
+
+    def __init__(self, clock_ns=None, annotate: bool = False) -> None:
         from ..obs.clock import mono_ns
 
         self._clock_ns = clock_ns if clock_ns is not None else mono_ns
@@ -103,16 +121,60 @@ class PhaseClock:
         self._state = "idle"
         self._t0 = self._clock_ns()
         self._born = self._t0
+        self._annotation = None
+        if annotate:
+            from jax.profiler import TraceAnnotation
 
-    def enter(self, state: str) -> str:
+            self._annotation = TraceAnnotation
+        #: the open annotation; None outside :meth:`on_this_thread`
+        self._span = None
+
+    def enter(self, state: str, **kw) -> str:
         """Transition; returns the OUTGOING state so nested phases
         (engine prefill/decode inside the element's admit/egress) can
-        restore their caller's state on exit."""
+        restore their caller's state on exit.  ``kw`` become the
+        arguments of the span this opens (an annotating clock only)."""
         now = self._clock_ns()
         self.ns[self._state] += now - self._t0
         prev, self._state = self._state, state
         self._t0 = now
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = self._annotation(SPANS[state], **kw)
+            self._span.__enter__()
         return prev
+
+    @contextlib.contextmanager
+    def on_this_thread(self):
+        """The clock's one thread runs its loop inside this: the span of
+        the current state opens here and the open one closes on the way
+        out.  Transitions made outside it (``warmup()`` on the thread
+        that starts the element) open nothing — an annotation closed on
+        another thread than it was opened on is lost."""
+        if self._annotation is not None:
+            self._span = self._annotation(SPANS[self._state])
+            self._span.__enter__()
+        try:
+            yield
+        finally:
+            if self._span is not None:
+                self._span.__exit__(None, None, None)
+                self._span = None
+
+    def note(self, **kw) -> None:
+        """Add arguments to the open span — what a phase learns after it
+        began (an admission's verdict).  Nothing on a clock that does
+        not annotate."""
+        if self._span is not None:
+            self._span.set_metadata(**kw)
+
+    def child(self, part: str):
+        """``llm.<state>.<part>``: an annotation to nest, with ``with``,
+        inside the open phase (nothing on a clock that does not
+        annotate)."""
+        if self._annotation is None:
+            return contextlib.nullcontext()
+        return self._annotation(f"{SPANS[self._state]}.{part}")
 
     def totals_ns(self) -> Dict[str, int]:
         """Integer per-state totals INCLUDING the in-progress state's
@@ -211,7 +273,7 @@ class DecodeEngine:
         self.chunk = max(0, int(chunk)) if self.paged else 0
         self._step_jit: Dict[Any, Any] = {}      # padded B[, W] -> exec
         self._prefill_jit: Dict[Any, Any] = {}   # padded T / (C, W)
-        self.phases = PhaseClock()
+        self.phases = PhaseClock(annotate=True)
         # live accounting the gauges read.  tokens_total counts every
         # GENERATED token (incl. each session's first, argmaxed from
         # the prefill logits); step_tokens only the decode-step ones —
@@ -245,6 +307,7 @@ class DecodeEngine:
             def _make():
                 from ..models.streamformer_lm import decode_step_pooled
 
+                @self._jax.named_scope("llm.engine.step")
                 def _step(params, k, v, tokens, pos, slots):
                     return decode_step_pooled(params, k, v, tokens,
                                               pos, slots, cfg)
@@ -274,6 +337,7 @@ class DecodeEngine:
             def _make():
                 from ..models.streamformer_lm import decode_step_paged
 
+                @self._jax.named_scope("llm.engine.pstep")
                 def _step(params, k, v, tokens, pos, tables):
                     return decode_step_paged(params, k, v, tokens, pos,
                                              tables, cfg, ps)
@@ -304,6 +368,7 @@ class DecodeEngine:
             def _make():
                 from ..models.streamformer_lm import prefill_chunk_paged
 
+                @self._jax.named_scope("llm.engine.chunk")
                 def _chunk(params, k, v, tokens, table, start, true_len,
                            scratch):
                     return prefill_chunk_paged(params, k, v, tokens,
@@ -332,6 +397,7 @@ class DecodeEngine:
             def _make():
                 from ..models.streamformer_lm import prefill_kv
 
+                @jax.named_scope("llm.engine.prefill")
                 def _prefill(params, k_pool, v_pool, tokens, slot,
                              true_len):
                     logits, ks, vs = prefill_kv(params, tokens, cfg,
@@ -341,10 +407,11 @@ class DecodeEngine:
                     # never reads (valid = arange <= pos), so one
                     # static-shape update serves every real length
                     # under this quantized bucket
-                    k_pool = jax.lax.dynamic_update_slice(
-                        k_pool, ks[None], (slot, 0, 0, 0, 0))
-                    v_pool = jax.lax.dynamic_update_slice(
-                        v_pool, vs[None], (slot, 0, 0, 0, 0))
+                    with jax.named_scope("sflm.kv_write"):
+                        k_pool = jax.lax.dynamic_update_slice(
+                            k_pool, ks[None], (slot, 0, 0, 0, 0))
+                        v_pool = jax.lax.dynamic_update_slice(
+                            v_pool, vs[None], (slot, 0, 0, 0, 0))
                     last = jax.lax.dynamic_index_in_dim(
                         logits, true_len - 1, axis=0, keepdims=False)
                     return last, k_pool, v_pool
@@ -516,16 +583,19 @@ class DecodeEngine:
             sess.pos = t
         else:
             padded = quantize_prompt(t, self.cfg.max_seq)
+            self.phases.note(padded=padded)
             buf = np.zeros((padded,), np.int32)
             buf[:t] = prompt
             fn = self._prefill_fn(padded)
             cold = self._enter_cold()
             try:
-                last, self.pool.k, self.pool.v = fn(
-                    self.params, self.pool.k, self.pool.v,
-                    jnp.asarray(buf), jnp.int32(sess.slot),
-                    jnp.int32(t))
-                logits = np.asarray(last)
+                with self.phases.child("dispatch"):
+                    last, self.pool.k, self.pool.v = fn(
+                        self.params, self.pool.k, self.pool.v,
+                        jnp.asarray(buf), jnp.int32(sess.slot),
+                        jnp.int32(t))
+                with self.phases.child("wait"):
+                    logits = np.asarray(last)
             finally:
                 if cold is not None:
                     self.phases.enter(cold)
@@ -607,10 +677,11 @@ class DecodeEngine:
         fn = self._chunk_fn(c_pad, w)
         cold = self._enter_cold()
         try:
-            last, pool.k, pool.v = fn(
-                self.params, pool.k, pool.v, jnp.asarray(toks),
-                jnp.asarray(table), jnp.int32(start),
-                jnp.int32(c_real), jnp.int32(pool.scratch))
+            with self.phases.child("dispatch"):
+                last, pool.k, pool.v = fn(
+                    self.params, pool.k, pool.v, jnp.asarray(toks),
+                    jnp.asarray(table), jnp.int32(start),
+                    jnp.int32(c_real), jnp.int32(pool.scratch))
         finally:
             if cold is not None:
                 self.phases.enter(cold)
@@ -622,7 +693,9 @@ class DecodeEngine:
         sess.pos = sess.plen
         self.prefills_total += 1
         self.tokens_total += 1
-        return int(np.argmax(np.asarray(last)))
+        with self.phases.child("wait"):
+            last = np.asarray(last)
+        return int(np.argmax(last))
 
     # -- decode ----------------------------------------------------------
     def _dispatch_paged(self, lanes):
@@ -635,23 +708,27 @@ class DecodeEngine:
         pool = self.pool
         ps = pool.page_size
         n = len(lanes)
-        padded = JitExecMixin.pad_rows(n, self.capacity)
-        w = quantize_pages(max(-(-(p + 1) // ps)
-                               for _, p, _ in lanes), pool.table_max)
-        toks = np.zeros((padded,), np.int32)
-        pos = np.zeros((padded,), np.int32)
-        tables = np.full((padded, w), pool.scratch, np.int32)
-        for i, (table, p, tok) in enumerate(lanes):
-            pos[i], toks[i] = p, tok
-            m = min(len(table), w)
-            tables[i, :m] = table[:m]
+        with self.phases.child("operands"):
+            padded = JitExecMixin.pad_rows(n, self.capacity)
+            w = quantize_pages(max(-(-(p + 1) // ps)
+                                   for _, p, _ in lanes), pool.table_max)
+            toks = np.zeros((padded,), np.int32)
+            pos = np.zeros((padded,), np.int32)
+            tables = np.full((padded, w), pool.scratch, np.int32)
+            for i, (table, p, tok) in enumerate(lanes):
+                pos[i], toks[i] = p, tok
+                m = min(len(table), w)
+                tables[i, :m] = table[:m]
+            toks, pos, tables = (jnp.asarray(toks), jnp.asarray(pos),
+                                 jnp.asarray(tables))
         fn = self._pstep_fn(padded, w)
         cold = self._enter_cold()
         try:
-            logits, pool.k, pool.v = fn(
-                self.params, pool.k, pool.v, jnp.asarray(toks),
-                jnp.asarray(pos), jnp.asarray(tables))
-            return np.asarray(logits)[:n]
+            with self.phases.child("dispatch"):
+                logits, pool.k, pool.v = fn(
+                    self.params, pool.k, pool.v, toks, pos, tables)
+            with self.phases.child("wait"):
+                return np.asarray(logits)[:n]
         finally:
             if cold is not None:
                 self.phases.enter(cold)
@@ -664,23 +741,26 @@ class DecodeEngine:
         import jax.numpy as jnp
 
         n = len(lanes)
-        padded = JitExecMixin.pad_rows(n, self.capacity)
-        slots = np.full((padded,), self.pool.scratch, np.int32)
-        pos = np.zeros((padded,), np.int32)
-        toks = np.zeros((padded,), np.int32)
-        for i, (slot, p, tok) in enumerate(lanes):
-            slots[i], pos[i], toks[i] = slot, p, tok
-        return (jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(slots),
-                padded, n)
+        with self.phases.child("operands"):
+            padded = JitExecMixin.pad_rows(n, self.capacity)
+            slots = np.full((padded,), self.pool.scratch, np.int32)
+            pos = np.zeros((padded,), np.int32)
+            toks = np.zeros((padded,), np.int32)
+            for i, (slot, p, tok) in enumerate(lanes):
+                slots[i], pos[i], toks[i] = slot, p, tok
+            return (jnp.asarray(toks), jnp.asarray(pos),
+                    jnp.asarray(slots), padded, n)
 
     def _dispatch(self, toks, pos, slots, padded: int, n: int):
         fn = self._step_fn(padded)
         cold = self._enter_cold()
         try:
-            logits, self.pool.k, self.pool.v = fn(
-                self.params, self.pool.k, self.pool.v, toks, pos,
-                slots)
-            return np.asarray(logits)[:n]
+            with self.phases.child("dispatch"):
+                logits, self.pool.k, self.pool.v = fn(
+                    self.params, self.pool.k, self.pool.v, toks, pos,
+                    slots)
+            with self.phases.child("wait"):
+                return np.asarray(logits)[:n]
         finally:
             if cold is not None:
                 self.phases.enter(cold)
@@ -694,7 +774,8 @@ class DecodeEngine:
         if not sessions:
             return []
         t0 = self._clock()
-        prev = self.phases.enter("decode")
+        prev = self.phases.enter("decode", step=self.steps_total,
+                                 lanes=len(sessions))
         if self.paged:
             for s in sessions:
                 self.pool.grow(s, s.pos + 1)   # lazy tail-page alloc
@@ -703,20 +784,22 @@ class DecodeEngine:
         else:
             lanes = [(s.slot, s.pos, s.next_token) for s in sessions]
             logits = self._dispatch(*self._lane_arrays(lanes))
-        out = np.argmax(logits, axis=1).astype(np.int32)
-        now = self._clock()
-        for s in sessions:
-            s.pos += 1
-            s.last_step_s = now
-        self.steps_total += 1
-        self.tokens_total += len(sessions)
-        self.step_tokens += len(sessions)
-        self.last_fill = len(sessions)
-        dt = now - t0
-        self.ewma_step_s = (dt if self.ewma_step_s == 0.0
-                            else 0.8 * self.ewma_step_s + 0.2 * dt)
+        with self.phases.child("sample"):
+            out = np.argmax(logits, axis=1).astype(np.int32)
+            now = self._clock()
+            for s in sessions:
+                s.pos += 1
+                s.last_step_s = now
+            self.steps_total += 1
+            self.tokens_total += len(sessions)
+            self.step_tokens += len(sessions)
+            self.last_fill = len(sessions)
+            dt = now - t0
+            self.ewma_step_s = (dt if self.ewma_step_s == 0.0
+                                else 0.8 * self.ewma_step_s + 0.2 * dt)
+            out = [int(t) for t in out]
         self.phases.enter(prev)
-        return [int(t) for t in out]
+        return out
 
     # -- hints / report --------------------------------------------------
     def retry_after_hint(self) -> float:

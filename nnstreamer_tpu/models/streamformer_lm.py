@@ -164,33 +164,43 @@ def prefill_kv(params: Dict[str, Any], tokens: jnp.ndarray,
         from ..ops.flash_attention import flash_wins
 
         flash = flash_wins(t)
-    pos = jnp.arange(t)
-    x = (params["embed"][tokens] + params["pos"][pos]).astype(cfg.dtype)
+    with jax.named_scope("sflm.embed"):
+        pos = jnp.arange(t)
+        x = (params["embed"][tokens]
+             + params["pos"][pos]).astype(cfg.dtype)
     ks, vs = [], []
     for lyr in params["layers"]:
-        y = _ln(x.astype(jnp.float32), lyr["ln1"]).astype(cfg.dtype)
-        qkv = jnp.einsum("td,dchn->tchn", y, lyr["wqkv"].astype(cfg.dtype))
-        q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
+        with jax.named_scope("sflm.qkv"):
+            y = _ln(x.astype(jnp.float32), lyr["ln1"]).astype(cfg.dtype)
+            qkv = jnp.einsum("td,dchn->tchn", y,
+                             lyr["wqkv"].astype(cfg.dtype))
+            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
         ks.append(k)
         vs.append(v)
-        if flash:
-            from ..ops.flash_attention import flash_attention
+        with jax.named_scope("sflm.attn"):
+            if flash:
+                from ..ops.flash_attention import flash_attention
 
-            attn = flash_attention(q, k, v, causal=True)
-        else:
-            from ..parallel.ring_attention import local_attention
+                attn = flash_attention(q, k, v, causal=True)
+            else:
+                from ..parallel.ring_attention import local_attention
 
-            attn = local_attention(q, k, v, causal=True)
-        o = jnp.einsum("qhd,hdn->qn", attn.astype(cfg.dtype),
-                       lyr["wo"].astype(cfg.dtype))
-        x = x + o
-        y = _ln(x.astype(jnp.float32), lyr["ln2"]).astype(cfg.dtype)
-        m = jnp.einsum("td,df->tf", y, lyr["w1"].astype(cfg.dtype))
-        m = jnp.einsum("tf,fd->td", jax.nn.gelu(m),
-                       lyr["w2"].astype(cfg.dtype))
-        x = x + m + _moe_dense(y, lyr, cfg)
-    x = _ln(x.astype(jnp.float32), params["ln_f"])
-    logits = jnp.einsum("td,dv->tv", x, params["head"])
+                attn = local_attention(q, k, v, causal=True)
+            o = jnp.einsum("qhd,hdn->qn", attn.astype(cfg.dtype),
+                           lyr["wo"].astype(cfg.dtype))
+            x = x + o
+        with jax.named_scope("sflm.mlp"):
+            y = _ln(x.astype(jnp.float32), lyr["ln2"]).astype(cfg.dtype)
+            m = jnp.einsum("td,df->tf", y, lyr["w1"].astype(cfg.dtype))
+            m = jnp.einsum("tf,fd->td", jax.nn.gelu(m),
+                           lyr["w2"].astype(cfg.dtype))
+        with jax.named_scope("sflm.moe"):
+            x = x + m + _moe_dense(y, lyr, cfg)
+    with jax.named_scope("sflm.head"):
+        x = _ln(x.astype(jnp.float32), params["ln_f"])
+        logits = jnp.einsum("td,dv->tv", x, params["head"])
+    # the cache write is the caller's (the engine's ``_prefill`` installs
+    # the run into its slot under ``sflm.kv_write``); nothing is read back
     return logits, jnp.stack(ks), jnp.stack(vs)
 
 
@@ -220,36 +230,45 @@ def decode_step_pooled(params: Dict[str, Any], k_pool: jnp.ndarray,
     shape is the point: B GEMV-shaped single-token steps become one
     GEMM-shaped step (the PR 9 padded-bucket economics, applied to the
     decode loop), and ONE executable per padded B serves every fill."""
-    x = (params["embed"][tokens] + params["pos"][pos]).astype(cfg.dtype)
+    with jax.named_scope("sflm.embed"):
+        x = (params["embed"][tokens]
+             + params["pos"][pos]).astype(cfg.dtype)
     valid = jnp.arange(cfg.max_seq)[None, :] <= pos[:, None]   # (B, T)
     scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
     for li, lyr in enumerate(params["layers"]):
-        y = _ln(x.astype(jnp.float32), lyr["ln1"]).astype(cfg.dtype)
-        qkv = jnp.einsum("bd,dchn->bchn", y,
-                         lyr["wqkv"].astype(cfg.dtype))
-        q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]          # (B, H, Dh)
+        with jax.named_scope("sflm.qkv"):
+            y = _ln(x.astype(jnp.float32), lyr["ln1"]).astype(cfg.dtype)
+            qkv = jnp.einsum("bd,dchn->bchn", y,
+                             lyr["wqkv"].astype(cfg.dtype))
+            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]      # (B, H, Dh)
         li_ix = jnp.full_like(slots, li)
-        k_pool = k_pool.at[slots, li_ix, pos].set(k)
-        v_pool = v_pool.at[slots, li_ix, pos].set(v)
-        kcur = k_pool[slots, li_ix]                 # (B, max_seq, H, Dh)
-        vcur = v_pool[slots, li_ix]
-        s = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32),
-                       kcur.astype(jnp.float32)) * scale
-        s = jnp.where(valid[:, None, :], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        attn = jnp.einsum("bht,bthd->bhd", p,
-                          vcur.astype(jnp.float32))
-        o = jnp.einsum("bhd,hdn->bn", attn.astype(cfg.dtype),
-                       lyr["wo"].astype(cfg.dtype))
-        x = x + o
-        y = _ln(x.astype(jnp.float32), lyr["ln2"]).astype(cfg.dtype)
-        m = jnp.einsum("bd,df->bf", y, lyr["w1"].astype(cfg.dtype))
-        m = jnp.einsum("bf,fd->bd", jax.nn.gelu(m),
-                       lyr["w2"].astype(cfg.dtype))
-        x = x + m + _moe_dense(y, lyr, cfg)
-    x = _ln(x.astype(jnp.float32), params["ln_f"])
-    return (jnp.einsum("bd,dv->bv", x, params["head"]),
-            k_pool, v_pool)
+        with jax.named_scope("sflm.kv_write"):
+            k_pool = k_pool.at[slots, li_ix, pos].set(k)
+            v_pool = v_pool.at[slots, li_ix, pos].set(v)
+        with jax.named_scope("sflm.kv_read"):
+            kcur = k_pool[slots, li_ix]             # (B, max_seq, H, Dh)
+            vcur = v_pool[slots, li_ix]
+        with jax.named_scope("sflm.attn"):
+            s = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32),
+                           kcur.astype(jnp.float32)) * scale
+            s = jnp.where(valid[:, None, :], s, -jnp.inf)
+            p = jax.nn.softmax(s, axis=-1)
+            attn = jnp.einsum("bht,bthd->bhd", p,
+                              vcur.astype(jnp.float32))
+            o = jnp.einsum("bhd,hdn->bn", attn.astype(cfg.dtype),
+                           lyr["wo"].astype(cfg.dtype))
+            x = x + o
+        with jax.named_scope("sflm.mlp"):
+            y = _ln(x.astype(jnp.float32), lyr["ln2"]).astype(cfg.dtype)
+            m = jnp.einsum("bd,df->bf", y, lyr["w1"].astype(cfg.dtype))
+            m = jnp.einsum("bf,fd->bd", jax.nn.gelu(m),
+                           lyr["w2"].astype(cfg.dtype))
+        with jax.named_scope("sflm.moe"):
+            x = x + m + _moe_dense(y, lyr, cfg)
+    with jax.named_scope("sflm.head"):
+        x = _ln(x.astype(jnp.float32), params["ln_f"])
+        logits = jnp.einsum("bd,dv->bv", x, params["head"])
+    return logits, k_pool, v_pool
 
 
 def decode_step_paged(params: Dict[str, Any], k_pages: jnp.ndarray,
@@ -286,7 +305,9 @@ def decode_step_paged(params: Dict[str, Any], k_pages: jnp.ndarray,
     ps = int(page_size)
     b, w = tables.shape
     span = w * ps
-    x = (params["embed"][tokens] + params["pos"][pos]).astype(cfg.dtype)
+    with jax.named_scope("sflm.embed"):
+        x = (params["embed"][tokens]
+             + params["pos"][pos]).astype(cfg.dtype)
     valid = jnp.arange(span)[None, :] <= pos[:, None]      # (B, W*ps)
     scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
     # tail-page coordinates for this step's scatter-append
@@ -294,34 +315,41 @@ def decode_step_paged(params: Dict[str, Any], k_pages: jnp.ndarray,
                                 axis=1)[:, 0]              # (B,)
     woff = pos % ps
     for li, lyr in enumerate(params["layers"]):
-        y = _ln(x.astype(jnp.float32), lyr["ln1"]).astype(cfg.dtype)
-        qkv = jnp.einsum("bd,dchn->bchn", y,
-                         lyr["wqkv"].astype(cfg.dtype))
-        q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]          # (B, H, Dh)
+        with jax.named_scope("sflm.qkv"):
+            y = _ln(x.astype(jnp.float32), lyr["ln1"]).astype(cfg.dtype)
+            qkv = jnp.einsum("bd,dchn->bchn", y,
+                             lyr["wqkv"].astype(cfg.dtype))
+            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]      # (B, H, Dh)
         li_ix = jnp.full_like(wpage, li)
-        k_pages = k_pages.at[wpage, li_ix, woff].set(k)
-        v_pages = v_pages.at[wpage, li_ix, woff].set(v)
-        kcur = k_pages[tables, li].reshape(
-            b, span, cfg.heads, cfg.head_dim)              # page gather
-        vcur = v_pages[tables, li].reshape(
-            b, span, cfg.heads, cfg.head_dim)
-        s = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32),
-                       kcur.astype(jnp.float32)) * scale
-        s = jnp.where(valid[:, None, :], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        attn = jnp.einsum("bht,bthd->bhd", p,
-                          vcur.astype(jnp.float32))
-        o = jnp.einsum("bhd,hdn->bn", attn.astype(cfg.dtype),
-                       lyr["wo"].astype(cfg.dtype))
-        x = x + o
-        y = _ln(x.astype(jnp.float32), lyr["ln2"]).astype(cfg.dtype)
-        m = jnp.einsum("bd,df->bf", y, lyr["w1"].astype(cfg.dtype))
-        m = jnp.einsum("bf,fd->bd", jax.nn.gelu(m),
-                       lyr["w2"].astype(cfg.dtype))
-        x = x + m + _moe_dense(y, lyr, cfg)
-    x = _ln(x.astype(jnp.float32), params["ln_f"])
-    return (jnp.einsum("bd,dv->bv", x, params["head"]),
-            k_pages, v_pages)
+        with jax.named_scope("sflm.kv_write"):
+            k_pages = k_pages.at[wpage, li_ix, woff].set(k)
+            v_pages = v_pages.at[wpage, li_ix, woff].set(v)
+        with jax.named_scope("sflm.kv_read"):
+            kcur = k_pages[tables, li].reshape(
+                b, span, cfg.heads, cfg.head_dim)          # page gather
+            vcur = v_pages[tables, li].reshape(
+                b, span, cfg.heads, cfg.head_dim)
+        with jax.named_scope("sflm.attn"):
+            s = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32),
+                           kcur.astype(jnp.float32)) * scale
+            s = jnp.where(valid[:, None, :], s, -jnp.inf)
+            p = jax.nn.softmax(s, axis=-1)
+            attn = jnp.einsum("bht,bthd->bhd", p,
+                              vcur.astype(jnp.float32))
+            o = jnp.einsum("bhd,hdn->bn", attn.astype(cfg.dtype),
+                           lyr["wo"].astype(cfg.dtype))
+            x = x + o
+        with jax.named_scope("sflm.mlp"):
+            y = _ln(x.astype(jnp.float32), lyr["ln2"]).astype(cfg.dtype)
+            m = jnp.einsum("bd,df->bf", y, lyr["w1"].astype(cfg.dtype))
+            m = jnp.einsum("bf,fd->bd", jax.nn.gelu(m),
+                           lyr["w2"].astype(cfg.dtype))
+        with jax.named_scope("sflm.moe"):
+            x = x + m + _moe_dense(y, lyr, cfg)
+    with jax.named_scope("sflm.head"):
+        x = _ln(x.astype(jnp.float32), params["ln_f"])
+        logits = jnp.einsum("bd,dv->bv", x, params["head"])
+    return logits, k_pages, v_pages
 
 
 def prefill_chunk_paged(params: Dict[str, Any], k_pages: jnp.ndarray,
@@ -361,42 +389,51 @@ def prefill_chunk_paged(params: Dict[str, Any], k_pages: jnp.ndarray,
     span = w * ps
     qpos = start + jnp.arange(c)                           # (C,) absolute
     qvalid = jnp.arange(c) < true_len
-    x = (params["embed"][tokens] + params["pos"][qpos]).astype(cfg.dtype)
+    with jax.named_scope("sflm.embed"):
+        x = (params["embed"][tokens]
+             + params["pos"][qpos]).astype(cfg.dtype)
     # key position t is visible to chunk query i iff t <= start + i
     kvalid = jnp.arange(span)[None, :] <= qpos[:, None]    # (C, W*ps)
     wpage = jnp.where(qvalid, table[qpos // ps], scratch)  # (C,)
     woff = qpos % ps
     scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
     for li, lyr in enumerate(params["layers"]):
-        y = _ln(x.astype(jnp.float32), lyr["ln1"]).astype(cfg.dtype)
-        qkv = jnp.einsum("td,dchn->tchn", y,
-                         lyr["wqkv"].astype(cfg.dtype))
-        q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]          # (C, H, Dh)
+        with jax.named_scope("sflm.qkv"):
+            y = _ln(x.astype(jnp.float32), lyr["ln1"]).astype(cfg.dtype)
+            qkv = jnp.einsum("td,dchn->tchn", y,
+                             lyr["wqkv"].astype(cfg.dtype))
+            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]      # (C, H, Dh)
         li_ix = jnp.full_like(wpage, li)
-        k_pages = k_pages.at[wpage, li_ix, woff].set(k)
-        v_pages = v_pages.at[wpage, li_ix, woff].set(v)
-        kcur = k_pages[table, li].reshape(
-            span, cfg.heads, cfg.head_dim)
-        vcur = v_pages[table, li].reshape(
-            span, cfg.heads, cfg.head_dim)
-        s = jnp.einsum("chd,thd->cht", q.astype(jnp.float32),
-                       kcur.astype(jnp.float32)) * scale
-        s = jnp.where(kvalid[:, None, :], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        attn = jnp.einsum("cht,thd->chd", p,
-                          vcur.astype(jnp.float32))
-        o = jnp.einsum("chd,hdn->cn", attn.astype(cfg.dtype),
-                       lyr["wo"].astype(cfg.dtype))
-        x = x + o
-        y = _ln(x.astype(jnp.float32), lyr["ln2"]).astype(cfg.dtype)
-        m = jnp.einsum("td,df->tf", y, lyr["w1"].astype(cfg.dtype))
-        m = jnp.einsum("tf,fd->td", jax.nn.gelu(m),
-                       lyr["w2"].astype(cfg.dtype))
-        x = x + m + _moe_dense(y, lyr, cfg)
-    x = _ln(x.astype(jnp.float32), params["ln_f"])
-    logits = jnp.einsum("td,dv->tv", x, params["head"])
-    last = jax.lax.dynamic_index_in_dim(logits, true_len - 1, axis=0,
-                                        keepdims=False)
+        with jax.named_scope("sflm.kv_write"):
+            k_pages = k_pages.at[wpage, li_ix, woff].set(k)
+            v_pages = v_pages.at[wpage, li_ix, woff].set(v)
+        with jax.named_scope("sflm.kv_read"):
+            kcur = k_pages[table, li].reshape(
+                span, cfg.heads, cfg.head_dim)
+            vcur = v_pages[table, li].reshape(
+                span, cfg.heads, cfg.head_dim)
+        with jax.named_scope("sflm.attn"):
+            s = jnp.einsum("chd,thd->cht", q.astype(jnp.float32),
+                           kcur.astype(jnp.float32)) * scale
+            s = jnp.where(kvalid[:, None, :], s, -jnp.inf)
+            p = jax.nn.softmax(s, axis=-1)
+            attn = jnp.einsum("cht,thd->chd", p,
+                              vcur.astype(jnp.float32))
+            o = jnp.einsum("chd,hdn->cn", attn.astype(cfg.dtype),
+                           lyr["wo"].astype(cfg.dtype))
+            x = x + o
+        with jax.named_scope("sflm.mlp"):
+            y = _ln(x.astype(jnp.float32), lyr["ln2"]).astype(cfg.dtype)
+            m = jnp.einsum("td,df->tf", y, lyr["w1"].astype(cfg.dtype))
+            m = jnp.einsum("tf,fd->td", jax.nn.gelu(m),
+                           lyr["w2"].astype(cfg.dtype))
+        with jax.named_scope("sflm.moe"):
+            x = x + m + _moe_dense(y, lyr, cfg)
+    with jax.named_scope("sflm.head"):
+        x = _ln(x.astype(jnp.float32), params["ln_f"])
+        logits = jnp.einsum("td,dv->tv", x, params["head"])
+        last = jax.lax.dynamic_index_in_dim(logits, true_len - 1,
+                                            axis=0, keepdims=False)
     return last, k_pages, v_pages
 
 

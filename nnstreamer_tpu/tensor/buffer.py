@@ -287,6 +287,12 @@ class TensorBufferPool:
     @property
     def stats(self) -> Dict[str, int]:
         with self._lock:
+            # report the present: a slab parked because a view was alive
+            # when its lease was reclaimed is pending only while that
+            # view lives (before, it stayed counted until the next
+            # acquire happened to sweep it)
+            self._drain_deferred_locked()
+            self._sweep_pending_locked()
             return {"hits": self.hits, "misses": self.misses,
                     "free": sum(len(b) for b in self._free.values()),
                     "free_bytes": self._free_bytes,

@@ -43,6 +43,13 @@ def enable_compile_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       MIN_CACHED_COMPILE_SECS)
+    # a profiler trace names a device operation from the metadata of the
+    # executable that ran (``jax.named_scope``: ``llm.engine.step``,
+    # ``sflm.attn``), and JAX leaves metadata out of the cache key by
+    # default: a cache filled before a scope was added or renamed would
+    # go on serving executables that carry the old names
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      True)
     return path
 
 
