@@ -230,8 +230,12 @@ class DecodeEngine:
     padded shape — sequences joining/leaving between steps change only
     the LANE COUNT, which quantizes onto the same warm set.
 
-    The pooled cache arrays are DONATED into the step and prefill
-    executables (``donate_argnums``): XLA updates the pool in place
+    The dense pool's arrays are ``(layers, slots + 1, max_seq, heads *
+    head_dim)`` (:func:`~nnstreamer_tpu.llm.pool.dense_pool_shape`, the
+    one place that shape is written): the step indexes ``[layer, slot,
+    pos]``, the dense prefill installs a prompt's ``(L, 1, T, H * Dh)``
+    run at ``(0, slot, 0, 0)``.  They are DONATED into the step and
+    prefill executables (``donate_argnums``): XLA updates the pool in place
     instead of materializing an input+output copy per step — without
     donation the per-step cost scales with POOL size (the whole cache
     copies to scatter one row per layer), which taxed a lone session by
@@ -402,16 +406,18 @@ class DecodeEngine:
                              true_len):
                     logits, ks, vs = prefill_kv(params, tokens, cfg,
                                                 flash=flash)
-                    # install the whole padded K/V run into the slot:
-                    # rows past true_len are garbage the decode mask
-                    # never reads (valid = arange <= pos), so one
-                    # static-shape update serves every real length
-                    # under this quantized bucket
+                    # install the whole padded K/V run into the slot,
+                    # as ``(L, 1, T, H * Dh)`` rows at ``(0, slot, 0,
+                    # 0)`` of the layer-major pool: rows past true_len
+                    # are garbage the decode mask never reads (valid =
+                    # arange <= pos), so one static-shape update serves
+                    # every real length under this quantized bucket
                     with jax.named_scope("sflm.kv_write"):
+                        run = (cfg.layers, 1, tokens.shape[0], -1)
                         k_pool = jax.lax.dynamic_update_slice(
-                            k_pool, ks[None], (slot, 0, 0, 0, 0))
+                            k_pool, ks.reshape(run), (0, slot, 0, 0))
                         v_pool = jax.lax.dynamic_update_slice(
-                            v_pool, vs[None], (slot, 0, 0, 0, 0))
+                            v_pool, vs.reshape(run), (0, slot, 0, 0))
                     last = jax.lax.dynamic_index_in_dim(
                         logits, true_len - 1, axis=0, keepdims=False)
                     return last, k_pool, v_pool

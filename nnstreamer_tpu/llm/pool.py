@@ -2,11 +2,11 @@
 LRU/deadline-evicted.
 
 A token-streaming session's device state is one STATIC-shape cache slot
-(``(layers, max_seq, heads, head_dim)`` per K and V — the
-``models/streamformer_lm.py`` decode contract), so the whole tier's
-cache memory is fixed at construction: ``(slots + 1) × layers ×
-max_seq × heads × head_dim × 2 × itemsize`` bytes, one scratch slot
-included for padding lanes.  There is NO per-session allocation on the
+(``max_seq`` rows of ``heads × head_dim`` per layer, for K and for V —
+the ``models/streamformer_lm.py`` pooled-decode contract,
+:func:`dense_pool_shape`), so the whole tier's cache memory is fixed at
+construction: ``layers × (slots + 1) × max_seq × heads × head_dim × 2 ×
+itemsize`` bytes, one scratch slot included for padding lanes.  There is NO per-session allocation on the
 admission path — a session either gets a pre-allocated slot or an
 explicit shed with a retry-after hint, never unbounded memory (the
 PR 7 overload doctrine applied to session state instead of queue
@@ -50,6 +50,22 @@ def slot_admission_controller(retry_after_s: float = 0.25
                                    retry_after_s=retry_after_s))
 
 
+def dense_pool_shape(cfg, slots: int) -> Tuple[int, int, int, int]:
+    """THE shape of each dense pool array (K and V alike), and the only
+    place it is written: ``(layers, slots + 1, max_seq, heads *
+    head_dim)``.  Layer-major, so a decode step takes its layer with a
+    static leading index (a slice, not a gather over every layer), and
+    lane-dense, so a row is ``heads * head_dim`` wide (1024 at GPT-2
+    medium's widths: whole 128-lane registers, where a minor dimension
+    of ``head_dim`` = 64 made the compiler convert the whole pool to a
+    padded layout and back around every gather).  Index ``slots`` of the
+    second dimension is the scratch slot.  Where ``heads * head_dim`` is
+    not a multiple of 128 the layout is as correct, only not
+    lane-dense."""
+    return (cfg.layers, int(slots) + 1, cfg.max_seq,
+            cfg.heads * cfg.head_dim)
+
+
 @dataclasses.dataclass
 class Session:
     """One live token stream resident in the pool."""
@@ -77,10 +93,11 @@ class Session:
 class KVCachePool:
     """Bounded slot pool + the pooled device cache arrays.
 
-    ``k``/``v`` are the ``(slots + 1, layers, max_seq, heads, head_dim)``
-    pooled cache (``models/streamformer_lm.decode_step_pooled``'s
-    operand); index ``slots`` is the SCRATCH slot padding lanes write
-    into, never handed to a session.  The pool owns slot bookkeeping —
+    ``k``/``v`` are the :func:`dense_pool_shape` pooled cache — ``(layers,
+    slots + 1, max_seq, heads * head_dim)`` in ``cfg.dtype``, ONE array
+    each (``models/streamformer_lm.decode_step_pooled``'s operand); slot
+    index ``slots`` is the SCRATCH slot padding lanes write into, never
+    handed to a session.  The pool owns slot bookkeeping —
     free list, live sessions by key, LRU order, occupancy — under one
     small lock; the decode engine reads/writes the arrays themselves
     from the single decode thread, so array access needs no lock.
@@ -101,8 +118,7 @@ class KVCachePool:
         self.admission = (admission if admission is not None
                           else slot_admission_controller())
         self._clock = clock if clock is not None else _time.monotonic
-        shape = (self.slots + 1, cfg.layers, cfg.max_seq, cfg.heads,
-                 cfg.head_dim)
+        shape = dense_pool_shape(cfg, self.slots)
         self.k = jnp.zeros(shape, cfg.dtype)
         self.v = jnp.zeros(shape, cfg.dtype)
         self._free: List[int] = list(range(self.slots))

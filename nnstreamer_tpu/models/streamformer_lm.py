@@ -17,6 +17,7 @@ full forward matches the training forward (shard_map on a 1-device mesh)
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, Tuple
 
 import jax
@@ -204,6 +205,28 @@ def prefill_kv(params: Dict[str, Any], tokens: jnp.ndarray,
     return logits, jnp.stack(ks), jnp.stack(vs)
 
 
+def _slot_rows(pool: jnp.ndarray, li: int, slots: jnp.ndarray
+               ) -> jnp.ndarray:
+    """``pool[li][slots]`` of a ``(L, S, max_seq, H * Dh)`` pool, read
+    as blocks of 256 positions through a flat ``(L * S * n, 256, H *
+    Dh)`` view of the whole pool (a reshape of leading dimensions: no
+    copy).  The values are the plain gather's; the TPU compiler makes
+    of this form ONE gather per call, which copies the ``B * n`` blocks
+    it names straight out of the pool, where of ``pool[li][slots]`` it
+    made a copy of the whole layer, a fusion cutting that into four
+    quarters of 256 positions, four gathers and a pad joining them:
+    45.2 -> 34.3 ms a step at 32 lanes, 16.2 -> 7.3 at 8, 10.3 -> 5.5
+    at one (PERF.md section 6, PR 26).  Where ``max_seq`` is no multiple
+    of 256 the blocks are the largest power of two that divides it."""
+    layers, s, t, row = pool.shape
+    blk = math.gcd(t, 256)
+    n = t // blk
+    first = (li * s + slots) * n                                # (B,)
+    blocks = pool.reshape(layers * s * n, blk, row)[
+        first[:, None] + jnp.arange(n)]                  # (B, n, blk, row)
+    return blocks.reshape(slots.shape[0], t, row)
+
+
 def decode_step_pooled(params: Dict[str, Any], k_pool: jnp.ndarray,
                        v_pool: jnp.ndarray, tokens: jnp.ndarray,
                        pos: jnp.ndarray, slots: jnp.ndarray,
@@ -213,9 +236,14 @@ def decode_step_pooled(params: Dict[str, Any], k_pool: jnp.ndarray,
     ``B`` resident sequences — each at its own position, each owning one
     cache slot — advance together through one batched invoke.
 
-    - ``k_pool``/``v_pool``: ``(S, L, max_seq, H, Dh)`` — the whole
-      session pool's cache, ``S`` static slots (the llm/ tier's bounded
-      memory: nothing here ever allocates per-sequence);
+    - ``k_pool``/``v_pool``: ``(L, S, max_seq, H * Dh)`` — the whole
+      session pool's cache (``llm/pool.dense_pool_shape``), ``S`` static
+      slots (the llm/ tier's bounded memory: nothing here ever allocates
+      per-sequence).  Layer-major and lane-dense: the layer is a
+      STATIC leading index, so a step's write and read
+      (:func:`_slot_rows`) touch its lanes' rows of one layer and never
+      walk the others, and a row is ``H * Dh`` wide, so the compiler
+      keeps the pool in its resting layout;
     - ``tokens``/``pos``/``slots``: ``(B,) int32`` — this step's token,
       position and cache-slot id per lane.  Padding lanes (partial
       buckets) point at a caller-reserved scratch slot, so their
@@ -223,7 +251,7 @@ def decode_step_pooled(params: Dict[str, Any], k_pool: jnp.ndarray,
     - returns ``(logits (B, vocab) f32, k_pool', v_pool')``.
 
     Same math as :func:`decode_step` (scatter the new K/V at
-    ``(slot, layer, pos)``, attend the single query against the slot's
+    ``(layer, slot, pos)``, attend the single query against the slot's
     prefix, positions beyond ``pos`` masked) — lane *i* of this step
     equals a solo :func:`decode_step` on slot *i*'s cache, which is the
     correctness spine the batched serving tier rests on.  The batched
@@ -235,19 +263,29 @@ def decode_step_pooled(params: Dict[str, Any], k_pool: jnp.ndarray,
              + params["pos"][pos]).astype(cfg.dtype)
     valid = jnp.arange(cfg.max_seq)[None, :] <= pos[:, None]   # (B, T)
     scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
+    b, row = tokens.shape[0], cfg.heads * cfg.head_dim
     for li, lyr in enumerate(params["layers"]):
         with jax.named_scope("sflm.qkv"):
             y = _ln(x.astype(jnp.float32), lyr["ln1"]).astype(cfg.dtype)
             qkv = jnp.einsum("bd,dchn->bchn", y,
                              lyr["wqkv"].astype(cfg.dtype))
             q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]      # (B, H, Dh)
-        li_ix = jnp.full_like(slots, li)
         with jax.named_scope("sflm.kv_write"):
-            k_pool = k_pool.at[slots, li_ix, pos].set(k)
-            v_pool = v_pool.at[slots, li_ix, pos].set(v)
+            k_pool = k_pool.at[li, slots, pos].set(k.reshape(b, row))
+            v_pool = v_pool.at[li, slots, pos].set(v.reshape(b, row))
         with jax.named_scope("sflm.kv_read"):
-            kcur = k_pool[slots, li_ix]             # (B, max_seq, H, Dh)
-            vcur = v_pool[slots, li_ix]
+            # the barrier ends the attention's say over layouts at the
+            # gathered copy: without it the compiler converts the
+            # blocks to float32 as they come and re-lays them out for
+            # the einsum in a pass of their own (47.7 ms a 32-lane
+            # step against 34.3; PERF.md section 6, PR 26)
+            kcur, vcur = jax.lax.optimization_barrier(
+                (_slot_rows(k_pool, li, slots),
+                 _slot_rows(v_pool, li, slots)))
+            kcur = kcur.reshape(                    # (B, max_seq, H, Dh)
+                b, cfg.max_seq, cfg.heads, cfg.head_dim)
+            vcur = vcur.reshape(
+                b, cfg.max_seq, cfg.heads, cfg.head_dim)
         with jax.named_scope("sflm.attn"):
             s = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32),
                            kcur.astype(jnp.float32)) * scale

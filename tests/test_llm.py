@@ -29,8 +29,9 @@ from nnstreamer_tpu.llm.client import (TokenStreamClient,
 from nnstreamer_tpu.llm.engine import (DecodeEngine, PhaseClock,
                                        quantize_pages, quantize_prompt)
 from nnstreamer_tpu.llm.paged import PagedKVCachePool, chain_hashes
-from nnstreamer_tpu.llm.pool import KVCachePool
-from nnstreamer_tpu.models.streamformer_lm import (config_from_custom,
+from nnstreamer_tpu.llm.pool import KVCachePool, dense_pool_shape
+from nnstreamer_tpu.models.streamformer_lm import (_slot_rows,
+                                                   config_from_custom,
                                                    decode_step,
                                                    decode_step_pooled,
                                                    forward_logits,
@@ -75,8 +76,7 @@ class TestPooledDecode:
         cache — the batched serving tier's correctness spine."""
         cfg = _cfg()
         params = init_params(cfg, 1)
-        S = 3
-        shape = (S + 1, cfg.layers, cfg.max_seq, cfg.heads, cfg.head_dim)
+        shape = dense_pool_shape(cfg, 3)
         kp = jnp.zeros(shape, cfg.dtype)
         vp = jnp.zeros(shape, cfg.dtype)
         toks = jnp.asarray([5, 17, 42], jnp.int32)
@@ -95,8 +95,7 @@ class TestPooledDecode:
         pad rows must never corrupt a resident session's cache."""
         cfg = _cfg()
         params = init_params(cfg, 2)
-        S = 2
-        shape = (S + 1, cfg.layers, cfg.max_seq, cfg.heads, cfg.head_dim)
+        shape = dense_pool_shape(cfg, 2)
         kp = jnp.ones(shape, cfg.dtype)
         vp = jnp.ones(shape, cfg.dtype)
         # lane 0 live (slot 0), lane 1 = padding pointed at scratch (2)
@@ -105,8 +104,8 @@ class TestPooledDecode:
             jnp.asarray([0, 0], jnp.int32),
             jnp.asarray([0, 2], jnp.int32), cfg)
         # slot 1 (untouched live slot) is bit-identical
-        np.testing.assert_array_equal(np.asarray(kp2[1]),
-                                      np.asarray(kp[1]))
+        np.testing.assert_array_equal(np.asarray(kp2[:, 1]),
+                                      np.asarray(kp[:, 1]))
 
     def test_teacher_forced_pooled_decode_matches_full_forward(self):
         """The consistency contract at the math layer: stepping a fixed
@@ -117,7 +116,7 @@ class TestPooledDecode:
         toks = np.random.default_rng(0).integers(0, 61, 14)
         full = np.asarray(forward_logits(
             params, jnp.asarray(toks, jnp.int32), cfg, flash=False))
-        shape = (2, cfg.layers, cfg.max_seq, cfg.heads, cfg.head_dim)
+        shape = dense_pool_shape(cfg, 1)
         kp = jnp.zeros(shape, cfg.dtype)
         vp = jnp.zeros(shape, cfg.dtype)
         for i, t in enumerate(toks):
@@ -148,6 +147,140 @@ class TestPooledDecode:
         np.testing.assert_allclose(np.asarray(vs),
                                    np.asarray(cache["v"][:, :11]),
                                    atol=1e-4, rtol=1e-4)
+
+    @pytest.mark.parametrize("max_seq", [48, 64, 256, 512, 1000])
+    def test_slot_rows_is_the_plain_gather(self, max_seq):
+        """The step's read (blocks of up to 256 positions through a
+        flat view of the pool) gives ``pool[layer][slots]`` to the bit:
+        one block a row, several, and a ``max_seq`` that 256 does not
+        divide; a slot named twice, the scratch slot, every layer."""
+        cfg = _cfg(layers=3, max_seq=max_seq)
+        shape = dense_pool_shape(cfg, 4)
+        pool = jnp.asarray(
+            np.random.default_rng(max_seq).standard_normal(shape),
+            jnp.bfloat16)
+        slots = jnp.asarray([3, 0, 4, 3, 1], jnp.int32)
+        for li in range(cfg.layers):
+            rows = _slot_rows(pool, li, slots)
+            assert rows.shape == (5,) + shape[2:]
+            np.testing.assert_array_equal(
+                np.asarray(rows.astype(jnp.float32)),
+                np.asarray(pool[li][slots].astype(jnp.float32)))
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_step_writes_exactly_its_rows(self, dtype):
+        """A step's scatter lands in the ``(layer, slot, pos)`` row of
+        each lane, for every layer, in the live slots and the scratch
+        one; every other byte of both pools is bit-identical, and the
+        rows hold what a solo decode_step on that slot would cache."""
+        cfg = _cfg(layers=3, dtype=dtype)
+        params = init_params(cfg, 6)
+        shape = dense_pool_shape(cfg, 3)
+        rng = np.random.default_rng(6)
+        kp = jnp.asarray(rng.standard_normal(shape), dtype)
+        vp = jnp.asarray(rng.standard_normal(shape), dtype)
+        toks, slots, pos = [5, 17, 0], [2, 0, 3], [5, 0, 7]  # 3 = scratch
+        _, kp2, vp2 = decode_step_pooled(
+            params, kp, vp, jnp.asarray(toks, jnp.int32),
+            jnp.asarray(pos, jnp.int32), jnp.asarray(slots, jnp.int32),
+            cfg)
+        assert kp2.shape == vp2.shape == shape and kp2.dtype == dtype
+        written = np.zeros(shape[:3], bool)
+        written[:, slots, pos] = True
+        for before, after in ((kp, kp2), (vp, vp2)):
+            before = np.asarray(before.astype(jnp.float32))
+            after = np.asarray(after.astype(jnp.float32))
+            np.testing.assert_array_equal(after[~written],
+                                          before[~written])
+            assert (after[written] != before[written]).any(axis=-1).all()
+        tol = 1e-4 if dtype == jnp.float32 else 5e-2
+        for tok, slot, p in zip(toks, slots, pos):
+            solo = {"k": kp[:, slot], "v": vp[:, slot]}
+            solo = {n: a.reshape(cfg.layers, cfg.max_seq, cfg.heads,
+                                 cfg.head_dim) for n, a in solo.items()}
+            _, cache = decode_step(params, dict(solo, pos=jnp.int32(p)),
+                                   jnp.int32(tok), cfg)
+            for name, pool in (("k", kp2), ("v", vp2)):
+                np.testing.assert_allclose(
+                    np.asarray(pool[:, slot, p].astype(jnp.float32)),
+                    np.asarray(cache[name][:, p].astype(jnp.float32)
+                               ).reshape(cfg.layers, -1),
+                    atol=tol, rtol=tol)
+
+    def test_engine_prefill_then_pooled_steps_match_decode_scan(self):
+        """test_prefill_kv_matches_decode_scan's contract through the
+        engine: ``_prefill`` installs the run into its slot's rows (and
+        no other slot's), and pooled steps from there on give the logits
+        a decode_step scan over prompt + continuation gives."""
+        cfg = _cfg(layers=3)
+        params = init_params(cfg, 4)
+        rng = np.random.default_rng(1)
+        prompt = rng.integers(0, 61, 11).astype(np.int32)
+        cont = rng.integers(0, 61, 5)
+        pool = KVCachePool(cfg, 3)
+        assert pool.k.shape == pool.v.shape == dense_pool_shape(cfg, 3)
+        eng = DecodeEngine(params, cfg, pool, capacity=2,
+                           prefill_mode="naive")
+        pool.acquire("other")
+        sess = pool.acquire("a")
+        first = eng.prefill(sess, prompt)
+        cache, scan = init_cache(cfg), []
+        for t in list(prompt) + list(cont):
+            logits, cache = decode_step(params, cache, jnp.int32(t), cfg)
+            scan.append(np.asarray(logits))
+        assert first == int(np.argmax(scan[10]))
+        for name, arr in (("k", pool.k), ("v", pool.v)):
+            arr = np.asarray(arr)
+            np.testing.assert_allclose(
+                arr[:, sess.slot, :11],
+                np.asarray(cache[name][:, :11]).reshape(cfg.layers, 11,
+                                                        -1),
+                atol=1e-4, rtol=1e-4)
+            others = np.delete(arr, sess.slot, axis=1)
+            assert not others.any()      # the install touched one slot
+        for i, t in enumerate(cont):
+            logits = eng._dispatch(*eng._lane_arrays(
+                [(sess.slot, 11 + i, int(t))]))
+            np.testing.assert_allclose(logits[0], scan[11 + i],
+                                       atol=1e-4, rtol=1e-4)
+
+    def test_pooled_logits_are_the_parents_to_the_bit(self):
+        """Same values in, same values out: a seeded 3-slot, 14-token
+        run's logits and both pools, against what the parent of the
+        layout change (PR 24's ``(S, L, T, H, Dh)`` pool, its pools
+        transposed to this layout) gave on the CPU."""
+        import hashlib
+
+        cfg = _cfg()
+        params = init_params(cfg, 26)
+        toks = np.random.default_rng(26).integers(0, 61, (14, 3))
+        shape = dense_pool_shape(cfg, 3)
+        kp, vp = jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
+        out = []
+        for i in range(14):
+            logits, kp, vp = decode_step_pooled(
+                params, kp, vp, jnp.asarray(toks[i], jnp.int32),
+                jnp.full((3,), i, jnp.int32),
+                jnp.asarray([2, 0, 1], jnp.int32), cfg)
+            out.append(np.asarray(logits))
+        out = np.stack(out)
+        assert out.argmax(-1).tolist() == PARENT_ARGMAX
+        digests = [hashlib.sha256(np.ascontiguousarray(a).tobytes())
+                   .hexdigest() for a in (out, kp, vp)]
+        assert digests == PARENT_SHA256
+
+
+#: recorded from commit 8ed891c (PR 24) before the pool's layout changed:
+#: argmax per (step, lane), and the SHA-256 of the (14, 3, 61) float32
+#: logits and of the final K and V pools
+PARENT_ARGMAX = [
+    [3, 57, 22], [52, 45, 56], [26, 47, 47], [19, 19, 49], [42, 42, 42],
+    [8, 42, 14], [59, 20, 23], [52, 56, 56], [0, 50, 60], [6, 20, 43],
+    [40, 60, 55], [38, 42, 34], [17, 58, 14], [17, 49, 21]]
+PARENT_SHA256 = [
+    "d248c3b00477bd4c507deb6ac567205c472d104cac86a761bb99ab482e1de6a4",
+    "d6ba610cee87fba2d3049c00fdaac9be40dbe51a415136071d59673115afdeb1",
+    "608f75c0d5e4d9a26fe72bd2b83915b55451e47500e4337aed84a3f3cbcfadd1"]
 
 
 class TestCustomGrammar:

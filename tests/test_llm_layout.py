@@ -1,0 +1,105 @@
+"""The compiler keeps the dense KV pool still: the ENGINE's own decode
+step and dense prefill, at ``sflm_gpt2m``'s widths, compiled for a
+DESCRIBED TPU v5e (no chip is attached here), update the donated
+``llm/pool.dense_pool_shape`` arrays in place — no conversion of the
+pool between layouts (``remat_compressed`` / ``remat_uncompressed``),
+and temporaries a fraction of the pool.  With the pool as ``(slots + 1,
+L, T, H, Dh)`` the 32-lane step held 188 such conversions and 10.0 GB of
+temporaries, three times the pool (PERF.md §6, PR 26).
+
+A compile that passes is not a chip run: it says what the compiler would
+do with the program, nothing about its speed.  The topology is described
+inside a fixture (only the worker that runs this file loads the TPU
+compiler), skipped where it cannot be.
+"""
+
+import json
+import os
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — no compiler here: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+
+
+@pytest.fixture(scope="module")
+def described(topo):
+    """The engine over ``sflm_gpt2m``'s configuration, and its operands
+    as shapes on one described chip.  The engine itself is built over a
+    one-slot pool and no weights: its jitted closures depend on ``cfg``
+    alone, the shapes they are lowered for are the cell's.  The
+    persistent cache is off (a described-topology executable cannot be
+    read back without a chip)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    from nnstreamer_tpu.llm.engine import DecodeEngine
+    from nnstreamer_tpu.llm.pool import KVCachePool, dense_pool_shape
+    from nnstreamer_tpu.models.streamformer_lm import config_from_custom
+    from nnstreamer_tpu.parallel.train_step import init_params
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "sflm_gpt2m.json")) as fh:
+        config = json.load(fh)
+    cfg = config_from_custom({k: str(v)
+                              for k, v in config["model"].items()})
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: on_chip(*x.shape, dtype=x.dtype),
+        jax.eval_shape(lambda: init_params(cfg, 0)))
+    pool = on_chip(*dense_pool_shape(cfg, config["element"]["slots"]),
+                   dtype=cfg.dtype)
+    engine = DecodeEngine({}, cfg, KVCachePool(cfg, 1), capacity=1)
+    yield {"engine": engine, "params": params, "pool": pool,
+           "pool_bytes": 2 * pool.size * pool.dtype.itemsize,
+           "on_chip": on_chip}
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _check(compiled, pool_bytes, temp_share):
+    text = compiled.as_text()
+    assert "remat_compressed" not in text
+    assert "remat_uncompressed" not in text
+    stats = compiled.memory_analysis()
+    # both pools are donated and updated in place ...
+    assert stats.alias_size_in_bytes >= pool_bytes
+    # ... and nothing of the pool's size is held beside them
+    assert stats.temp_size_in_bytes < pool_bytes * temp_share
+
+
+@pytest.mark.parametrize("lanes", [1, 8, 32])
+def test_decode_step_keeps_the_pool_still(described, lanes):
+    d = described
+    vec = d["on_chip"](lanes)
+    compiled = d["engine"]._step_fn(lanes).lower(
+        d["params"], d["pool"], d["pool"], vec, vec, vec).compile()
+    _check(compiled, d["pool_bytes"], 1 / 5)  # found: 0.04, 0.02, 0.23 GB
+
+
+@pytest.mark.parametrize("padded_t", [64, 1024])
+def test_dense_prefill_keeps_the_pool_still(described, padded_t):
+    d = described
+    compiled = d["engine"]._prefill_fn(padded_t).lower(
+        d["params"], d["pool"], d["pool"], d["on_chip"](padded_t),
+        d["on_chip"](), d["on_chip"]()).compile()
+    _check(compiled, d["pool_bytes"], 1 / 4)       # found: 0.04, 0.44 GB
