@@ -54,6 +54,15 @@ from nnstreamer_tpu.utils.platform import (device_label,  # noqa: E402
 LM_CUSTOM = ("vocab:8192,dim:512,heads:8,head_dim:64,mlp:2048,layers:4,"
              "experts:2,max_seq:2048,dtype:bfloat16")
 
+#: the second family (arch:sambay_lm: Mamba, window and full differential
+#: attention, memory units, cross-attention on one cache) at a small
+#: size, every kind of layer: a bring-up fault shows in seconds, not
+#: after the 7.7 GB warm-up of benchmarks/configs/phi4_mini_flash.json
+HYBRID_CUSTOM = ("arch:sambay_lm,vocab:8192,dim:512,heads:8,kv_heads:4,"
+                 "head_dim:64,mlp:2048,layers:8,window:128,d_state:16,"
+                 "d_conv:4,expand:2,dt_rank:32,max_seq:2048,"
+                 "dtype:bfloat16")
+
 #: flash kernel vs naive float32 attention, bf16 inputs: max abs error of
 #: the forward output (values are O(1)), and max error of a gradient
 #: relative to the oracle gradient's range
@@ -332,16 +341,16 @@ def _serve(port: int, jobs, frame_len: int):
     return [results[i] for i in range(len(jobs))]
 
 
-def _check_streams(params, cfg, jobs, streams) -> dict:
+def _check_streams(params, cfg, jobs, streams, forward_logits) -> dict:
     """Every stream is exactly its granted length of in-vocabulary
-    tokens, and — teacher-forced through ``forward_logits`` on the same
-    weights — at least LM_MIN_SHARE of the served tokens sit within
-    LM_TOKEN_SLACK of the top logit of their position."""
+    tokens, and — teacher-forced through the family's
+    ``forward_logits`` on the same weights — at least LM_MIN_SHARE of
+    the served tokens sit within LM_TOKEN_SLACK of the top logit of
+    their position."""
     import jax
     import jax.numpy as jnp
 
     from nnstreamer_tpu.llm.engine import quantize_prompt
-    from nnstreamer_tpu.models.streamformer_lm import forward_logits
 
     fwd = jax.jit(lambda p, t: forward_logits(p, t, cfg))
     exact = near = total = 0
@@ -405,12 +414,17 @@ def phase_llm(sid: int, custom: str = LM_CUSTOM, slots: int = 8,
     from nnstreamer_tpu import parse_launch
     from nnstreamer_tpu.filter.framework import FilterProperties
     from nnstreamer_tpu.llm.element import REQ_HEADER
-    from nnstreamer_tpu.models.streamformer_lm import config_from_custom
+    from nnstreamer_tpu.llm.family import FAMILIES, family_of_custom
     from nnstreamer_tpu.query.server import peek_server, shutdown_server
+
+    import importlib
 
     import jax.numpy as jnp
 
-    cfg = config_from_custom(FilterProperties.parse_custom(custom))
+    family, own = family_of_custom(FilterProperties.parse_custom(custom))
+    cfg = family.config_from_custom(own)
+    forward_logits = importlib.import_module(
+        FAMILIES[family.name]).forward_logits
     frame_len = REQ_HEADER + cfg.max_seq
     paged = f" page-size={page_size}" if page_size else ""
     t0 = time.monotonic()
@@ -427,12 +441,12 @@ def phase_llm(sid: int, custom: str = LM_CUSTOM, slots: int = 8,
         llm = p.get("llm")
         eng, pool = llm.engine, llm.pool
         assert _all_on_tpu(eng.params), "a parameter leaf is off-chip"
-        assert _on_tpu(pool.k) and _on_tpu(pool.v), "pool is off-chip"
+        assert all(_on_tpu(a) for a in pool.arrays), "pool is off-chip"
         out = {"ok": True, "setup_s": round(setup_s, 2),
                "warm_executables": eng.compiles}
         if mosaic_bucket:
             assert _mosaic(eng._prefill_jit[mosaic_bucket], eng.params,
-                           pool.k, pool.v,
+                           pool.arrays,
                            jnp.zeros((mosaic_bucket,), jnp.int32),
                            jnp.int32(0), jnp.int32(1)), (
                 f"{mosaic_bucket}-bucket prefill holds no Mosaic call")
@@ -474,7 +488,8 @@ def phase_llm(sid: int, custom: str = LM_CUSTOM, slots: int = 8,
         # terminal frame
         assert peek_server(sid).drain(10.0), "a stream has no terminal"
         out.update(sessions=llm.sessions_total, streams=streams,
-                   **_check_streams(eng.params, cfg, jobs, streams))
+                   **_check_streams(eng.params, cfg, jobs, streams,
+                                    forward_logits))
         if logits_t:
             out.update(_logits_vs_f32_cpu(eng.params, cfg, logits_t))
     finally:
@@ -534,6 +549,8 @@ def main() -> int:
     phases = {"kernels": phase_kernels(), "stream": phase_stream()}
     dense = phase_llm(4621, mosaic_bucket=2048, logits_t=256)
     paged = phase_llm(4622, page_size=16, shared_prefix=64)
+    hybrid = phase_llm(4623, custom=HYBRID_CUSTOM)
+    hybrid.pop("streams")
     # the two prefill paths round differently in bf16: reported, not
     # asserted
     pairs = [(a, b) for da, pa in zip(dense.pop("streams"),
@@ -541,7 +558,8 @@ def main() -> int:
              for a, b in zip(da, pa)]
     paged["tokens_equal_dense"] = (
         f"{sum(a == b for a, b in pairs)}/{len(pairs)}")
-    phases.update(llm_dense=dense, llm_paged=paged, mesh=phase_mesh())
+    phases.update(llm_dense=dense, llm_paged=paged, llm_hybrid=hybrid,
+                  mesh=phase_mesh())
     summary.update(
         phases=phases,
         setup_s_total=round(sum(ph.get("setup_s", 0.0)

@@ -85,12 +85,15 @@ class _Request:
 class TensorLLM(Element):
     FACTORY = "tensor_llm"
     PROPERTIES = {
-        "custom": (None, "streamformer_lm sizing grammar "
-                         "(models/streamformer_lm.config_from_custom): "
-                         "layers/width/heads/head_dim/mlp/vocab/"
-                         "experts/max_seq/dtype — max_seq MUST be "
-                         "named (it times slots is the cache memory "
-                         "bound)"),
+        "custom": (None, "the served family and its sizing grammar: "
+                         "arch:<family> (llm/family.py; default "
+                         "streamformer_lm, whose keys are layers/width/"
+                         "heads/head_dim/mlp/vocab/experts/max_seq/"
+                         "dtype — models/streamformer_lm"
+                         ".config_from_custom; arch:sambay_lm — "
+                         "models/sambay_lm.config_from_custom) — "
+                         "max_seq MUST be named (it times slots is the "
+                         "cache memory bound)"),
         "seed": (0, "deterministic weight seed"),
         "slots": (8, "KV-cache slots = max concurrently-resident "
                      "sessions; cache memory = (slots+1) x layers x "
@@ -183,7 +186,7 @@ class TensorLLM(Element):
     # -- verifier hook ---------------------------------------------------
     def static_check(self):
         from ..filter.framework import FilterProperties
-        from ..models.streamformer_lm import config_from_custom
+        from .family import family_of_custom
 
         out = []
 
@@ -221,6 +224,16 @@ class TensorLLM(Element):
         chunk = _num("prefill-chunk", -1)
         pfx = _num("prefix-cache", -1)
         custom = FilterProperties.parse_custom(self.custom)
+        family = None
+        try:
+            family, custom = family_of_custom(custom)
+        except ValueError as exc:
+            out.append(("error", "llm-unknown-arch",
+                        f"{self.name}: {exc}"))
+        if family is not None and not family.paged:
+            refused = self._unpaged_refusal(family, ps, chunk, pfx)
+            if refused:
+                out.append(("error", "llm-family-not-paged", refused))
         if ps < 0 or pages < 0:
             out.append(("error", "llm-page-size",
                         f"{self.name}: page-size={ps} / pages={pages} "
@@ -253,9 +266,9 @@ class TensorLLM(Element):
                         "x heads x head_dim x 2) would be an implicit "
                         "default; the serving tier must size its cache "
                         "explicitly"))
-        else:
+        elif family is not None:
             try:
-                config_from_custom(custom)
+                family.config_from_custom(custom)
             except (ValueError, TypeError) as exc:
                 out.append(("error", "misconfig",
                             f"{self.name}: custom= rejected: {exc}"))
@@ -275,20 +288,38 @@ class TensorLLM(Element):
                         "debugging"))
         return out
 
+    def _unpaged_refusal(self, family, ps: int, chunk: int,
+                         pfx: int) -> Optional[str]:
+        """Why a family that keeps more than pages of keys refuses
+        ``page-size`` / ``prefill-chunk`` / ``prefix-cache``; ``None``
+        where none of them is set."""
+        asked = [f"{k}={v}" for k, v, on in (
+            ("page-size", ps, ps > 0), ("prefill-chunk", chunk, chunk > 0),
+            ("prefix-cache", pfx, pfx == 1)) if on]
+        if not asked:
+            return None
+        return (f"{self.name}: arch:{family.name} cannot serve "
+                f"{' / '.join(asked)}: its sessions keep recurrent rows "
+                "and rings beside one layer's keys, which no page table "
+                "names and no page's content hash vouches for (prefix "
+                "reuse over recurrent state needs snapshots); it "
+                "prefills in fixed chunks of its own and serves from "
+                "the dense slot pool — drop the property")
+
     # -- lifecycle -------------------------------------------------------
     def start(self):
         from ..filter.framework import FilterProperties
-        from ..models.registry import host_init
-        from ..models.streamformer_lm import config_from_custom
         from ..obs.clock import mono_ns
-        from ..parallel.train_step import init_params
         from ..utils.platform import enable_compile_cache
         from .engine import DecodeEngine
+        from .family import family_of_custom
         from .pool import KVCachePool
 
         enable_compile_cache()
-        custom = FilterProperties.parse_custom(self.custom)
-        self.cfg = config_from_custom(custom)
+        family, custom = family_of_custom(
+            FilterProperties.parse_custom(self.custom))
+        self.family = family
+        self.cfg = family.config_from_custom(custom)
         # for slots/batch/max_new_tokens, 0 and unset both clamp to 1:
         # the `or` default loses nothing under max()
         # nnslint: allow(falsy-zero-default)
@@ -302,14 +333,17 @@ class TensorLLM(Element):
         self._sess_timeout = max(0.0,
                                  float(self.session_timeout_ms or 0)) / 1e3
         self._depth = int(self.queue_depth or 0) or 2 * self._slots
-        params = host_init(
-            lambda: init_params(self.cfg, int(self.seed or 0)))
         ps = max(0, int(self.page_size if self.page_size is not None
                         else 0))
         chunk = int(self.prefill_chunk
                     if self.prefill_chunk is not None else -1)
         pfx = int(self.prefix_cache
                   if self.prefix_cache is not None else -1)
+        if not family.paged:
+            refused = self._unpaged_refusal(family, ps, chunk, pfx)
+            if refused:
+                raise ValueError(refused)   # before any weight is drawn
+        params = family.init_params(self.cfg, int(self.seed or 0))
         if ps > 0:
             from .paged import PagedKVCachePool
 
@@ -323,13 +357,13 @@ class TensorLLM(Element):
             if str(self.prefill or "auto") == "step":
                 self._chunk = 0   # prompt rides the decode grid instead
         else:
-            self.pool = KVCachePool(self.cfg, self._slots)
+            self.pool = KVCachePool(self.cfg, self._slots, family=family)
             self._chunk = 0
         self.engine = DecodeEngine(params, self.cfg, self.pool,
                                    capacity=self._batch,
                                    prefill_mode=str(self.prefill
                                                     or "auto"),
-                                   chunk=self._chunk)
+                                   chunk=self._chunk, family=family)
         self.engine.warmup()
         self._mono_ns = mono_ns
         self._cv = make_condition("llm.engine")
@@ -388,6 +422,14 @@ class TensorLLM(Element):
              lambda: eng.last_fill / max(1, eng.capacity)),
             ("nns_llm_pending", lambda: len(self._pending)),
         )]
+        # the pool's bytes by kind of state (``kv`` alone for the
+        # default family; ``ring`` / ``conv`` / ``ssm`` beside it where
+        # a session keeps more than keys by position)
+        self._obs_gauges.extend(
+            REGISTRY.register(Gauge(
+                "nns_llm_state_bytes", dict(labels, kind=kind),
+                fn=lambda kind=kind: pool.bytes_by_kind()[kind]))
+            for kind in pool.bytes_by_kind())
         if getattr(eng, "paged", False):
             self._obs_gauges.extend(
                 REGISTRY.register(Gauge(n, dict(labels), fn=f))

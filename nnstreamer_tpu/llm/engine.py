@@ -230,9 +230,13 @@ class DecodeEngine:
     padded shape — sequences joining/leaving between steps change only
     the LANE COUNT, which quantizes onto the same warm set.
 
-    The dense pool's arrays are ``(layers, slots + 1, max_seq, heads *
-    head_dim)`` (:func:`~nnstreamer_tpu.llm.pool.dense_pool_shape`, the
-    one place that shape is written): the step indexes ``[layer, slot,
+    The engine imports no model: what it compiles are the functions of
+    the pool's FAMILY (``llm/family.py``), and what it passes them is
+    ``pool.arrays`` as ONE donated tuple, whatever the family's sessions
+    keep.  For the default ``streamformer_lm`` the dense pool's arrays
+    are ``(layers, slots + 1, max_seq, heads * head_dim)``
+    (:func:`~nnstreamer_tpu.llm.pool.dense_pool_shape`, the one place
+    that shape is written): the step indexes ``[layer, slot,
     pos]``, the dense prefill installs a prompt's ``(L, 1, T, H * Dh)``
     run at ``(0, slot, 0, 0)``.  They are DONATED into the step and
     prefill executables (``donate_argnums``): XLA updates the pool in place
@@ -240,27 +244,37 @@ class DecodeEngine:
     donation the per-step cost scales with POOL size (the whole cache
     copies to scatter one row per layer), which taxed a lone session by
     >50 % for merely sharing a big pool.  Every call site reassigns
-    ``pool.k``/``pool.v`` from the outputs (a donated input buffer is
-    dead).
+    ``pool.arrays`` from the outputs (a donated input buffer is
+    dead).  A family whose ``chunk_len`` is not 0 has its prompts
+    prefilled in chunks of that length through ONE ``_prefill``
+    executable (positions, not a power-of-two bucket a prompt), each
+    chunk taking the state the last one left in the slot.
     """
 
     def __init__(self, params, cfg, pool: KVCachePool,
                  capacity: int, prefill_mode: str = "auto",
-                 clock=None, chunk: int = 0) -> None:
+                 clock=None, chunk: int = 0, family=None) -> None:
         import jax
+
+        if family is None:
+            from .family import get_family
+
+            # the paged arena has no family of its own: the default's
+            family = getattr(pool, "family", None) or get_family()
+        self.family = family
 
         # the weights live where the pool lives, placed ONCE: params
         # built under models.registry.host_init and kept as given are
         # host arrays that every prefill and decode dispatch would copy
         # to the device again
-        device = next(iter(pool.k.devices()))
+        device = next(iter(pool.arrays[0].devices()))
         self.params = jax.device_put(params, device)
         # ... and the fresh pool arrays are committed there too, like
         # every later generation (a donated call's outputs are
         # committed): jit keys its executables on commitment, so an
         # uncommitted first generation would make the first shape
         # warmed compile a second time on its first live dispatch
-        pool.k, pool.v = jax.device_put((pool.k, pool.v), device)
+        pool.arrays = tuple(jax.device_put(tuple(pool.arrays), device))
         self.cfg = cfg
         self.pool = pool
         self.capacity = max(1, int(capacity))
@@ -275,6 +289,9 @@ class DecodeEngine:
         #: interleaved-prefill chunk size in tokens (paged only;
         #: 0 = whole remaining prompt in one chunk executable)
         self.chunk = max(0, int(chunk)) if self.paged else 0
+        #: dense prefill in fixed chunks of this many positions (the
+        #: family's; 0 = the whole prompt, padded to a power of two)
+        self.chunk_len = 0 if self.paged else int(family.chunk_len(cfg))
         self._step_jit: Dict[Any, Any] = {}      # padded B[, W] -> exec
         self._prefill_jit: Dict[Any, Any] = {}   # padded T / (C, W)
         self.phases = PhaseClock(annotate=True)
@@ -306,19 +323,17 @@ class DecodeEngine:
         if fn is None:
             compileledger.record("llm.engine.step",
                                  (("padded", padded),))
-            cfg = self.cfg
+            cfg, family = self.cfg, self.family
 
             def _make():
-                from ..models.streamformer_lm import decode_step_pooled
-
                 @self._jax.named_scope("llm.engine.step")
-                def _step(params, k, v, tokens, pos, slots):
-                    return decode_step_pooled(params, k, v, tokens,
+                def _step(params, state, tokens, pos, slots):
+                    return family.decode_step(params, state, tokens,
                                               pos, slots, cfg)
 
-                return self._jax.jit(_step, donate_argnums=(1, 2))
+                return self._jax.jit(_step, donate_argnums=(1,))
 
-            fn = _memo_jit(("step", _cfg_key(cfg)), _make)
+            fn = _memo_jit(("step", family.name, _cfg_key(cfg)), _make)
             self._step_jit[padded] = fn
             self.compiles += 1
             self._cold_exec = True
@@ -335,20 +350,19 @@ class DecodeEngine:
             compileledger.record("llm.engine.pstep",
                                  (("padded", padded),
                                   ("width", width)))
-            cfg = self.cfg
+            cfg, family = self.cfg, self.family
             ps = self.pool.page_size
 
             def _make():
-                from ..models.streamformer_lm import decode_step_paged
-
                 @self._jax.named_scope("llm.engine.pstep")
-                def _step(params, k, v, tokens, pos, tables):
-                    return decode_step_paged(params, k, v, tokens, pos,
-                                             tables, cfg, ps)
+                def _step(params, state, tokens, pos, tables):
+                    return family.decode_step_paged(
+                        params, state, tokens, pos, tables, cfg, ps)
 
-                return self._jax.jit(_step, donate_argnums=(1, 2))
+                return self._jax.jit(_step, donate_argnums=(1,))
 
-            fn = _memo_jit(("pstep", _cfg_key(cfg), ps), _make)
+            fn = _memo_jit(("pstep", family.name, _cfg_key(cfg), ps),
+                           _make)
             self._step_jit[key] = fn
             self.compiles += 1
             self._cold_exec = True
@@ -366,22 +380,21 @@ class DecodeEngine:
             compileledger.record("llm.engine.chunk",
                                  (("padded_c", padded_c),
                                   ("width", width)))
-            cfg = self.cfg
+            cfg, family = self.cfg, self.family
             ps = self.pool.page_size
 
             def _make():
-                from ..models.streamformer_lm import prefill_chunk_paged
-
                 @self._jax.named_scope("llm.engine.chunk")
-                def _chunk(params, k, v, tokens, table, start, true_len,
+                def _chunk(params, state, tokens, table, start, true_len,
                            scratch):
-                    return prefill_chunk_paged(params, k, v, tokens,
-                                               table, start, true_len,
-                                               cfg, ps, scratch)
+                    return family.prefill_chunk_paged(
+                        params, state, tokens, table, start, true_len,
+                        cfg, ps, scratch)
 
-                return self._jax.jit(_chunk, donate_argnums=(1, 2))
+                return self._jax.jit(_chunk, donate_argnums=(1,))
 
-            fn = _memo_jit(("chunk", _cfg_key(cfg), ps), _make)
+            fn = _memo_jit(("chunk", family.name, _cfg_key(cfg), ps),
+                           _make)
             self._prefill_jit[key] = fn
             self.compiles += 1
             self._cold_exec = True
@@ -393,38 +406,33 @@ class DecodeEngine:
         if fn is None:
             compileledger.record("llm.engine.prefill",
                                  (("padded_t", padded_t),))
-            cfg = self.cfg
+            cfg, family = self.cfg, self.family
             flash = {"auto": None, "flash": True,
                      "naive": False}[self.prefill_mode]
             jax = self._jax
+            chunked = self.chunk_len > 0
 
             def _make():
-                from ..models.streamformer_lm import prefill_kv
+                if chunked:
+                    @jax.named_scope("llm.engine.prefill")
+                    def _prefill(params, state, tokens, slot, start,
+                                 true_len, last):
+                        return family.prefill_chunk(
+                            params, state, tokens, slot, start, true_len,
+                            last, cfg)
+                else:
+                    # the family installs the whole padded run into the
+                    # slot (``sflm.kv_write``) and answers with the
+                    # logits of position ``true_len - 1``
+                    @jax.named_scope("llm.engine.prefill")
+                    def _prefill(params, state, tokens, slot, true_len):
+                        return family.prefill(params, state, tokens, slot,
+                                              true_len, cfg, flash)
 
-                @jax.named_scope("llm.engine.prefill")
-                def _prefill(params, k_pool, v_pool, tokens, slot,
-                             true_len):
-                    logits, ks, vs = prefill_kv(params, tokens, cfg,
-                                                flash=flash)
-                    # install the whole padded K/V run into the slot,
-                    # as ``(L, 1, T, H * Dh)`` rows at ``(0, slot, 0,
-                    # 0)`` of the layer-major pool: rows past true_len
-                    # are garbage the decode mask never reads (valid =
-                    # arange <= pos), so one static-shape update serves
-                    # every real length under this quantized bucket
-                    with jax.named_scope("sflm.kv_write"):
-                        run = (cfg.layers, 1, tokens.shape[0], -1)
-                        k_pool = jax.lax.dynamic_update_slice(
-                            k_pool, ks.reshape(run), (0, slot, 0, 0))
-                        v_pool = jax.lax.dynamic_update_slice(
-                            v_pool, vs.reshape(run), (0, slot, 0, 0))
-                    last = jax.lax.dynamic_index_in_dim(
-                        logits, true_len - 1, axis=0, keepdims=False)
-                    return last, k_pool, v_pool
+                return jax.jit(_prefill, donate_argnums=(1,))
 
-                return jax.jit(_prefill, donate_argnums=(1, 2))
-
-            fn = _memo_jit(("prefill", _cfg_key(cfg), flash), _make)
+            fn = _memo_jit(("prefill", family.name, _cfg_key(cfg), flash),
+                           _make)
             self._prefill_jit[padded_t] = fn
             self.compiles += 1
             self._cold_exec = True
@@ -476,11 +484,23 @@ class DecodeEngine:
             fn = self._step_fn(rows)
             # donated operands: the pool arrays MUST be reassigned from
             # the outputs (the inputs' buffers are dead after the call)
-            logits, self.pool.k, self.pool.v = fn(
-                self.params, self.pool.k, self.pool.v, toks, pos, slots)
+            logits, self.pool.arrays = fn(
+                self.params, self.pool.arrays, toks, pos, slots)
             self._jax.block_until_ready(logits)
         if self.prefill_mode == "step":
             return   # prompt decode rides the step executables above
+        if self.chunk_len > 0:
+            # ONE executable whatever the prompt's length, both ways
+            # through its ``last`` branch
+            fn = self._prefill_fn(self.chunk_len)
+            for last in (False, True):
+                logits, self.pool.arrays = fn(
+                    self.params, self.pool.arrays,
+                    jnp.zeros((self.chunk_len,), jnp.int32),
+                    jnp.int32(self.pool.scratch), jnp.int32(0),
+                    jnp.int32(1), jnp.bool_(last))
+                self._jax.block_until_ready(logits)
+            return
         lengths, t = [], 8
         while True:
             lengths.append(min(t, self.cfg.max_seq))
@@ -489,8 +509,8 @@ class DecodeEngine:
             t <<= 1
         for padded in sorted(set(lengths)):
             fn = self._prefill_fn(padded)
-            last, self.pool.k, self.pool.v = fn(
-                self.params, self.pool.k, self.pool.v,
+            last, self.pool.arrays = fn(
+                self.params, self.pool.arrays,
                 jnp.zeros((padded,), jnp.int32),
                 jnp.int32(self.pool.scratch), jnp.int32(1))
             self._jax.block_until_ready(last)
@@ -541,8 +561,8 @@ class DecodeEngine:
                 pos = jnp.zeros((rows,), jnp.int32)
                 tables = jnp.full((rows, w), pool.scratch, jnp.int32)
                 fn = self._pstep_fn(rows, w)
-                logits, pool.k, pool.v = fn(
-                    self.params, pool.k, pool.v, toks, pos, tables)
+                logits, pool.arrays = fn(
+                    self.params, pool.arrays, toks, pos, tables)
                 self._jax.block_until_ready(logits)
         if self.prefill_mode == "step":
             return   # prompt decode rides the paged step grid above
@@ -553,8 +573,8 @@ class DecodeEngine:
                 if w < min_w:
                     continue
                 fn = self._chunk_fn(c, w)
-                last, pool.k, pool.v = fn(
-                    self.params, pool.k, pool.v,
+                last, pool.arrays = fn(
+                    self.params, pool.arrays,
                     jnp.zeros((c,), jnp.int32),
                     jnp.full((w,), pool.scratch, jnp.int32),
                     jnp.int32(0), jnp.int32(1),
@@ -587,6 +607,9 @@ class DecodeEngine:
                                            int(prompt[i]))])
                 logits = self._dispatch(*rows)[0]
             sess.pos = t
+        elif self.chunk_len > 0:
+            logits = self._prefill_chunks(sess, prompt)
+            sess.pos = t
         else:
             padded = quantize_prompt(t, self.cfg.max_seq)
             self.phases.note(padded=padded)
@@ -596,8 +619,8 @@ class DecodeEngine:
             cold = self._enter_cold()
             try:
                 with self.phases.child("dispatch"):
-                    last, self.pool.k, self.pool.v = fn(
-                        self.params, self.pool.k, self.pool.v,
+                    last, self.pool.arrays = fn(
+                        self.params, self.pool.arrays,
                         jnp.asarray(buf), jnp.int32(sess.slot),
                         jnp.int32(t))
                 with self.phases.child("wait"):
@@ -611,6 +634,37 @@ class DecodeEngine:
         sess.last_step_s = self._clock()
         self.phases.enter(prev)
         return int(np.argmax(logits))
+
+    def _prefill_chunks(self, sess: Session, prompt: np.ndarray):
+        """The prompt through the family's one chunk executable: every
+        chunk is dispatched behind the last (each takes the state the
+        one before left in the slot, so the device runs them in order
+        while the host goes on), and only the last chunk's logits are
+        waited for."""
+        import jax.numpy as jnp
+
+        c, t = self.chunk_len, int(prompt.shape[0])
+        n = -(-t // c)
+        self.phases.note(padded=n * c, chunks=n)
+        buf = np.zeros((n * c,), np.int32)
+        buf[:t] = prompt
+        fn = self._prefill_fn(c)
+        cold = self._enter_cold()
+        try:
+            with self.phases.child("dispatch"):
+                slot = jnp.int32(sess.slot)
+                for i in range(n):
+                    last, self.pool.arrays = fn(
+                        self.params, self.pool.arrays,
+                        jnp.asarray(buf[i * c:(i + 1) * c]), slot,
+                        jnp.int32(i * c), jnp.int32(min(c, t - i * c)),
+                        jnp.bool_(i == n - 1))
+            self.prefill_chunks_total += n
+            with self.phases.child("wait"):
+                return np.asarray(last)
+        finally:
+            if cold is not None:
+                self.phases.enter(cold)
 
     # -- paged prefill ---------------------------------------------------
     def _prefill_paged(self, sess) -> int:
@@ -684,8 +738,8 @@ class DecodeEngine:
         cold = self._enter_cold()
         try:
             with self.phases.child("dispatch"):
-                last, pool.k, pool.v = fn(
-                    self.params, pool.k, pool.v, jnp.asarray(toks),
+                last, pool.arrays = fn(
+                    self.params, pool.arrays, jnp.asarray(toks),
                     jnp.asarray(table), jnp.int32(start),
                     jnp.int32(c_real), jnp.int32(pool.scratch))
         finally:
@@ -731,8 +785,8 @@ class DecodeEngine:
         cold = self._enter_cold()
         try:
             with self.phases.child("dispatch"):
-                logits, pool.k, pool.v = fn(
-                    self.params, pool.k, pool.v, toks, pos, tables)
+                logits, pool.arrays = fn(
+                    self.params, pool.arrays, toks, pos, tables)
             with self.phases.child("wait"):
                 return np.asarray(logits)[:n]
         finally:
@@ -762,9 +816,8 @@ class DecodeEngine:
         cold = self._enter_cold()
         try:
             with self.phases.child("dispatch"):
-                logits, self.pool.k, self.pool.v = fn(
-                    self.params, self.pool.k, self.pool.v, toks, pos,
-                    slots)
+                logits, self.pool.arrays = fn(
+                    self.params, self.pool.arrays, toks, pos, slots)
             with self.phases.child("wait"):
                 return np.asarray(logits)[:n]
         finally:
@@ -831,9 +884,11 @@ class DecodeEngine:
             "ewma_step_ms": round(self.ewma_step_s * 1e3, 3),
             "compiles": self.compiles,
             "cache_bytes": self.pool.cache_bytes(),
+            "cache_bytes_by_kind": self.pool.bytes_by_kind(),
             "phases": phases,
         }
-        if self.paged:
+        if self.paged or self.chunk_len > 0:
             out["prefill_chunks"] = self.prefill_chunks_total
+        if self.paged:
             out["paged"] = self.pool.stats()
         return out

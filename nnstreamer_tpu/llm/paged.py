@@ -156,6 +156,19 @@ class PagedKVCachePool:
         the same ``slots`` — the apples-to-apples residency claim."""
         return int(self.k.nbytes) + int(self.v.nbytes)
 
+    def bytes_by_kind(self) -> Dict[str, int]:
+        return {"kv": self.cache_bytes()}
+
+    @property
+    def arrays(self):
+        """The arena as the engine passes it to the family's paged
+        functions (the dense pool's ``arrays``, for this pool)."""
+        return self.k, self.v
+
+    @arrays.setter
+    def arrays(self, value) -> None:
+        self.k, self.v = value
+
     @property
     def live(self) -> int:
         with self._lock:

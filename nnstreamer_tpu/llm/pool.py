@@ -12,6 +12,13 @@ explicit shed with a retry-after hint, never unbounded memory (the
 PR 7 overload doctrine applied to session state instead of queue
 depth).
 
+WHAT a slot holds is the served family's affair (``llm/family.py``):
+:class:`SlotPool` is the bookkeeping alone, and :class:`KVCachePool`
+lays the family's ``init_state`` arrays beside it — keys and values by
+position for every layer (``streamformer_lm``), or one layer's rows, a
+ring per windowed layer and fixed recurrent rows side by side
+(``sambay_lm``).
+
 Slot admission composes the existing
 :class:`~nnstreamer_tpu.query.overload.AdmissionController`: a
 watermark policy over SLOT occupancy sheds bronze sessions before the
@@ -90,47 +97,28 @@ class Session:
     obs: Any = None
 
 
-class KVCachePool:
-    """Bounded slot pool + the pooled device cache arrays.
+class SlotPool:
+    """Slot bookkeeping of a dense pool, whatever a slot holds: free
+    list, live sessions by key, LRU order, occupancy, admission — under
+    one small lock.  Slot index ``slots`` is the SCRATCH slot padding
+    lanes write into, never handed to a session."""
 
-    ``k``/``v`` are the :func:`dense_pool_shape` pooled cache — ``(layers,
-    slots + 1, max_seq, heads * head_dim)`` in ``cfg.dtype``, ONE array
-    each (``models/streamformer_lm.decode_step_pooled``'s operand); slot
-    index ``slots`` is the SCRATCH slot padding lanes write into, never
-    handed to a session.  The pool owns slot bookkeeping —
-    free list, live sessions by key, LRU order, occupancy — under one
-    small lock; the decode engine reads/writes the arrays themselves
-    from the single decode thread, so array access needs no lock.
-    """
-
-    def __init__(self, cfg, slots: int,
+    def __init__(self, slots: int,
                  admission: Optional[AdmissionController] = None,
                  clock=None) -> None:
         import time as _time
 
-        import jax.numpy as jnp
-
         if int(slots) < 1:
             raise ValueError(f"KVCachePool needs >= 1 slot (got {slots})")
-        self.cfg = cfg
         self.slots = int(slots)
         self.scratch = self.slots          # padding lanes' slot id
         self.admission = (admission if admission is not None
                           else slot_admission_controller())
         self._clock = clock if clock is not None else _time.monotonic
-        shape = dense_pool_shape(cfg, self.slots)
-        self.k = jnp.zeros(shape, cfg.dtype)
-        self.v = jnp.zeros(shape, cfg.dtype)
         self._free: List[int] = list(range(self.slots))
         self._live: Dict[Any, Session] = {}
         self._order = 0
         self._lock = make_lock("llm.pool")
-
-    # -- sizing ----------------------------------------------------------
-    def cache_bytes(self) -> int:
-        """Device bytes the pooled cache occupies — CONSTANT for the
-        pool's life (the bounded-memory evidence the soak gates on)."""
-        return int(self.k.nbytes) + int(self.v.nbytes)
 
     @property
     def live(self) -> int:
@@ -234,3 +222,53 @@ class KVCachePool:
         with self._lock:
             return [s.key for s in self._live.values()
                     if s.born_s < cutoff]
+
+
+class KVCachePool(SlotPool):
+    """Bounded slot pool + the pooled device arrays of one family.
+
+    ``arrays`` is the family's ``init_state(cfg, slots)``: for the
+    default ``streamformer_lm`` the :func:`dense_pool_shape` pair
+    ``(k, v)`` — ``(layers, slots + 1, max_seq, heads * head_dim)`` in
+    ``cfg.dtype``, ONE array each (``models/streamformer_lm
+    .decode_step_pooled``'s operand), also reachable as ``pool.k`` /
+    ``pool.v``; for another family whatever its sessions keep.  The
+    decode engine passes the tuple, donated, to the family's functions
+    and assigns what they return, from the single decode thread, so
+    array access needs no lock.
+    """
+
+    def __init__(self, cfg, slots: int,
+                 admission: Optional[AdmissionController] = None,
+                 clock=None, family=None) -> None:
+        super().__init__(slots, admission, clock)
+        if family is None:
+            from .family import get_family
+
+            family = get_family()
+        self.cfg = cfg
+        self.family = family
+        self.arrays: Tuple[Any, ...] = tuple(
+            family.init_state(cfg, self.slots))
+
+    # the streamformer_lm pair by name, to read (arrays[0], arrays[1])
+    @property
+    def k(self):
+        return self.arrays[0]
+
+    @property
+    def v(self):
+        return self.arrays[1]
+
+    # -- sizing ----------------------------------------------------------
+    def cache_bytes(self) -> int:
+        """Device bytes the pooled state occupies — CONSTANT for the
+        pool's life (the bounded-memory evidence the soak gates on)."""
+        return sum(int(a.nbytes) for a in self.arrays)
+
+    def bytes_by_kind(self) -> Dict[str, int]:
+        """:meth:`cache_bytes` split by the family's kinds of state."""
+        out: Dict[str, int] = {}
+        for kind, a in zip(self.family.state_kinds, self.arrays):
+            out[kind] = out.get(kind, 0) + int(a.nbytes)
+        return out

@@ -205,6 +205,30 @@ def prefill_kv(params: Dict[str, Any], tokens: jnp.ndarray,
     return logits, jnp.stack(ks), jnp.stack(vs)
 
 
+def prefill_pooled(params: Dict[str, Any], k_pool: jnp.ndarray,
+                   v_pool: jnp.ndarray, tokens: jnp.ndarray,
+                   slot: jnp.ndarray, true_len: jnp.ndarray,
+                   cfg: StreamFormerConfig, flash: "bool | None" = None
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """:func:`prefill_kv` into slot ``slot`` of the dense pool: the whole
+    padded K/V run is installed as ``(L, 1, T, H * Dh)`` rows at ``(0,
+    slot, 0, 0)`` of the layer-major pool.  Rows past ``true_len`` are
+    garbage the decode mask never reads (valid = arange <= pos), so one
+    static-shape update serves every real length under this quantized
+    bucket.  Returns ``(logits of position true_len - 1, k_pool',
+    v_pool')``."""
+    logits, ks, vs = prefill_kv(params, tokens, cfg, flash=flash)
+    with jax.named_scope("sflm.kv_write"):
+        run = (cfg.layers, 1, tokens.shape[0], -1)
+        k_pool = jax.lax.dynamic_update_slice(
+            k_pool, ks.reshape(run), (0, slot, 0, 0))
+        v_pool = jax.lax.dynamic_update_slice(
+            v_pool, vs.reshape(run), (0, slot, 0, 0))
+    last = jax.lax.dynamic_index_in_dim(
+        logits, true_len - 1, axis=0, keepdims=False)
+    return last, k_pool, v_pool
+
+
 def _slot_rows(pool: jnp.ndarray, li: int, slots: jnp.ndarray
                ) -> jnp.ndarray:
     """``pool[li][slots]`` of a ``(L, S, max_seq, H * Dh)`` pool, read
@@ -217,9 +241,16 @@ def _slot_rows(pool: jnp.ndarray, li: int, slots: jnp.ndarray
     quarters of 256 positions, four gathers and a pad joining them:
     45.2 -> 34.3 ms a step at 32 lanes, 16.2 -> 7.3 at 8, 10.3 -> 5.5
     at one (PERF.md section 6, PR 26).  Where ``max_seq`` is no multiple
-    of 256 the blocks are the largest power of two that divides it."""
+    of 256 the blocks are the largest power of two that divides it, and
+    where a block of a wider row would pass 512 KiB (256 positions of
+    1 024 bfloat16 are just that) the blocks are halved until it does
+    not: the compiler cuts a larger block's gather in two along the row
+    and joins the halves in a pass of their own (described-chip compile
+    of ``sambay_lm``'s 1 280-wide rows, PR 29)."""
     layers, s, t, row = pool.shape
     blk = math.gcd(t, 256)
+    while blk > 8 and blk * row * pool.dtype.itemsize > 2 ** 19:
+        blk //= 2
     n = t // blk
     first = (li * s + slots) * n                                # (B,)
     blocks = pool.reshape(layers * s * n, blk, row)[
@@ -600,6 +631,64 @@ def _build_registry_model(custom_props):
                                        (cfg.vocab, seq))])
     return Model(name="streamformer_lm", forward=forward, params=params,
                  in_info=in_info, out_info=out_info)
+
+
+# -- the seam tensor_llm takes a family through (llm/family.py) ----------
+class _Family:
+    """``arch:streamformer_lm`` (the default): keys and values by
+    position for every layer, in a dense slot pool or a paged arena."""
+
+    name = "streamformer_lm"
+    paged = True
+    state_kinds = ("kv", "kv")
+    config_from_custom = staticmethod(config_from_custom)
+
+    @staticmethod
+    def init_params(cfg, seed):
+        from .registry import host_init
+
+        return host_init(lambda: init_params(cfg, int(seed)))
+
+    @staticmethod
+    def init_state(cfg, slots):
+        from ..llm.pool import dense_pool_shape
+
+        shape = dense_pool_shape(cfg, slots)
+        return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
+
+    @staticmethod
+    def chunk_len(cfg) -> int:
+        return 0
+
+    @staticmethod
+    def decode_step(params, state, tokens, pos, slots, cfg):
+        logits, k, v = decode_step_pooled(params, *state, tokens, pos,
+                                          slots, cfg)
+        return logits, (k, v)
+
+    @staticmethod
+    def prefill(params, state, tokens, slot, true_len, cfg, flash):
+        last, k, v = prefill_pooled(params, *state, tokens, slot,
+                                    true_len, cfg, flash=flash)
+        return last, (k, v)
+
+    @staticmethod
+    def decode_step_paged(params, state, tokens, pos, tables, cfg,
+                          page_size):
+        logits, k, v = decode_step_paged(params, *state, tokens, pos,
+                                         tables, cfg, page_size)
+        return logits, (k, v)
+
+    @staticmethod
+    def prefill_chunk_paged(params, state, tokens, table, start, true_len,
+                            cfg, page_size, scratch):
+        last, k, v = prefill_chunk_paged(params, *state, tokens, table,
+                                         start, true_len, cfg, page_size,
+                                         scratch)
+        return last, (k, v)
+
+
+FAMILY = _Family()
 
 
 def _register():

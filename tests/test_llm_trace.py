@@ -297,7 +297,7 @@ def _lowered(program):
         fn = eng._chunk_fn(8, 2)
         args = (jnp.zeros((8,), i32), jnp.zeros((2,), i32), i32(0),
                 i32(1), i32(pool.scratch))
-    return fn.lower(eng.params, pool.k, pool.v, *args).as_text(
+    return fn.lower(eng.params, pool.arrays, *args).as_text(
         debug_info=True)
 
 
