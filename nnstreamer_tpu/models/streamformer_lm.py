@@ -258,6 +258,39 @@ def _slot_rows(pool: jnp.ndarray, li: int, slots: jnp.ndarray
     return blocks.reshape(slots.shape[0], t, row)
 
 
+def _attend_rows(q: jnp.ndarray, k_rows: jnp.ndarray, v_rows: jnp.ndarray,
+                 valid: jnp.ndarray, cfg: StreamFormerConfig) -> jnp.ndarray:
+    """Attention of ONE query a lane over that lane's gathered rows, the
+    rows read as they lie: ``q (B, H, Dh)``, ``k_rows``/``v_rows (B, T,
+    H * Dh)`` in ``cfg.dtype`` (what :func:`_slot_rows` hands over),
+    ``valid (B, T)``; returns ``(B, H, Dh)`` float32 before ``wo``.
+
+    Each query head is laid into its own ``Dh`` columns of a row
+    (zeros elsewhere), so the scores are one ``(H, H * Dh) x (T, H *
+    Dh)^T`` product a lane and the read-out one ``(H, T) x (T, H *
+    Dh)`` product, of which a head keeps its own columns: operands in
+    ``cfg.dtype``, accumulation in float32, scale, mask and softmax in
+    float32.  The rows are never reshaped to heads, converted or
+    transposed: of ``einsum("bhd,bthd->bht")`` over a float32 image the
+    TPU compiler made a ``copy f32[32,1024,1024]`` a pool and layer,
+    48 a step, and two multiply-and-reduce passes on the vector unit
+    (22.2 ms of a 32-lane step's 32.6 busy at ``sflm_gpt2m``'s widths;
+    PERF.md section 6, PR 30).  ``sambay_lm._diff_attn_rows`` is the
+    same form for differential pairs over grouped key heads."""
+    b, h, hd = q.shape
+    own = jnp.eye(h, dtype=cfg.dtype)
+    qm = jnp.einsum("bhd,hk->bhkd", q.astype(cfg.dtype),
+                    own).reshape(b, h, h * hd)
+    s = jnp.einsum("bhr,btr->bht", qm, k_rows,
+                   preferred_element_type=jnp.float32)
+    s = s * (1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32)))
+    p = jax.nn.softmax(jnp.where(valid[:, None, :], s, -jnp.inf), axis=-1)
+    wide = jnp.einsum("bht,btr->bhr", p.astype(cfg.dtype), v_rows,
+                      preferred_element_type=jnp.float32)
+    return jnp.einsum("bhkd,hk->bhd", wide.reshape(b, h, h, hd),
+                      own.astype(jnp.float32))
+
+
 def decode_step_pooled(params: Dict[str, Any], k_pool: jnp.ndarray,
                        v_pool: jnp.ndarray, tokens: jnp.ndarray,
                        pos: jnp.ndarray, slots: jnp.ndarray,
@@ -285,7 +318,11 @@ def decode_step_pooled(params: Dict[str, Any], k_pool: jnp.ndarray,
     ``(layer, slot, pos)``, attend the single query against the slot's
     prefix, positions beyond ``pos`` masked) — lane *i* of this step
     equals a solo :func:`decode_step` on slot *i*'s cache, which is the
-    correctness spine the batched serving tier rests on.  The batched
+    correctness spine the batched serving tier rests on.  The attention
+    (:func:`_attend_rows`) takes the gathered rows as they lie, ``(B,
+    max_seq, H * Dh)`` in ``cfg.dtype``: both products on the MXU with
+    float32 accumulation, no float32 or transposed image of a lane's
+    cache (with ``dtype: float32`` nothing is rounded).  The batched
     shape is the point: B GEMV-shaped single-token steps become one
     GEMM-shaped step (the PR 9 padded-bucket economics, applied to the
     decode loop), and ONE executable per padded B serves every fill."""
@@ -293,7 +330,6 @@ def decode_step_pooled(params: Dict[str, Any], k_pool: jnp.ndarray,
         x = (params["embed"][tokens]
              + params["pos"][pos]).astype(cfg.dtype)
     valid = jnp.arange(cfg.max_seq)[None, :] <= pos[:, None]   # (B, T)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
     b, row = tokens.shape[0], cfg.heads * cfg.head_dim
     for li, lyr in enumerate(params["layers"]):
         with jax.named_scope("sflm.qkv"):
@@ -306,24 +342,20 @@ def decode_step_pooled(params: Dict[str, Any], k_pool: jnp.ndarray,
             v_pool = v_pool.at[li, slots, pos].set(v.reshape(b, row))
         with jax.named_scope("sflm.kv_read"):
             # the barrier ends the attention's say over layouts at the
-            # gathered copy: without it the compiler converts the
-            # blocks to float32 as they come and re-lays them out for
-            # the einsum in a pass of their own (47.7 ms a 32-lane
-            # step against 34.3; PERF.md section 6, PR 26)
+            # gathered copy, (B, max_seq, H * Dh) a pool: the einsum
+            # over a float32 image of the rows, without it, had the
+            # blocks converted and re-laid out as they came (PERF.md
+            # section 6, PR 26).  _attend_rows asks for no other
+            # layout, and the barrier now costs: it holds K's and V's
+            # rows (134 MB at 32 lanes) both before the scores start,
+            # 13.9 ms a 32-lane step dispatched back to back against
+            # 9.7 without it, 4.2 either way at one lane (chip run,
+            # PR 30; PERF.md section 7)
             kcur, vcur = jax.lax.optimization_barrier(
                 (_slot_rows(k_pool, li, slots),
                  _slot_rows(v_pool, li, slots)))
-            kcur = kcur.reshape(                    # (B, max_seq, H, Dh)
-                b, cfg.max_seq, cfg.heads, cfg.head_dim)
-            vcur = vcur.reshape(
-                b, cfg.max_seq, cfg.heads, cfg.head_dim)
         with jax.named_scope("sflm.attn"):
-            s = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32),
-                           kcur.astype(jnp.float32)) * scale
-            s = jnp.where(valid[:, None, :], s, -jnp.inf)
-            p = jax.nn.softmax(s, axis=-1)
-            attn = jnp.einsum("bht,bthd->bhd", p,
-                              vcur.astype(jnp.float32))
+            attn = _attend_rows(q, kcur, vcur, valid, cfg)
             o = jnp.einsum("bhd,hdn->bn", attn.astype(cfg.dtype),
                            lyr["wo"].astype(cfg.dtype))
             x = x + o
@@ -365,8 +397,9 @@ def decode_step_paged(params: Dict[str, Any], k_pages: jnp.ndarray,
 
     Per layer: scatter-append the new K/V into the TAIL page
     (``tables[b, pos//page_size]`` at offset ``pos % page_size``),
-    gather the lane's pages back as one ``(W*page_size,)`` run and
-    attend with the same causal-prefix mask as the dense step — lane
+    gather the lane's pages back as one ``(W*page_size,)`` run of ``H *
+    Dh``-wide rows and attend through the dense step's
+    :func:`_attend_rows` under the same causal-prefix mask — lane
     *i* equals a solo :func:`decode_step` on the same history, the
     correctness spine the paged pool rests on.  The arena is donated by
     the engine exactly like the dense pool (the in-place-update
@@ -378,7 +411,6 @@ def decode_step_paged(params: Dict[str, Any], k_pages: jnp.ndarray,
         x = (params["embed"][tokens]
              + params["pos"][pos]).astype(cfg.dtype)
     valid = jnp.arange(span)[None, :] <= pos[:, None]      # (B, W*ps)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
     # tail-page coordinates for this step's scatter-append
     wpage = jnp.take_along_axis(tables, (pos // ps)[:, None],
                                 axis=1)[:, 0]              # (B,)
@@ -394,17 +426,11 @@ def decode_step_paged(params: Dict[str, Any], k_pages: jnp.ndarray,
             k_pages = k_pages.at[wpage, li_ix, woff].set(k)
             v_pages = v_pages.at[wpage, li_ix, woff].set(v)
         with jax.named_scope("sflm.kv_read"):
-            kcur = k_pages[tables, li].reshape(
-                b, span, cfg.heads, cfg.head_dim)          # page gather
-            vcur = v_pages[tables, li].reshape(
-                b, span, cfg.heads, cfg.head_dim)
+            # the page gather, viewed as rows (B, W*ps, H * Dh)
+            kcur = k_pages[tables, li].reshape(b, span, -1)
+            vcur = v_pages[tables, li].reshape(b, span, -1)
         with jax.named_scope("sflm.attn"):
-            s = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32),
-                           kcur.astype(jnp.float32)) * scale
-            s = jnp.where(valid[:, None, :], s, -jnp.inf)
-            p = jax.nn.softmax(s, axis=-1)
-            attn = jnp.einsum("bht,bthd->bhd", p,
-                              vcur.astype(jnp.float32))
+            attn = _attend_rows(q, kcur, vcur, valid, cfg)
             o = jnp.einsum("bhd,hdn->bn", attn.astype(cfg.dtype),
                            lyr["wo"].astype(cfg.dtype))
             x = x + o

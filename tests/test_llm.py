@@ -14,6 +14,7 @@ by construction.
 """
 
 import gc
+import os
 import threading
 import time
 
@@ -33,6 +34,7 @@ from nnstreamer_tpu.llm.pool import KVCachePool, dense_pool_shape
 from nnstreamer_tpu.models.streamformer_lm import (_slot_rows,
                                                    config_from_custom,
                                                    decode_step,
+                                                   decode_step_paged,
                                                    decode_step_pooled,
                                                    forward_logits,
                                                    generate, init_cache,
@@ -244,13 +246,107 @@ class TestPooledDecode:
             np.testing.assert_allclose(logits[0], scan[11 + i],
                                        atol=1e-4, rtol=1e-4)
 
-    def test_pooled_logits_are_the_parents_to_the_bit(self):
+    @staticmethod
+    def _written_pools(cfg, slots, seed, scale=1.0):
+        """Both dense pools of ``slots`` sessions (+ scratch), every
+        position written with seeded values rounded to ``cfg.dtype``."""
+        rng = np.random.default_rng(seed)
+        shape = dense_pool_shape(cfg, slots)
+        return tuple(jnp.asarray(scale * rng.standard_normal(shape),
+                                 cfg.dtype) for _ in range(2))
+
+    def test_bfloat16_rows_match_the_float32_solo_step(self):
+        """The row-form attention with bfloat16 operands (``q`` and
+        ``p`` rounded for the MXU, float32 accumulation) against the
+        float32 solo ``decode_step`` on the same weights and the same
+        cached values: three lanes at positions on both sides of a
+        256-position block edge of ``_slot_rows``, plus a padding lane
+        on the scratch slot."""
+        base = dict(max_seq=512, layers=3)
+        cfg, cfg32 = _cfg(dtype=jnp.bfloat16, **base), _cfg(**base)
+        params = init_params(cfg32, 30)
+        kp, vp = self._written_pools(cfg, 3, 30)
+        toks, slots, pos = [5, 17, 42, 0], [2, 0, 1, 3], [255, 256, 300, 0]
+        logits, _, _ = decode_step_pooled(
+            params, kp, vp, jnp.asarray(toks, jnp.int32),
+            jnp.asarray(pos, jnp.int32), jnp.asarray(slots, jnp.int32),
+            cfg)
+        assert logits.dtype == jnp.float32
+        for lane in range(3):
+            cache = {n: a[:, slots[lane]].astype(jnp.float32).reshape(
+                cfg.layers, cfg.max_seq, cfg.heads, cfg.head_dim)
+                for n, a in (("k", kp), ("v", vp))}
+            solo, _ = decode_step(
+                params, dict(cache, pos=jnp.int32(pos[lane])),
+                jnp.int32(toks[lane]), cfg32)
+            np.testing.assert_allclose(np.asarray(logits[lane]),
+                                       np.asarray(solo),
+                                       atol=5e-2, rtol=5e-2)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_dead_sessions_rows_beyond_pos_are_masked(self, dtype):
+        """A slot whose rows beyond ``pos`` still hold a longer dead
+        session's values, large and finite, gives the logits of a fresh
+        slot: the mask holds through the products (a masked position's
+        weight is an exact zero, whatever its key and value)."""
+        cfg = _cfg(dtype=dtype, max_seq=64)
+        params = init_params(_cfg(max_seq=64), 31)
+        live, _ = self._written_pools(cfg, 1, 31)
+        dead, _ = self._written_pools(cfg, 1, 32, scale=3e4)
+        upto = jnp.arange(cfg.max_seq)[None, None, :, None] <= 9
+        args = (jnp.asarray([7], jnp.int32), jnp.asarray([9], jnp.int32),
+                jnp.asarray([0], jnp.int32), cfg)
+        fresh, _, _ = decode_step_pooled(
+            params, jnp.where(upto, live, 0), jnp.where(upto, live, 0),
+            *args)
+        reused, _, _ = decode_step_pooled(
+            params, jnp.where(upto, live, dead),
+            jnp.where(upto, live, dead), *args)
+        assert np.isfinite(np.asarray(reused)).all()
+        np.testing.assert_array_equal(np.asarray(reused),
+                                      np.asarray(fresh))
+
+    def test_paged_step_is_the_pooled_steps_mathematics(self):
+        """One helper, one mathematics: the same three sessions in a
+        paged arena (pages out of order, a scratch-padded table) and in
+        the dense pool give the same bfloat16 step far inside the
+        bfloat16 tolerance; only the masked tail's length (``W * page``
+        against ``max_seq``) differs."""
+        cfg = _cfg(dtype=jnp.bfloat16, max_seq=64, layers=3)
+        params = init_params(_cfg(max_seq=64, layers=3), 33)
+        kp, vp = self._written_pools(cfg, 3, 33)
+        ps, w = 8, 4                           # 32 of 64 positions paged
+        tables = np.asarray([[5, 2, 9, 12], [0, 7, 12, 12], [11, 3, 1, 6]])
+        arena = (13, cfg.layers, ps, cfg.heads, cfg.head_dim)  # 12: scratch
+        pages = []
+        for dense in (kp, vp):
+            a = np.zeros(arena, np.float32)
+            rows = np.asarray(dense.astype(jnp.float32))
+            for slot in range(3):
+                for j, page in enumerate(tables[slot]):
+                    if page != 12:
+                        a[page] = rows[:, slot, j * ps:(j + 1) * ps].reshape(
+                            arena[1:])
+            pages.append(jnp.asarray(a, cfg.dtype))
+        toks = jnp.asarray([5, 17, 42], jnp.int32)
+        pos = jnp.asarray([20, 9, 31], jnp.int32)
+        dense, _, _ = decode_step_pooled(
+            params, kp, vp, toks, pos, jnp.arange(3, dtype=jnp.int32), cfg)
+        paged, kpg, _ = decode_step_paged(
+            params, *pages, toks, pos, jnp.asarray(tables, jnp.int32), cfg,
+            ps)
+        assert kpg.shape == arena
+        np.testing.assert_allclose(np.asarray(paged), np.asarray(dense),
+                                   atol=1e-3, rtol=1e-3)
+
+    def test_pooled_logits_and_pools_are_the_parents_values(self):
         """Same values in, same values out: a seeded 3-slot, 14-token
         run's logits and both pools, against what the parent of the
-        layout change (PR 24's ``(S, L, T, H, Dh)`` pool, its pools
-        transposed to this layout) gave on the CPU."""
-        import hashlib
-
+        attention's row form (PR 29; to the bit what PR 24's ``(S, L, T,
+        H, Dh)`` pool gave) computed on the CPU.  The greedy tokens are
+        the parent's; the values agree to a float32 sum's order (the
+        scores are summed over a row's ``H * Dh`` columns, all but a
+        head's own ``Dh`` of them exact zeros)."""
         cfg = _cfg()
         params = init_params(cfg, 26)
         toks = np.random.default_rng(26).integers(0, 61, (14, 3))
@@ -265,22 +361,27 @@ class TestPooledDecode:
             out.append(np.asarray(logits))
         out = np.stack(out)
         assert out.argmax(-1).tolist() == PARENT_ARGMAX
-        digests = [hashlib.sha256(np.ascontiguousarray(a).tobytes())
-                   .hexdigest() for a in (out, kp, vp)]
-        assert digests == PARENT_SHA256
+        with np.load(PARENT_VALUES) as parent:
+            np.testing.assert_allclose(out, parent["logits"],
+                                       atol=1e-5, rtol=1e-5)
+            for name, pool in (("k", kp), ("v", vp)):
+                pool = np.asarray(pool)
+                np.testing.assert_allclose(pool[:, :, :14], parent[name],
+                                           atol=1e-5, rtol=1e-5)
+                assert not pool[:, :, 14:].any()
 
 
 #: recorded from commit 8ed891c (PR 24) before the pool's layout changed:
-#: argmax per (step, lane), and the SHA-256 of the (14, 3, 61) float32
-#: logits and of the final K and V pools
+#: argmax per (step, lane)
 PARENT_ARGMAX = [
     [3, 57, 22], [52, 45, 56], [26, 47, 47], [19, 19, 49], [42, 42, 42],
     [8, 42, 14], [59, 20, 23], [52, 56, 56], [0, 50, 60], [6, 20, 43],
     [40, 60, 55], [38, 42, 34], [17, 58, 14], [17, 49, 21]]
-PARENT_SHA256 = [
-    "d248c3b00477bd4c507deb6ac567205c472d104cac86a761bb99ab482e1de6a4",
-    "d6ba610cee87fba2d3049c00fdaac9be40dbe51a415136071d59673115afdeb1",
-    "608f75c0d5e4d9a26fe72bd2b83915b55451e47500e4337aed84a3f3cbcfadd1"]
+#: recorded from commit 8310dbe (PR 29), whose SHA-256 of each array was
+#: PR 24's: the (14, 3, 61) float32 logits and the written positions
+#: ``[:, :, :14]`` of the final K and V pools (the rest are zeros)
+PARENT_VALUES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             "test_llm_parent_values.npz")
 
 
 class TestCustomGrammar:

@@ -15,6 +15,7 @@ compiler), skipped where it cannot be.
 
 import json
 import os
+import re
 
 import pytest
 
@@ -87,13 +88,36 @@ def _check(compiled, pool_bytes, temp_share):
     assert stats.temp_size_in_bytes < pool_bytes * temp_share
 
 
+def _compiled_step(d, lanes):
+    """The engine's step at ``lanes`` lanes, compiled once a module."""
+    steps = d.setdefault("steps", {})
+    if lanes not in steps:
+        vec = d["on_chip"](lanes)
+        steps[lanes] = d["engine"]._step_fn(lanes).lower(
+            d["params"], (d["pool"], d["pool"]), vec, vec, vec).compile()
+    return steps[lanes]
+
+
 @pytest.mark.parametrize("lanes", [1, 8, 32])
 def test_decode_step_keeps_the_pool_still(described, lanes):
-    d = described
-    vec = d["on_chip"](lanes)
-    compiled = d["engine"]._step_fn(lanes).lower(
-        d["params"], (d["pool"], d["pool"]), vec, vec, vec).compile()
-    _check(compiled, d["pool_bytes"], 1 / 5)  # found: 0.04, 0.02, 0.23 GB
+    _check(_compiled_step(described, lanes), described["pool_bytes"],
+           1 / 5)                          # found: 0.04, 0.03, 0.09 GB
+
+
+@pytest.mark.parametrize("lanes", [1, 8, 32])
+def test_decode_step_reads_the_rows_as_they_lie(described, lanes):
+    """The attention makes no float32 image of a lane's gathered rows:
+    no ``f32[lanes, 1024, 1024]`` array anywhere in the compiled step
+    and no float32 copy of that extent under another shape (there were
+    48 ``copy f32[32,1024,1024]{1,2,0}`` in the 32-lane step, one a pool
+    and layer, and 0.226 GB of temporaries; PERF.md section 6, PR 30)."""
+    compiled = _compiled_step(described, lanes)
+    text = compiled.as_text()
+    assert f"f32[{lanes},1024,1024]" not in text
+    assert not re.search(r"= f32\[[0-9,]*1024,1024\]\S* copy\(", text)
+    if lanes == 32:
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 0.15e9                           # found: 0.089 GB
 
 
 @pytest.mark.parametrize("padded_t", [64, 1024])
