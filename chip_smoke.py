@@ -446,7 +446,7 @@ def phase_llm(sid: int, custom: str = LM_CUSTOM, slots: int = 8,
                "warm_executables": eng.compiles}
         if mosaic_bucket:
             assert _mosaic(eng._prefill_jit[mosaic_bucket], eng.params,
-                           pool.arrays,
+                           pool.arrays, eng._sampled,
                            jnp.zeros((mosaic_bucket,), jnp.int32),
                            jnp.int32(0), jnp.int32(1)), (
                 f"{mosaic_bucket}-bucket prefill holds no Mosaic call")

@@ -23,7 +23,10 @@ Everything REUSES the existing serving plane rather than forking it:
   slot ids of every resident sequence and runs ONE padded
   ``decode_step_pooled`` invoke over the active set (the PR 9
   ``pad_rows`` quantization: a bounded set of warm executables serves
-  every fill).  Prefill routes through ``ops/flash_attention.py`` so
+  every fill).  Tokens are sampled on the chip and stay there, so the
+  element keeps one step in flight (``dispatch`` step k, then
+  ``collect`` step k-1 and push its tokens while the chip works).
+  Prefill routes through ``ops/flash_attention.py`` so
   long prompts never materialize (T, T) scores.  Exact, conserved
   prefill-vs-decode-vs-idle wall-time attribution.
 - **element.py** — the stateful ``tensor_llm`` filter element: prompt

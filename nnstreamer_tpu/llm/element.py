@@ -20,7 +20,10 @@ plane into a continuous-batching token stream server:
   every downstream push — so per-client token order is exact BY
   CONSTRUCTION (single pusher, bucket re-forms every step, sessions
   join mid-flight after their prefill and leave on stop-token /
-  max-new / disconnect).
+  max-new / disconnect).  The loop keeps ONE STEP IN FLIGHT: tokens are
+  sampled on the chip and stay there, so step k is dispatched before
+  the host has read step k-1, whose tokens it then reads and pushes
+  while the chip works (``_decode_loop_inner``).
 - **streaming egress**: per-token ``[1, 1] int32`` frames flow to the
   serversink carrying the request's extras (client id, wire seq, QoS,
   trace context), ``pts`` = token index, and ``extra["nns_more"]`` on
@@ -371,6 +374,7 @@ class TensorLLM(Element):
         self._stopping = False
         self._flushing = False
         self._req_n = 0                      # standalone session keys
+        self._sent_ns = 0                    # the step in flight's dispatch
         self.shed_total = 0
         self.rejected_total = 0
         self.evicted_total = 0
@@ -614,7 +618,8 @@ class TensorLLM(Element):
             with self._cv:
                 if self._stopping:
                     return
-                if not self._pending and pool.live == 0:
+                if not self._pending and pool.live == 0 \
+                        and not eng.in_flight:
                     eng.phases.enter("idle")
                     # idle tick bounds disconnect-prune latency too
                     self._cv.wait(0.05)
@@ -624,14 +629,23 @@ class TensorLLM(Element):
                 self._cv.notify_all()   # free chain() backpressure slots
             self._prune_sessions()
             requeue = self._admit(taken)
+            # the lanes of step k are chosen before step k-1's tokens
+            # are known, from what the host does know: a stream that
+            # the step in flight brings to its granted length is left
+            # out exactly; one that ends there by its stop token rides
+            # one more step, whose token is dropped and whose row lands
+            # one position on in its own slot (inside max_seq: admission
+            # holds prompt + max_new <= max_seq).  With no lane to go on
+            # nothing is dispatched and the step in flight is collected
+            # at once.
             sessions = [s for s in pool.sessions()
-                        if not getattr(s, "prefilling", False)]
-            if sessions:
-                n = len(sessions)
-                pick = [sessions[(rr + i) % n]
-                        for i in range(min(n, self._batch))]
-                rr = (rr + len(pick)) % max(1, n)
-                self._run_step(pick)
+                        if not getattr(s, "prefilling", False)
+                        and s.emitted + s.in_flight < s.max_new]
+            n = len(sessions)
+            pick = [sessions[(rr + i) % n]
+                    for i in range(min(n, self._batch))]
+            rr = (rr + len(pick)) % max(1, n)
+            self._run_step(pick)
             # interleaved chunked prefill: ONE bounded chunk per loop
             # iteration, so a long prompt time-shares the decode thread
             # with resident streams instead of stalling them (with no
@@ -722,7 +736,6 @@ class TensorLLM(Element):
                         tracer.annotate_span("llm-prefill", t0,
                                              self._mono_ns(), seq=-1,
                                              trace_id=ctx.trace_id)
-                sess.next_token = first
                 # the prefill's token is this session's first answer —
                 # emit it NOW (time-to-first-token is the prefill, not
                 # the prefill plus one bucket cycle)
@@ -775,29 +788,37 @@ class TensorLLM(Element):
                     tracer.annotate_span("llm-prefill-chunk", t0, t1,
                                          seq=-1, trace_id=ctx.trace_id)
             if first is not None:
-                sess.next_token = first
                 self._finish_or_emit(sess, first)
             return
 
     # -- stepping / egress -----------------------------------------------
-    def _run_step(self, picked) -> None:
+    def _run_step(self, pick) -> None:
+        """Dispatch step k over ``pick`` and, while the chip runs it,
+        collect step k-1 and push its tokens.  A slot a stream of step
+        k-1 gives up here is prefilled, by a later iteration, behind
+        step k: the device keeps the order they were sent in."""
         eng = self.engine
-        t0 = self._mono_ns()
-        toks = eng.step(picked)
+        waiting = eng.in_flight
+        t0, now = self._sent_ns, self._mono_ns()
+        if pick:
+            eng.dispatch(pick)
+            self._sent_ns = now
+        if not waiting:
+            return
+        lanes = eng.collect()
         t1 = self._mono_ns()
         self._ctr_sync()
         tracer = self._tracer()
         if tracer is not None:
-            # the SHARED decode window, once per resident trace — the
-            # cross-stream device-invoke convention (per-token
-            # wall-clock truth, not a 1/n share)
-            for sess in picked:
+            # the SHARED decode window (dispatch to collect), once per
+            # resident trace — the cross-stream device-invoke convention
+            # (per-token wall-clock truth, not a 1/n share)
+            for sess, _ in lanes:
                 ctx = sess.extra.get("nns_trace")
                 if ctx is not None and ctx.trace_id:
                     tracer.annotate_span("llm-decode", t0, t1, seq=-1,
                                          trace_id=ctx.trace_id)
-        for sess, tok in zip(picked, toks):
-            sess.next_token = tok
+        for sess, tok in lanes:
             self._finish_or_emit(sess, tok)
 
     def _finish_or_emit(self, sess, tok: int) -> None:

@@ -26,6 +26,7 @@ inside.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -92,6 +93,16 @@ def _memo_jit(key: tuple, make):
         fn = make()
         _EXEC_MEMO[key] = fn
     return fn
+
+
+def _sample(logits, sampled, rows):
+    """How every engine executable ends: the greedy token of ``logits``
+    (the lowest index among equals, as ``np.argmax`` takes it) and
+    ``sampled`` with it written at ``rows``.  Logits stay on the chip."""
+    import jax.numpy as jnp
+
+    out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return out, sampled.at[rows].set(out)
 
 
 class PhaseClock:
@@ -225,10 +236,11 @@ class DecodeEngine:
     observability tier reads.
 
     Single-threaded by contract: exactly one decode thread calls
-    :meth:`prefill` / :meth:`step` (the element's loop), so the pool
-    arrays mutate without locks.  The jitted executables are cached per
-    padded shape — sequences joining/leaving between steps change only
-    the LANE COUNT, which quantizes onto the same warm set.
+    :meth:`prefill` / :meth:`dispatch` / :meth:`collect` (the element's
+    loop), so the pool arrays mutate without locks.  The jitted
+    executables are cached per padded shape — sequences joining/leaving
+    between steps change only the LANE COUNT, which quantizes onto the
+    same warm set.
 
     The engine imports no model: what it compiles are the functions of
     the pool's FAMILY (``llm/family.py``), and what it passes them is
@@ -249,6 +261,18 @@ class DecodeEngine:
     prefilled in chunks of that length through ONE ``_prefill``
     executable (positions, not a power-of-two bucket a prompt), each
     chunk taking the state the last one left in the slot.
+
+    **Tokens are sampled on the chip and stay there.**  Every executable
+    ends in :func:`_sample`: no logits leave the device, and the token a
+    stream sampled last lives in ``_sampled``, an int32 ``(slots + 1,)``
+    vector beside ``pool.arrays`` (row ``slots`` takes the padding
+    lanes' writes), donated and reassigned with them.  A prompt's last
+    chunk writes the stream's first token into its row, a step gathers
+    its lanes' input tokens from there and scatters their outputs back,
+    so a step needs nothing of the step before from the host:
+    :meth:`dispatch` sends step k without waiting for it and
+    :meth:`collect` reads the ``B`` int32 of the oldest step in flight.
+    :meth:`step` is the two in a row.
     """
 
     def __init__(self, params, cfg, pool: KVCachePool,
@@ -275,6 +299,14 @@ class DecodeEngine:
         # uncommitted first generation would make the first shape
         # warmed compile a second time on its first live dispatch
         pool.arrays = tuple(jax.device_put(tuple(pool.arrays), device))
+        self._device = device
+        self._sampled = jax.device_put(
+            np.zeros((pool.slots + 1,), np.int32), device)
+        #: steps dispatched and not yet collected, oldest first:
+        #: (device tokens, sessions, when it was dispatched).  One, and
+        #: a second between a dispatch and the collect that follows it
+        self._flights: collections.deque = collections.deque(maxlen=2)
+        self._collected_s = 0.0
         self.cfg = cfg
         self.pool = pool
         self.capacity = max(1, int(capacity))
@@ -304,6 +336,12 @@ class DecodeEngine:
         self.steps_total = 0
         self.prefills_total = 0
         self.prefill_chunks_total = 0
+        #: steps dispatched while the step before was uncollected, and
+        #: lanes stepped after their stream had ended (a stop token or an
+        #: eviction the host learnt of a step late): their output is
+        #: dropped and counts as no token
+        self.steps_ahead = 0
+        self.lanes_discarded = 0
         self.last_fill = 0
         self.ewma_step_s = 0.0
         self.compiles = 0
@@ -327,11 +365,12 @@ class DecodeEngine:
 
             def _make():
                 @self._jax.named_scope("llm.engine.step")
-                def _step(params, state, tokens, pos, slots):
-                    return family.decode_step(params, state, tokens,
-                                              pos, slots, cfg)
+                def _step(params, state, sampled, pos, slots):
+                    logits, state = family.decode_step(
+                        params, state, sampled[slots], pos, slots, cfg)
+                    return (*_sample(logits, sampled, slots), state)
 
-                return self._jax.jit(_step, donate_argnums=(1,))
+                return self._jax.jit(_step, donate_argnums=(1, 2))
 
             fn = _memo_jit(("step", family.name, _cfg_key(cfg)), _make)
             self._step_jit[padded] = fn
@@ -355,11 +394,13 @@ class DecodeEngine:
 
             def _make():
                 @self._jax.named_scope("llm.engine.pstep")
-                def _step(params, state, tokens, pos, tables):
-                    return family.decode_step_paged(
-                        params, state, tokens, pos, tables, cfg, ps)
+                def _step(params, state, sampled, pos, rows, tables):
+                    logits, state = family.decode_step_paged(
+                        params, state, sampled[rows], pos, tables, cfg,
+                        ps)
+                    return (*_sample(logits, sampled, rows), state)
 
-                return self._jax.jit(_step, donate_argnums=(1,))
+                return self._jax.jit(_step, donate_argnums=(1, 2))
 
             fn = _memo_jit(("pstep", family.name, _cfg_key(cfg), ps),
                            _make)
@@ -373,7 +414,9 @@ class DecodeEngine:
         """Paged prefill-chunk executable per ``(padded C, table
         width)``; chunk origin and real length ride as traced operands,
         so ONE executable serves every chunk of every prompt at every
-        prefix-hit offset under its quantized bucket."""
+        prefix-hit offset under its quantized bucket.  ``row`` is where
+        the chunk's token goes: the session's on a prompt's last chunk,
+        the padding lanes' before."""
         key = ("chunk", padded_c, width)
         fn = self._prefill_jit.get(key)
         if fn is None:
@@ -385,13 +428,14 @@ class DecodeEngine:
 
             def _make():
                 @self._jax.named_scope("llm.engine.chunk")
-                def _chunk(params, state, tokens, table, start, true_len,
-                           scratch):
-                    return family.prefill_chunk_paged(
+                def _chunk(params, state, sampled, tokens, table, start,
+                           true_len, scratch, row):
+                    logits, state = family.prefill_chunk_paged(
                         params, state, tokens, table, start, true_len,
                         cfg, ps, scratch)
+                    return (*_sample(logits, sampled, row), state)
 
-                return self._jax.jit(_chunk, donate_argnums=(1,))
+                return self._jax.jit(_chunk, donate_argnums=(1, 2))
 
             fn = _memo_jit(("chunk", family.name, _cfg_key(cfg), ps),
                            _make)
@@ -414,22 +458,30 @@ class DecodeEngine:
 
             def _make():
                 if chunked:
+                    # only the last chunk has logits (the others answer
+                    # zeros): theirs goes to the padding lanes' row
                     @jax.named_scope("llm.engine.prefill")
-                    def _prefill(params, state, tokens, slot, start,
-                                 true_len, last):
-                        return family.prefill_chunk(
+                    def _prefill(params, state, sampled, tokens, slot,
+                                 start, true_len, last):
+                        logits, state = family.prefill_chunk(
                             params, state, tokens, slot, start, true_len,
                             last, cfg)
+                        row = jax.numpy.where(last, slot,
+                                              sampled.shape[0] - 1)
+                        return (*_sample(logits, sampled, row), state)
                 else:
                     # the family installs the whole padded run into the
                     # slot (``sflm.kv_write``) and answers with the
                     # logits of position ``true_len - 1``
                     @jax.named_scope("llm.engine.prefill")
-                    def _prefill(params, state, tokens, slot, true_len):
-                        return family.prefill(params, state, tokens, slot,
-                                              true_len, cfg, flash)
+                    def _prefill(params, state, sampled, tokens, slot,
+                                 true_len):
+                        logits, state = family.prefill(
+                            params, state, tokens, slot, true_len, cfg,
+                            flash)
+                        return (*_sample(logits, sampled, slot), state)
 
-                return jax.jit(_prefill, donate_argnums=(1,))
+                return jax.jit(_prefill, donate_argnums=(1, 2))
 
             fn = _memo_jit(("prefill", family.name, _cfg_key(cfg), flash),
                            _make)
@@ -478,15 +530,15 @@ class DecodeEngine:
         shapes = sorted({JitExecMixin.pad_rows(n, self.capacity)
                          for n in range(1, self.capacity + 1)})
         for rows in shapes:
-            toks = jnp.zeros((rows,), jnp.int32)
             pos = jnp.zeros((rows,), jnp.int32)
             slots = jnp.full((rows,), self.pool.scratch, jnp.int32)
             fn = self._step_fn(rows)
-            # donated operands: the pool arrays MUST be reassigned from
-            # the outputs (the inputs' buffers are dead after the call)
-            logits, self.pool.arrays = fn(
-                self.params, self.pool.arrays, toks, pos, slots)
-            self._jax.block_until_ready(logits)
+            # donated operands: the pool arrays and the sampled tokens
+            # MUST be reassigned from the outputs (the inputs' buffers
+            # are dead after the call)
+            out, self._sampled, self.pool.arrays = fn(
+                self.params, self.pool.arrays, self._sampled, pos, slots)
+            self._jax.block_until_ready(out)
         if self.prefill_mode == "step":
             return   # prompt decode rides the step executables above
         if self.chunk_len > 0:
@@ -494,12 +546,12 @@ class DecodeEngine:
             # through its ``last`` branch
             fn = self._prefill_fn(self.chunk_len)
             for last in (False, True):
-                logits, self.pool.arrays = fn(
-                    self.params, self.pool.arrays,
+                out, self._sampled, self.pool.arrays = fn(
+                    self.params, self.pool.arrays, self._sampled,
                     jnp.zeros((self.chunk_len,), jnp.int32),
                     jnp.int32(self.pool.scratch), jnp.int32(0),
                     jnp.int32(1), jnp.bool_(last))
-                self._jax.block_until_ready(logits)
+                self._jax.block_until_ready(out)
             return
         lengths, t = [], 8
         while True:
@@ -509,11 +561,11 @@ class DecodeEngine:
             t <<= 1
         for padded in sorted(set(lengths)):
             fn = self._prefill_fn(padded)
-            last, self.pool.arrays = fn(
-                self.params, self.pool.arrays,
+            out, self._sampled, self.pool.arrays = fn(
+                self.params, self.pool.arrays, self._sampled,
                 jnp.zeros((padded,), jnp.int32),
                 jnp.int32(self.pool.scratch), jnp.int32(1))
-            self._jax.block_until_ready(last)
+            self._jax.block_until_ready(out)
         # scratch writes during warmup are garbage by design; zero the
         # scratch lane is unnecessary (no session ever reads it)
 
@@ -557,13 +609,14 @@ class DecodeEngine:
                            for n in range(1, self.capacity + 1)})
         for rows in rows_set:
             for w in widths:
-                toks = jnp.zeros((rows,), jnp.int32)
                 pos = jnp.zeros((rows,), jnp.int32)
+                lanes = jnp.full((rows,), pool.slots, jnp.int32)
                 tables = jnp.full((rows, w), pool.scratch, jnp.int32)
                 fn = self._pstep_fn(rows, w)
-                logits, pool.arrays = fn(
-                    self.params, pool.arrays, toks, pos, tables)
-                self._jax.block_until_ready(logits)
+                out, self._sampled, pool.arrays = fn(
+                    self.params, pool.arrays, self._sampled, pos, lanes,
+                    tables)
+                self._jax.block_until_ready(out)
         if self.prefill_mode == "step":
             return   # prompt decode rides the paged step grid above
         ps = pool.page_size
@@ -573,19 +626,22 @@ class DecodeEngine:
                 if w < min_w:
                     continue
                 fn = self._chunk_fn(c, w)
-                last, pool.arrays = fn(
-                    self.params, pool.arrays,
+                out, self._sampled, pool.arrays = fn(
+                    self.params, pool.arrays, self._sampled,
                     jnp.zeros((c,), jnp.int32),
                     jnp.full((w,), pool.scratch, jnp.int32),
                     jnp.int32(0), jnp.int32(1),
-                    jnp.int32(pool.scratch))
-                self._jax.block_until_ready(last)
+                    jnp.int32(pool.scratch), jnp.int32(pool.slots))
+                self._jax.block_until_ready(out)
 
     # -- prefill ---------------------------------------------------------
     def prefill(self, sess: Session, prompt: np.ndarray) -> int:
         """Seed ``sess``'s cache slot from its prompt and return the
         session's FIRST generated token (greedy argmax of the last
-        prompt position's logits — :func:`generate`'s semantics).
+        prompt position's logits — :func:`generate`'s semantics), which
+        the executable also left in the session's row of ``_sampled``
+        for its first step.  Synchronous: it waits for that one int32,
+        behind whatever step is in flight.
 
         ``prefill_mode="step"`` decodes the prompt token-by-token
         through the pooled step instead (the decode-without-prefill
@@ -594,52 +650,69 @@ class DecodeEngine:
         import jax.numpy as jnp
 
         prev = self.phases.enter("prefill")
-        t = int(prompt.shape[0])
-        if self.paged:
-            try:
-                return self._prefill_paged(sess)
-            finally:
-                self.phases.enter(prev)
-        if self.prefill_mode == "step":
-            logits = None
-            for i in range(t):
-                rows = self._lane_arrays([(sess.slot, i,
-                                           int(prompt[i]))])
-                logits = self._dispatch(*rows)[0]
+        try:
+            if self.prefill_mode == "step":
+                return self._prefill_by_steps(sess, prompt)
+            if self.paged:
+                # the non-interleaved path: ``chunk == 0`` makes it ONE
+                # whole-suffix chunk
+                while True:
+                    first = self._advance_chunk(sess)
+                    if first is not None:
+                        return first
+            t = int(prompt.shape[0])
+            if self.chunk_len > 0:
+                first = self._prefill_chunks(sess, prompt)
+            else:
+                padded = quantize_prompt(t, self.cfg.max_seq)
+                self.phases.note(padded=padded)
+                buf = np.zeros((padded,), np.int32)
+                buf[:t] = prompt
+                fn = self._prefill_fn(padded)
+                cold = self._enter_cold()
+                try:
+                    with self.phases.child("dispatch"):
+                        out, self._sampled, self.pool.arrays = fn(
+                            self.params, self.pool.arrays, self._sampled,
+                            jnp.asarray(buf), jnp.int32(sess.slot),
+                            jnp.int32(t))
+                    with self.phases.child("wait"):
+                        first = int(out)
+                finally:
+                    if cold is not None:
+                        self.phases.enter(cold)
             sess.pos = t
-        elif self.chunk_len > 0:
-            logits = self._prefill_chunks(sess, prompt)
-            sess.pos = t
-        else:
-            padded = quantize_prompt(t, self.cfg.max_seq)
-            self.phases.note(padded=padded)
-            buf = np.zeros((padded,), np.int32)
-            buf[:t] = prompt
-            fn = self._prefill_fn(padded)
-            cold = self._enter_cold()
-            try:
-                with self.phases.child("dispatch"):
-                    last, self.pool.arrays = fn(
-                        self.params, self.pool.arrays,
-                        jnp.asarray(buf), jnp.int32(sess.slot),
-                        jnp.int32(t))
-                with self.phases.child("wait"):
-                    logits = np.asarray(last)
-            finally:
-                if cold is not None:
-                    self.phases.enter(cold)
-            sess.pos = t
+            self._prefilled(sess)
+            return first
+        finally:
+            self.phases.enter(prev)
+
+    def _prefilled(self, sess: Session) -> None:
         self.prefills_total += 1
         self.tokens_total += 1
         sess.last_step_s = self._clock()
-        self.phases.enter(prev)
-        return int(np.argmax(logits))
 
-    def _prefill_chunks(self, sess: Session, prompt: np.ndarray):
+    def _prefill_by_steps(self, sess: Session, prompt: np.ndarray) -> int:
+        """The prompt through the step executables, a position a step,
+        each token forced on its step (``next_token``); a paged session
+        starts behind its prefix hit.  Only the last step's token is
+        waited for."""
+        t = int(prompt.shape[0])
+        for i in range(getattr(sess, "prefill_pos", 0), t):
+            sess.pos, sess.next_token = i, int(prompt[i])
+            out = self._launch([sess])
+        sess.pos = t
+        if self.paged:
+            self.pool.note_prefill(sess, t)
+        self._prefilled(sess)
+        with self.phases.child("wait"):
+            return int(np.asarray(out)[0])
+
+    def _prefill_chunks(self, sess: Session, prompt: np.ndarray) -> int:
         """The prompt through the family's one chunk executable: every
         chunk is dispatched behind the last (each takes the state the
         one before left in the slot, so the device runs them in order
-        while the host goes on), and only the last chunk's logits are
+        while the host goes on), and only the last chunk's token is
         waited for."""
         import jax.numpy as jnp
 
@@ -654,45 +727,19 @@ class DecodeEngine:
             with self.phases.child("dispatch"):
                 slot = jnp.int32(sess.slot)
                 for i in range(n):
-                    last, self.pool.arrays = fn(
-                        self.params, self.pool.arrays,
+                    out, self._sampled, self.pool.arrays = fn(
+                        self.params, self.pool.arrays, self._sampled,
                         jnp.asarray(buf[i * c:(i + 1) * c]), slot,
                         jnp.int32(i * c), jnp.int32(min(c, t - i * c)),
                         jnp.bool_(i == n - 1))
             self.prefill_chunks_total += n
             with self.phases.child("wait"):
-                return np.asarray(last)
+                return int(out)
         finally:
             if cold is not None:
                 self.phases.enter(cold)
 
     # -- paged prefill ---------------------------------------------------
-    def _prefill_paged(self, sess) -> int:
-        """Whole-prompt paged prefill: walk :meth:`_advance_chunk` to
-        completion inline (the non-interleaved path — ``chunk == 0``
-        makes it ONE whole-suffix chunk).  ``prefill_mode="step"``
-        instead decodes the prompt token-by-token through the paged
-        step grid (the decode-without-prefill misconfig path, paged)."""
-        if self.prefill_mode == "step":
-            pool = self.pool
-            prompt = sess.prompt
-            first = None
-            for i in range(sess.prefill_pos, sess.plen):
-                pool.grow(sess, i + 1)
-                logits = self._dispatch_paged(
-                    [(sess.table, i, int(prompt[i]))])
-                first = int(np.argmax(logits[0]))
-            pool.note_prefill(sess, sess.plen)
-            sess.pos = sess.plen
-            self.prefills_total += 1
-            self.tokens_total += 1
-            sess.last_step_s = self._clock()
-            return first
-        while True:
-            first = self._advance_chunk(sess)
-            if first is not None:
-                return first
-
     def prefill_chunk_step(self, sess) -> Optional[int]:
         """Advance ``sess``'s prefill by ONE bounded chunk — the
         element's decode loop interleaves these between decode steps so
@@ -711,8 +758,8 @@ class DecodeEngine:
         real positions, dispatch the ``(padded C, width)`` executable
         (origin and real length as traced operands), register any
         newly-full prompt pages with the prefix cache.  Returns the
-        first generated token on the FINAL chunk (argmax of position
-        ``plen - 1``'s logits), else ``None``."""
+        first generated token on the FINAL chunk (sampled from position
+        ``plen - 1``'s logits into the session's row), else ``None``."""
         import jax.numpy as jnp
 
         pool = self.pool
@@ -734,14 +781,16 @@ class DecodeEngine:
         table = np.full((w,), pool.scratch, np.int32)
         m = min(len(sess.table), w)
         table[:m] = sess.table[:m]
+        row = sess.slot if c_real == remaining else pool.slots
         fn = self._chunk_fn(c_pad, w)
         cold = self._enter_cold()
         try:
             with self.phases.child("dispatch"):
-                last, pool.arrays = fn(
-                    self.params, pool.arrays, jnp.asarray(toks),
-                    jnp.asarray(table), jnp.int32(start),
-                    jnp.int32(c_real), jnp.int32(pool.scratch))
+                out, self._sampled, pool.arrays = fn(
+                    self.params, pool.arrays, self._sampled,
+                    jnp.asarray(toks), jnp.asarray(table),
+                    jnp.int32(start), jnp.int32(c_real),
+                    jnp.int32(pool.scratch), jnp.int32(row))
         finally:
             if cold is not None:
                 self.phases.enter(cold)
@@ -751,114 +800,131 @@ class DecodeEngine:
         if sess.prefilling:
             return None
         sess.pos = sess.plen
-        self.prefills_total += 1
-        self.tokens_total += 1
+        self._prefilled(sess)
         with self.phases.child("wait"):
-            last = np.asarray(last)
-        return int(np.argmax(last))
+            return int(out)
 
     # -- decode ----------------------------------------------------------
-    def _dispatch_paged(self, lanes):
-        """(table, pos, token) lanes → one paged step dispatch.  The
-        table width is the max lane's page count pow2-quantized;
-        padding lanes and padding table entries point at the scratch
-        page, so their scatter-appends can never touch a live page."""
+    def _launch(self, sessions: Sequence[Session]):
+        """One step over ``sessions``, each at its ``pos``, sent to the
+        device: the tokens it will sample, still there.  Padding lanes
+        point at the scratch slot (the scratch page of a paged pool),
+        position 0, and at the last row of ``_sampled``: their writes
+        can never touch a live session.  A paged lane's tail page is
+        allocated here (lazily, from the reservation admission made)."""
         import jax.numpy as jnp
 
         pool = self.pool
-        ps = pool.page_size
-        n = len(lanes)
+        forced = [s for s in sessions if s.next_token is not None]
+        if forced:
+            # the rare way in for a token of the caller's: read the
+            # vector (behind whatever is in flight), set, put it back
+            sampled = np.array(self._sampled)
+            for s in forced:
+                sampled[s.slot], s.next_token = s.next_token, None
+            self._sampled = self._jax.device_put(sampled, self._device)
         with self.phases.child("operands"):
-            padded = JitExecMixin.pad_rows(n, self.capacity)
-            w = quantize_pages(max(-(-(p + 1) // ps)
-                                   for _, p, _ in lanes), pool.table_max)
-            toks = np.zeros((padded,), np.int32)
+            padded = JitExecMixin.pad_rows(len(sessions), self.capacity)
+            rows = np.full((padded,), pool.slots, np.int32)
             pos = np.zeros((padded,), np.int32)
-            tables = np.full((padded, w), pool.scratch, np.int32)
-            for i, (table, p, tok) in enumerate(lanes):
-                pos[i], toks[i] = p, tok
-                m = min(len(table), w)
-                tables[i, :m] = table[:m]
-            toks, pos, tables = (jnp.asarray(toks), jnp.asarray(pos),
-                                 jnp.asarray(tables))
-        fn = self._pstep_fn(padded, w)
+            for i, s in enumerate(sessions):
+                rows[i], pos[i] = s.slot, s.pos
+            operands = [jnp.asarray(pos), jnp.asarray(rows)]
+            if self.paged:
+                ps = pool.page_size
+                for s in sessions:
+                    pool.grow(s, s.pos + 1)
+                w = quantize_pages(max(-(-(s.pos + 1) // ps)
+                                       for s in sessions), pool.table_max)
+                tables = np.full((padded, w), pool.scratch, np.int32)
+                for i, s in enumerate(sessions):
+                    m = min(len(s.table), w)
+                    tables[i, :m] = s.table[:m]
+                operands.append(jnp.asarray(tables))
+                fn = self._pstep_fn(padded, w)
+            else:
+                fn = self._step_fn(padded)
         cold = self._enter_cold()
         try:
             with self.phases.child("dispatch"):
-                logits, pool.arrays = fn(
-                    self.params, pool.arrays, toks, pos, tables)
-            with self.phases.child("wait"):
-                return np.asarray(logits)[:n]
+                out, self._sampled, pool.arrays = fn(
+                    self.params, pool.arrays, self._sampled, *operands)
         finally:
             if cold is not None:
                 self.phases.enter(cold)
+        return out
 
-    def _lane_arrays(self, lanes: Sequence[Tuple[int, int, int]]):
-        """(slot, pos, token) lanes → padded device operands.  Padding
-        lanes point at the pool's scratch slot, position 0 — their
-        scatter writes land in scratch, their gathered logits are
-        sliced away."""
-        import jax.numpy as jnp
+    @property
+    def in_flight(self) -> int:
+        """Steps dispatched and not yet collected."""
+        return len(self._flights)
 
-        n = len(lanes)
-        with self.phases.child("operands"):
-            padded = JitExecMixin.pad_rows(n, self.capacity)
-            slots = np.full((padded,), self.pool.scratch, np.int32)
-            pos = np.zeros((padded,), np.int32)
-            toks = np.zeros((padded,), np.int32)
-            for i, (slot, p, tok) in enumerate(lanes):
-                slots[i], pos[i], toks[i] = slot, p, tok
-            return (jnp.asarray(toks), jnp.asarray(pos),
-                    jnp.asarray(slots), padded, n)
-
-    def _dispatch(self, toks, pos, slots, padded: int, n: int):
-        fn = self._step_fn(padded)
-        cold = self._enter_cold()
-        try:
-            with self.phases.child("dispatch"):
-                logits, self.pool.arrays = fn(
-                    self.params, self.pool.arrays, toks, pos, slots)
-            with self.phases.child("wait"):
-                return np.asarray(logits)[:n]
-        finally:
-            if cold is not None:
-                self.phases.enter(cold)
-
-    def step(self, sessions: Sequence[Session]) -> List[int]:
-        """One continuous-batching decode step over ``sessions`` (≤
-        ``capacity``; the element's round-robin pick): consumes each
-        session's ``next_token``, advances its cache position, returns
-        the greedily-sampled NEXT token per session (the caller emits
-        it and decides stop-token/max-new completion)."""
-        if not sessions:
-            return []
+    def dispatch(self, sessions: Sequence[Session]) -> None:
+        """Send one continuous-batching decode step over ``sessions`` (≤
+        ``capacity``; the element's round-robin pick) and return without
+        waiting for it: each lane consumes the token the chip sampled
+        for its stream last and advances its cache position.  The
+        step's tokens are :meth:`collect`'s to read."""
+        if len(self._flights) > 1:
+            raise RuntimeError("two steps in flight: collect() the "
+                               "older before a third is dispatched")
         t0 = self._clock()
-        prev = self.phases.enter("decode", step=self.steps_total,
-                                 lanes=len(sessions))
-        if self.paged:
-            for s in sessions:
-                self.pool.grow(s, s.pos + 1)   # lazy tail-page alloc
-            logits = self._dispatch_paged(
-                [(s.table, s.pos, s.next_token) for s in sessions])
-        else:
-            lanes = [(s.slot, s.pos, s.next_token) for s in sessions]
-            logits = self._dispatch(*self._lane_arrays(lanes))
+        ahead = len(self._flights)
+        prev = self.phases.enter("decode", step=self.steps_total + ahead,
+                                 lanes=len(sessions), ahead=ahead)
+        try:
+            out = self._launch(sessions)
+        finally:
+            self.phases.enter(prev)
+        for s in sessions:
+            s.pos += 1
+            s.in_flight += 1
+        self.steps_ahead += ahead
+        self._flights.append((out, list(sessions), t0))
+
+    def collect(self) -> List[Tuple[Session, int]]:
+        """Wait for the oldest step in flight, read its ``B`` int32 and
+        return its lanes, each with the NEXT token of its stream (the
+        caller emits it and decides stop-token/max-new completion).  A
+        lane whose session the pool took back while the step ran is
+        left out and counted in ``lanes_discarded``."""
+        out, sessions, t0 = self._flights.popleft()
+        prev = self.phases.enter("decode")
+        with self.phases.child("wait"):
+            tokens = np.asarray(out).tolist()
         with self.phases.child("sample"):
-            out = np.argmax(logits, axis=1).astype(np.int32)
             now = self._clock()
-            for s in sessions:
-                s.pos += 1
-                s.last_step_s = now
+            lanes = []
+            for s, tok in zip(sessions, tokens):
+                s.in_flight -= 1
+                if not s.released:
+                    s.last_step_s = now
+                    lanes.append((s, tok))
             self.steps_total += 1
-            self.tokens_total += len(sessions)
-            self.step_tokens += len(sessions)
+            self.tokens_total += len(lanes)
+            self.step_tokens += len(lanes)
+            self.lanes_discarded += len(sessions) - len(lanes)
             self.last_fill = len(sessions)
-            dt = now - t0
+            # the loop's period where steps run one behind another, the
+            # step's own time where each is collected at once
+            dt = now - max(t0, self._collected_s)
+            self._collected_s = now
             self.ewma_step_s = (dt if self.ewma_step_s == 0.0
                                 else 0.8 * self.ewma_step_s + 0.2 * dt)
-            out = [int(t) for t in out]
         self.phases.enter(prev)
-        return out
+        return lanes
+
+    def step(self, sessions: Sequence[Session]) -> List[int]:
+        """:meth:`dispatch` then :meth:`collect`: one synchronous step,
+        the next token per session — for callers that run no step
+        ahead."""
+        if not sessions:
+            return []
+        if self._flights:
+            raise RuntimeError("step() with a step in flight: collect() "
+                               "it first")
+        self.dispatch(sessions)
+        return [tok for _, tok in self.collect()]
 
     # -- hints / report --------------------------------------------------
     def retry_after_hint(self) -> float:
@@ -878,6 +944,8 @@ class DecodeEngine:
         out = {
             "tokens": self.tokens_total,
             "steps": self.steps_total,
+            "steps_ahead": self.steps_ahead,
+            "lanes_discarded": self.lanes_discarded,
             "prefills": self.prefills_total,
             "mean_fill": round(self.step_tokens
                                / max(1, self.steps_total), 2),

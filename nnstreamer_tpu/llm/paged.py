@@ -71,7 +71,9 @@ def chain_hashes(prompt: np.ndarray, page_size: int) -> List[bytes]:
 @dataclasses.dataclass
 class PagedSession(Session):
     """A :class:`~nnstreamer_tpu.llm.pool.Session` whose cache is a
-    block table instead of a slot (``slot`` stays ``-1``)."""
+    block table instead of a slot: ``slot`` numbers the resident
+    sessions (``0 .. slots - 1``) and names no memory of the arena, only
+    the session's row of the engine's sampled tokens."""
 
     table: List[int] = dataclasses.field(default_factory=list)
     plen: int = 0                 # prompt length (positions 0..plen-1)
@@ -133,6 +135,7 @@ class PagedKVCachePool:
         self.k = jnp.zeros(shape, cfg.dtype)
         self.v = jnp.zeros(shape, cfg.dtype)
         self._free: List[int] = list(range(self.pages))
+        self._free_slots: List[int] = list(range(self.slots))
         self._live: Dict[Any, PagedSession] = {}
         self._order = 0
         self._reserved = 0                 # sum of live sess.reserve
@@ -292,7 +295,7 @@ class PagedKVCachePool:
                 table.append(pg)
             self._order += 1
             sess = PagedSession(
-                key=key, slot=-1, qos=qos or "silver",
+                key=key, slot=self._free_slots.pop(), qos=qos or "silver",
                 extra=dict(extra or {}), born_s=now, last_step_s=now,
                 order=self._order, table=table, plen=plen,
                 prefill_pos=hit * self.page_size, prompt=arr,
@@ -390,6 +393,8 @@ class PagedKVCachePool:
                 else:
                     self._free.append(pg)
             self._reserved -= sess.reserve
+            self._free_slots.append(sess.slot)
+            sess.released = True
             sess.reserve = 0
             sess.table = []
             sess.prompt = None

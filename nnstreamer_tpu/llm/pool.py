@@ -80,7 +80,13 @@ class Session:
     key: Any                    # (client_id, wire seq) — or a local id
     slot: int                   # cache slot id (stable for the life)
     pos: int = 0                # next cache write position
-    next_token: int = 0         # token the next decode step consumes
+    #: a token the CALLER makes the next decode step consume in place of
+    #: the one the chip sampled for this stream and kept (teacher
+    #: forcing, a prompt decoded step by step); ``None``, as the element
+    #: leaves it: the chip's own.  The step that consumes it clears it.
+    next_token: Optional[int] = None
+    in_flight: int = 0          # steps dispatched, tokens not yet read
+    released: bool = False      # the pool took the slot back
     emitted: int = 0            # tokens answered so far
     max_new: int = 0            # granted continuation length
     stop_token: int = -1        # ends the stream when emitted (<0: none)
@@ -192,6 +198,7 @@ class SlotPool:
             sess = self._live.pop(key, None)
             if sess is not None:
                 self._free.append(sess.slot)
+                sess.released = True
             return sess
 
     def touch(self, key) -> None:

@@ -212,8 +212,9 @@ class TestPooledDecode:
     def test_engine_prefill_then_pooled_steps_match_decode_scan(self):
         """test_prefill_kv_matches_decode_scan's contract through the
         engine: ``_prefill`` installs the run into its slot's rows (and
-        no other slot's), and pooled steps from there on give the logits
-        a decode_step scan over prompt + continuation gives."""
+        no other slot's), and pooled steps from there on, each fed the
+        continuation's token (``next_token``), sample the token a
+        decode_step scan over prompt + continuation gives."""
         cfg = _cfg(layers=3)
         params = init_params(cfg, 4)
         rng = np.random.default_rng(1)
@@ -241,10 +242,10 @@ class TestPooledDecode:
             others = np.delete(arr, sess.slot, axis=1)
             assert not others.any()      # the install touched one slot
         for i, t in enumerate(cont):
-            logits = eng._dispatch(*eng._lane_arrays(
-                [(sess.slot, 11 + i, int(t))]))
-            np.testing.assert_allclose(logits[0], scan[11 + i],
-                                       atol=1e-4, rtol=1e-4)
+            assert sess.pos == 11 + i
+            sess.next_token = int(t)
+            assert eng.step([sess]) == [int(np.argmax(scan[11 + i]))]
+            assert sess.next_token is None       # consumed by the step
 
     @staticmethod
     def _written_pools(cfg, slots, seed, scale=1.0):
@@ -1543,6 +1544,87 @@ class TestPagedElementLocal:
         p.stop()
         assert sum(len(v) for v in by_key.values()) == 5 + 7 + 6 + 8
         assert compiles == warm, (warm, compiles)
+
+
+# ---------------------------------------------------------------------------
+# one decode step in flight (tests/llm_ahead.py holds the scenario)
+# ---------------------------------------------------------------------------
+
+class TestOneStepInFlight:
+    """The element dispatches step k before it has read step k-1: the
+    streams it serves are the synchronous path's and ``generate()``'s,
+    to the token, whatever the pool and however the lanes are picked."""
+
+    @pytest.fixture(scope="class")
+    def requests(self):
+        import llm_ahead
+
+        family, cfg, params = llm_ahead.world(CUSTOM, 0)
+        eng = DecodeEngine(params, cfg, KVCachePool(cfg, 1, family=family),
+                           capacity=1)
+        out = llm_ahead.requests_for(cfg, 24, eng)
+        for (prompt, max_new, stop), want in out:
+            ref = generate(params, cfg, prompt, max_new).tolist()
+            assert want == (ref if stop < 0
+                            else ref[:ref.index(stop) + 1])
+        # the fifth ends on its slot's last row but one: prompt +
+        # max_new == max_seq, the most admission grants
+        prompt, max_new, _ = out[4][0]
+        assert len(prompt) + max_new == cfg.max_seq == 48
+        return out
+
+    @pytest.mark.parametrize("props", [
+        "slots=6 batch=6",                       # every stream a lane
+        "slots=4 batch=2",                       # round-robin pick
+        "slots=2 batch=2",                       # slots reused
+        "slots=4 batch=4 page-size=16 prefill-chunk=0",
+        "slots=2 batch=2 page-size=16 prefill-chunk=0",
+        "slots=4 batch=2 page-size=4 prefill-chunk=4",   # interleaved
+    ])
+    def test_streams_are_the_synchronous_paths(self, requests, props):
+        import llm_ahead
+
+        got, report = llm_ahead.serve(CUSTOM, 0, props, 24, requests)
+        llm_ahead.check(got, report, requests,
+                        every_lane="batch=2" not in props
+                        or "slots=2" in props)
+        if "page-size" in props:
+            assert report["paged"]["live"] == 0
+            assert report["paged"]["free"] + report["paged"][
+                "reclaimable"] == report["paged"]["pages"]
+
+    def test_drain_with_a_step_in_flight_finishes_every_stream(
+            self, requests):
+        import llm_ahead
+
+        got, report = llm_ahead.serve(CUSTOM, 0, "slots=6 batch=4", 24,
+                                      requests, drain=True)
+        assert report["live_after_drain"] == 0
+        llm_ahead.check(got, report, requests, every_lane=False)
+
+    def test_step_refuses_to_run_beside_a_step_in_flight(self):
+        cfg = _cfg()
+        pool = KVCachePool(cfg, 2)
+        eng = DecodeEngine(init_params(cfg, 1), cfg, pool, capacity=2)
+        a, b = pool.acquire("a"), pool.acquire("b")
+        for s in (a, b):
+            s.max_new = 8
+            eng.prefill(s, np.asarray([3, 1, 4], np.int32))
+        eng.dispatch([a, b])
+        assert eng.in_flight == 1 and (a.pos, a.in_flight) == (4, 1)
+        with pytest.raises(RuntimeError, match="in flight"):
+            eng.step([a])
+        eng.dispatch([a])                      # one ahead of the other
+        with pytest.raises(RuntimeError, match="two steps in flight"):
+            eng.dispatch([a])
+        pool.release("b")                      # ends while its lane runs
+        first = eng.collect()
+        assert [s.key for s, _ in first] == ["a"]
+        assert eng.lanes_discarded == 1 and b.in_flight == 0
+        (sess, tok), = eng.collect()
+        assert sess is a and a.in_flight == 0 and eng.in_flight == 0
+        assert eng.steps_total == 2 and eng.steps_ahead == 1
+        assert eng.step_tokens == 2
 
 
 # ---------------------------------------------------------------------------

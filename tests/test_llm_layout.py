@@ -72,6 +72,8 @@ def described(topo):
     engine = DecodeEngine({}, cfg, KVCachePool(cfg, 1), capacity=1)
     yield {"engine": engine, "params": params, "pool": pool,
            "pool_bytes": 2 * pool.size * pool.dtype.itemsize,
+           # a token a slot, sampled on the chip, beside the pools
+           "sampled": on_chip(config["element"]["slots"] + 1),
            "on_chip": on_chip}
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
@@ -94,7 +96,8 @@ def _compiled_step(d, lanes):
     if lanes not in steps:
         vec = d["on_chip"](lanes)
         steps[lanes] = d["engine"]._step_fn(lanes).lower(
-            d["params"], (d["pool"], d["pool"]), vec, vec, vec).compile()
+            d["params"], (d["pool"], d["pool"]), d["sampled"], vec,
+            vec).compile()
     return steps[lanes]
 
 
@@ -124,8 +127,8 @@ def test_decode_step_reads_the_rows_as_they_lie(described, lanes):
 def test_dense_prefill_keeps_the_pool_still(described, padded_t):
     d = described
     compiled = d["engine"]._prefill_fn(padded_t).lower(
-        d["params"], (d["pool"], d["pool"]), d["on_chip"](padded_t),
-        d["on_chip"](), d["on_chip"]()).compile()
+        d["params"], (d["pool"], d["pool"]), d["sampled"],
+        d["on_chip"](padded_t), d["on_chip"](), d["on_chip"]()).compile()
     _check(compiled, d["pool_bytes"], 1 / 4)       # found: 0.04, 0.44 GB
 
 
@@ -166,6 +169,7 @@ def hybrid(topo, described):
             "params": shapes(lambda: family.init_params(cfg, 0)),
             "state": shapes(lambda: family.init_state(
                 cfg, config["element"]["slots"])),
+            "sampled": on_chip(config["element"]["slots"] + 1),
             "on_chip": on_chip}
 
 
@@ -186,7 +190,7 @@ def test_hybrid_decode_step_fits_the_chip_and_has_no_loop(hybrid):
     lanes = h["config"]["element"]["batch"]
     vec = h["on_chip"](lanes)
     compiled = h["engine"]._step_fn(lanes).lower(
-        h["params"], h["state"], vec, vec, vec).compile()
+        h["params"], h["state"], h["sampled"], vec, vec).compile()
     stats = compiled.memory_analysis()
     plan = h["config"]["memory_plan"]
     assert stats.argument_size_in_bytes >= (plan["weights_bytes"]
@@ -207,8 +211,8 @@ def test_hybrid_prefill_chunk_fits_beside_the_pools(hybrid):
     import jax.numpy as jnp
 
     compiled = h["engine"]._prefill_fn(h["cfg"].chunk).lower(
-        h["params"], h["state"], i32(h["cfg"].chunk), i32(), i32(), i32(),
-        i32(dtype=jnp.bool_)).compile()
+        h["params"], h["state"], h["sampled"], i32(h["cfg"].chunk), i32(),
+        i32(), i32(), i32(dtype=jnp.bool_)).compile()
     stats = compiled.memory_analysis()
     assert stats.alias_size_in_bytes >= h["config"]["memory_plan"][
         "pool_bytes"]

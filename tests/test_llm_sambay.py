@@ -407,12 +407,61 @@ def test_element_serves_the_family_from_the_launch_line():
     assert kinds == {k: float(v) for k, v in by_kind.items()}
 
 
+# -- one decode step in flight (tests/llm_ahead.py holds the scenario) ----
+@pytest.fixture(scope="module")
+def ahead_requests():
+    import llm_ahead
+
+    family, cfg, params = llm_ahead.world(CUSTOM, 3)
+    assert family is sm.FAMILY
+    eng = DecodeEngine(params, cfg, KVCachePool(cfg, 1, family=family),
+                       capacity=1)
+    out = llm_ahead.requests_for(cfg, 3 + 40, eng)
+    # the synchronous path is the reference's greedy continuation
+    (prompt, max_new, _), want = out[0]
+    seq = np.concatenate([prompt, np.asarray(want[:-1], np.int32)])
+    logits = ref.forward_logits(params, seq, MODEL)[len(prompt) - 1:]
+    assert want == [int(r.argmax()) for r in logits]
+    # prompts of one to five chunks of 8; the fifth request fills its
+    # slot (rows, rings and recurrent rows) to max_seq exactly
+    assert sorted(-(-len(r[0][0]) // 8) for r in out) == [1, 1, 1, 2, 2, 5]
+    assert len(out[4][0][0]) + out[4][0][1] == cfg.max_seq
+    return out
+
+
+@pytest.mark.parametrize("props, every_lane", [
+    ("slots=6 batch=6", True),          # every stream a lane
+    ("slots=4 batch=2", False),         # round-robin pick
+    ("slots=2 batch=2", True)])         # slots, rings and rows reused
+def test_element_one_step_ahead_serves_the_synchronous_streams(
+        ahead_requests, props, every_lane):
+    """The element dispatches step k before it has read step k-1; a
+    stream that ends by its stop token rides one more step, which writes
+    one more row, ring entry and recurrent state into a slot the next
+    prompt's first chunk then starts clean."""
+    import llm_ahead
+
+    got, report = llm_ahead.serve(CUSTOM, 3, props, 3 + 40, ahead_requests)
+    llm_ahead.check(got, report, ahead_requests, every_lane=every_lane)
+    assert report["prefill_chunks"] == 12
+
+
+def test_element_drains_with_a_step_in_flight(ahead_requests):
+    import llm_ahead
+
+    got, report = llm_ahead.serve(CUSTOM, 3, "slots=6 batch=4", 3 + 40,
+                                  ahead_requests, drain=True)
+    assert report["live_after_drain"] == 0
+    llm_ahead.check(got, report, ahead_requests, every_lane=False)
+
+
 # -- streamformer_lm through the same seam -------------------------------
 def test_streamformer_through_the_seam_is_bit_equal():
     """The default family's engine (state as one donated tuple, the
-    prefill's install moved into the family) gives, to the bit, what the
-    model's own functions give when called as the engine used to call
-    them: prefill, then steps at one lane and at a padded bucket."""
+    prefill's install moved into the family) gives, to the bit, the
+    pools the model's own functions give when called as the engine used
+    to call them, and samples the greedy token of their logits: prefill,
+    then steps at one lane and at a padded bucket."""
     from nnstreamer_tpu.models import streamformer_lm as sf
     from nnstreamer_tpu.parallel.train_step import init_params
 
@@ -442,9 +491,11 @@ def test_streamformer_through_the_seam_is_bit_equal():
     sess = pool.acquire("a")
     sess.slot = 1
     fn = eng._prefill_fn(16)
-    last, pool.arrays = fn(eng.params, pool.arrays, jnp.asarray(buf),
-                           jnp.int32(1), jnp.int32(11))
-    assert np.array_equal(np.asarray(last), want_first)
+    first, sampled, pool.arrays = fn(
+        eng.params, pool.arrays, jnp.zeros((4,), jnp.int32),
+        jnp.asarray(buf), jnp.int32(1), jnp.int32(11))
+    assert int(first) == int(np.argmax(want_first))
+    assert np.asarray(sampled).tolist() == [0, int(first), 0, 0]
     assert np.array_equal(np.asarray(pool.k, np.float32),
                           np.asarray(k, np.float32))
     step = jax.jit(partial(sf.decode_step_pooled, cfg=cfg))
@@ -453,10 +504,14 @@ def test_streamformer_through_the_seam_is_bit_equal():
         pos = jnp.asarray([11] + [0] * (lanes - 1), jnp.int32)
         slots = jnp.asarray([1] + [3] * (lanes - 1), jnp.int32)
         want, k2, v2 = step(params, k, v, toks, pos, slots)
-        got, state = eng._step_fn(lanes)(eng.params, pool.arrays, toks,
-                                         pos, slots)
+        # the lanes' tokens come from the rows their slots name
+        got, sampled, state = eng._step_fn(lanes)(
+            eng.params, pool.arrays, jnp.asarray([0, 7, 0, 0], jnp.int32),
+            pos, slots)
         pool.arrays = state
-        assert np.array_equal(np.asarray(got), np.asarray(want))
+        assert got.dtype == jnp.int32 and np.array_equal(
+            np.asarray(got), np.argmax(np.asarray(want), -1))
+        assert int(sampled[1]) == int(got[0])
         assert np.array_equal(np.asarray(state[1], np.float32),
                               np.asarray(v2, np.float32))
         k, v = k2, v2

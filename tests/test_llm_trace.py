@@ -64,6 +64,7 @@ def _traced(props, requests, trace_dir):
         src.end_of_stream()
         p.wait(timeout=120)
         counts = {"steps": eng.steps_total - steps0,
+                  "report": eng.report(),
                   "prefills": eng.prefills_total - prefills0,
                   "chunks": eng.prefill_chunks_total - chunks0,
                   "shed": llm.shed_total, "rejected": llm.rejected_total}
@@ -130,6 +131,30 @@ def test_one_decode_span_with_a_step_per_engine_step(round_trip):
     assert numbers == list(range(numbers[0], numbers[0] + len(steps)))
     assert {s[3]["lanes"] for s in steps} <= {1, 2}
     assert steps[0][3]["lanes"] == 2
+
+
+def test_every_step_but_the_first_of_a_busy_run_is_dispatched_ahead(
+        round_trip):
+    """Both streams are resident before the first step and one goes on
+    until the last: every step but the first was sent while the step
+    before was uncollected, and says so; the engine counts them."""
+    spans, counts = round_trip
+    steps = [s[3] for s in spans if s[0] == "llm.decode" and "step" in s[3]]
+    assert [s["ahead"] for s in steps] == [0] + [1] * (len(steps) - 1)
+    report = counts["report"]
+    assert report["steps"] == counts["steps"]
+    assert report["steps_ahead"] == counts["steps"] - 1
+    assert report["lanes_discarded"] == 0
+    # the pieces of ``llm.decode`` that carry no ``step`` are the
+    # collects: they hold ``wait`` and ``sample``, of the step before
+    # the one whose ``operands`` and ``dispatch`` the piece before holds
+    bare = [s for s in spans if s[0] == "llm.decode" and not s[3]]
+    waits = [s for s in spans if s[0] == "llm.decode.wait"]
+    assert len(bare) == len(waits) == counts["steps"]
+    assert all(b[1] <= w[1] and w[2] <= b[2] for b, w in zip(bare, waits))
+    sends = [s for s in spans if s[0] == "llm.decode.dispatch"]
+    # step k is sent before step k-1 is waited for
+    assert all(sends[k][1] < waits[k - 1][1] for k in range(1, len(sends)))
 
 
 def test_phase_spans_are_a_partition_of_the_threads_time(round_trip):
@@ -269,36 +294,74 @@ ALL_SCOPES = MATH | {"sflm.kv_write", "sflm.kv_read"}
 def _lowered(program):
     """``as_text(debug_info=True)`` of one step program at a toy size
     (the plain ``as_text()`` drops the names)."""
+    fn, args = _program(_engine(CUSTOM, program in ("pstep", "chunk")),
+                        program)
+    return fn.lower(*args).as_text(debug_info=True)
+
+
+def _program(eng, program, lanes=2):
+    """One of the engine's four executables at a toy size, and operands
+    to lower it with."""
     import jax.numpy as jnp
 
-    from nnstreamer_tpu.llm.paged import PagedKVCachePool
-    from nnstreamer_tpu.llm.pool import KVCachePool
-    from nnstreamer_tpu.models.streamformer_lm import config_from_custom
-    from nnstreamer_tpu.parallel.train_step import init_params
-
-    cfg = config_from_custom(dict(kv.split(":")
-                                  for kv in CUSTOM.split(",")))
-    params = init_params(cfg, 0)
-    paged = program in ("pstep", "chunk")
-    pool = (PagedKVCachePool(cfg, pages=12, page_size=8, slots=2) if paged
-            else KVCachePool(cfg, 2))
-    eng = DecodeEngine(params, cfg, pool, capacity=2)
-    i32 = jnp.int32
-    lanes = jnp.zeros((2,), i32)
+    i32, pool = jnp.int32, eng.pool
+    vec = jnp.zeros((lanes,), i32)
     if program == "step":
-        fn, args = eng._step_fn(2), (lanes, lanes, lanes)
+        fn, args = eng._step_fn(lanes), (vec, vec)
     elif program == "pstep":
-        fn = eng._pstep_fn(2, 2)
-        args = (lanes, lanes, jnp.zeros((2, 2), i32))
+        fn = eng._pstep_fn(lanes, 2)
+        args = (vec, vec, jnp.zeros((lanes, 2), i32))
+    elif program == "prefill" and eng.chunk_len:
+        fn = eng._prefill_fn(eng.chunk_len)
+        args = (jnp.zeros((eng.chunk_len,), i32), i32(0), i32(0), i32(1),
+                jnp.bool_(True))
     elif program == "prefill":
         fn = eng._prefill_fn(8)
         args = (jnp.zeros((8,), i32), i32(0), i32(1))
     else:
         fn = eng._chunk_fn(8, 2)
         args = (jnp.zeros((8,), i32), jnp.zeros((2,), i32), i32(0),
-                i32(1), i32(pool.scratch))
-    return fn.lower(eng.params, pool.arrays, *args).as_text(
-        debug_info=True)
+                i32(1), i32(pool.scratch), i32(0))
+    return fn, (eng.params, pool.arrays, eng._sampled, *args)
+
+
+def _engine(family_custom, paged):
+    import llm_ahead
+    from nnstreamer_tpu.llm.paged import PagedKVCachePool
+    from nnstreamer_tpu.llm.pool import KVCachePool
+
+    family, cfg, params = llm_ahead.world(family_custom, 0)
+    pool = (PagedKVCachePool(cfg, pages=12, page_size=8, slots=2) if paged
+            else KVCachePool(cfg, 2, family=family))
+    return DecodeEngine(params, cfg, pool, capacity=2)
+
+
+SAMBAY = ("arch:sambay_lm,vocab:61,dim:32,heads:4,kv_heads:2,head_dim:8,"
+          "mlp:64,layers:8,window:8,d_state:4,dt_rank:2,max_seq:64,"
+          "dtype:float32")
+
+
+@pytest.mark.parametrize("custom, program", [
+    (CUSTOM, "step"), (CUSTOM, "pstep"), (CUSTOM, "prefill"),
+    (CUSTOM, "chunk"), (SAMBAY, "step"), (SAMBAY, "prefill")])
+def test_no_executable_of_the_warm_grid_returns_logits(custom, program):
+    """What leaves each executable: the sampled tokens (``int32``, a
+    lane each; one for a prefill), the ``(slots + 1,)`` vector they are
+    kept in, and the donated state — nothing with a vocabulary axis."""
+    eng = _engine(custom, paged=program in ("pstep", "chunk"))
+    lanes = 2
+    fn, args = _program(eng, program, lanes)
+    out = fn.lower(*args).out_info
+    tokens, sampled, state = out
+    assert tokens.dtype == np.int32
+    assert tokens.shape == ((lanes,) if "step" in program else ())
+    assert sampled.dtype == np.int32 and sampled.shape == (3,)
+    assert [(s.shape, s.dtype) for s in state] == [
+        (a.shape, a.dtype) for a in eng.pool.arrays]
+    vocab = eng.cfg.vocab
+    import jax
+    for leaf in jax.tree_util.tree_leaves(out):
+        assert vocab not in leaf.shape, leaf
 
 
 @pytest.mark.parametrize("program, scopes", [
