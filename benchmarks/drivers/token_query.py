@@ -41,10 +41,15 @@ class Driver:
     def open(self) -> None:
         """Build and start the pipeline; ``tensor_llm.start`` builds the
         weights from the seed and warms every shape it serves."""
-        from nnstreamer_tpu import parse_launch
+        from nnstreamer_tpu import native, parse_launch
         from nnstreamer_tpu.llm.element import REQ_HEADER
 
         t = time.monotonic()
+        # the wire's CRC builds ``native/libnnstw.so`` on first use (a
+        # ``make`` of about a second in a fresh checkout): here, in
+        # set-up, and not under the window's first requests, which at
+        # 64 requests/s queue behind it and are shed
+        native.available()
         self.frame_len = REQ_HEADER + self.model["max_seq"]
         custom = ",".join(f"{k}:{v}" for k, v in self.model.items())
         props = " ".join(f"{k}={v}"
@@ -200,9 +205,11 @@ class Driver:
         vocab = self.model["vocab"]
         done = [r for r in run.requests if r["outcome"] == "done"]
         cut = [r for r in run.requests if r["outcome"] == "cut"]
-        lengths_ok = all(len(r["tokens"]) == r["max_new"] for r in done)
-        in_vocab = all(0 <= t < vocab for r in done + cut
-                       for t in r["tokens"])
+        wrong_length = sum(1 for r in done
+                           if len(r["tokens"]) != r["max_new"])
+        outside_vocab = sum(1 for r in done + cut for t in r["tokens"]
+                            if not 0 <= t < vocab)
+        lengths_ok, in_vocab = not wrong_length, not outside_vocab
         # a stream cut at the run's end is judged on what it had served
         # (at 1.3 s a step no 256-token stream ends inside a window)
         judged = done + [r for r in cut if len(r["tokens"]) >= MIN_JUDGED]
@@ -235,6 +242,17 @@ class Driver:
         out["correct"] = bool(sampled and lengths_ok and in_vocab
                               and share >= float(ref["min_share"])
                               and not run.counters["compiles"])
+        # what ``correct`` compared, each beside its limit
+        out["compared"] = {
+            "near_top_share": {"value": share,
+                               "limit": f">= {float(ref['min_share'])}"},
+            "sampled_streams": {"value": sampled, "limit": ">= 1"},
+            "streams_of_another_length": {"value": wrong_length,
+                                          "limit": "== 0"},
+            "tokens_outside_vocab": {"value": outside_vocab,
+                                     "limit": "== 0"},
+            "compiles_in_window": {
+                "value": len(run.counters["compiles"]), "limit": "== 0"}}
         return out
 
     def _requests_of(self, run: Run) -> List[Dict[str, Any]]:

@@ -1,11 +1,13 @@
 """Token element: milliseconds a decode step leaves the chip idle — the
 device's idle time inside a whole number of decode steps of the traced
 slice (``benchmarks/spans.py``: no operation of the ``XLA Ops`` line
-running) over those steps.  Everything the one decode thread does
-between two dispatches lands here: the logits' copy out, the host
-argmax, 32 token frames pushed one by one, the lock, pruning,
-admission, the operands.  The split by program span is printed on the
-line before the result (``trace.program.idle_by_span``)."""
+running) over those steps.  Whatever the one decode thread does that
+the step in flight does not hide lands here (PR 34: step k is queued
+before step k-1's tokens are read and sent, so the egress, the lock,
+pruning and the operands run beside the device): a synchronous
+prefill's own gaps and the host after it, admission, and any iteration
+of the host longer than the step.  The split by program span is printed
+on the line before the result (``trace.program.idle_by_span``)."""
 
 from benchmarks import spans
 

@@ -14,7 +14,10 @@ the clients) is timed as ``setup_s``; then the cell's traffic runs for
 The last line of stdout is the result the driver parses: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` and, traced,
 ``breakdown`` — the cell's end-to-end metrics with ``--trace 0``, its
-per-layer metrics with ``--trace 1``.  Everything else a reader may want
+per-layer metrics with ``--trace 1`` — and last ``compared``: each
+number ``correct`` was decided by, beside its limit (the same go out as
+the last lines of stderr, which is what the driver's record keeps of a
+run that was not correct).  Everything else a reader may want
 (percentiles, lateness, the set-up split, the checks) is on the lines
 before it.
 """
@@ -29,7 +32,7 @@ import argparse   # noqa: E402
 import json       # noqa: E402
 import os         # noqa: E402
 import sys        # noqa: E402
-from typing import Any, Dict, List   # noqa: E402
+from typing import Any, Dict, List, Optional   # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -187,8 +190,12 @@ def describe(run: Run) -> Dict[str, Any]:
 
 
 def result_line(correct: bool, run: Run, metrics: Dict[str, Any],
-                device: Dict[str, Any]) -> Dict[str, Any]:
-    """The contract's object: these keys and no others."""
+                device: Dict[str, Any],
+                compared: Optional[Dict[str, Any]] = None
+                ) -> Dict[str, Any]:
+    """The contract's object: these keys, and ``compared`` (each number
+    the check compared, with its limit) last where the driver's check
+    gave any."""
     line = {"correct": bool(correct), "attempted": len(run.requests),
             "failed": sum(1 for r in run.requests if not r["ok"]
                           and r["outcome"] != "cut"),
@@ -198,6 +205,8 @@ def result_line(correct: bool, run: Run, metrics: Dict[str, Any],
                               window_s=run.trace["window_s"])
         line["breakdown"] = {"device_ops": run.trace["device_ops"],
                              "idle_gaps": run.trace["idle_gaps"]}
+    if compared:
+        line["compared"] = compared
     return line
 
 
@@ -227,10 +236,12 @@ def main(argv=None) -> int:
         driver.open()
         run = driver.window(ctx.traffic, args.seed, args.seconds,
                             bool(args.trace))
+        # the window's peak: a process's peak never falls again, so it
+        # is read before the reference check allocates its own arrays
+        device["memory_peak_bytes"] = memory_peak_bytes()
         checks = driver.check(run)
     finally:
         driver.close()
-    device["memory_peak_bytes"] = memory_peak_bytes()
     kind = "per_layer" if args.trace else "end_to_end"
     metrics = read_metrics(manifest, run, kind)
     print(json.dumps({"cell": cell["name"], "seed": args.seed,
@@ -241,8 +252,12 @@ def main(argv=None) -> int:
     print(json.dumps({"also": read_metrics(manifest, run, other),
                       "trace": {k: v for k, v in (run.trace or {}).items()
                                 if k not in ("counters",)}}), flush=True)
-    print(json.dumps(result_line(checks["correct"], run, metrics, device)),
-          flush=True)
+    compared = checks.get("compared") or {}
+    print(json.dumps(result_line(checks["correct"], run, metrics, device,
+                                 compared)), flush=True)
+    for name, got in compared.items():
+        print(f"compared {name} {got['value']!r} limit {got['limit']}",
+              file=sys.stderr, flush=True)
     return 0
 
 

@@ -5,6 +5,13 @@ Only the process that holds the chip can trace it, so this runs in the
 driver's process.  The Python tracer is off: it would record every frame
 of every reader thread and slow the host it is measuring; the host's
 own ``TraceMe`` events (dispatch, transfers) stay on and name the gaps.
+
+The slice's window is a span in the trace itself (``bench.slice``,
+:data:`benchmarks.xplane.SLICE_SPAN`): opened once ``start_trace`` has
+returned, closed before ``stop_trace`` is called, since a span is
+recorded when it closes.  The reducer clips the device's operations to
+it, so the window and the busy time are read off one clock; no host
+clock enters either.
 """
 
 from __future__ import annotations
@@ -33,14 +40,12 @@ def traced_slice(trace_dir: str) -> Iterator[Slice]:
     options.enable_hlo_proto = False     # op names do not need it
     out = Slice()
     jax.profiler.start_trace(trace_dir, profiler_options=options)
-    t = time.monotonic()
     try:
-        yield out
+        with jax.profiler.TraceAnnotation(xplane.SLICE_SPAN):
+            yield out
     finally:
-        window_s = time.monotonic() - t
         jax.profiler.stop_trace()
-    out.reduced = xplane.reduce_trace(xplane.find_trace(trace_dir),
-                                      window_s=window_s)
+    out.reduced = xplane.reduce_trace(xplane.find_trace(trace_dir))
 
 
 def trace_middle(run, trace_dir: str, snapshot: Callable[[], Dict[str, Any]],

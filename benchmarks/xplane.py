@@ -15,11 +15,22 @@ overlap are not counted twice.  Host activity is every event of the
 ``/host:CPU`` plane; a gap takes the name of the host event that
 overlaps it most, the shortest such event on a tie (the innermost
 frame says most).
+
+**The window** is the ``bench.slice`` span of the ``/host:CPU`` plane,
+which ``benchmarks/tracing.py`` opens once the profiler runs and closes
+before it stops it: a ``TraceAnnotation``, so on the clock the device's
+operations are laid on.  Every device event is clipped to it before
+anything is summed, so ``busy_s`` can never pass ``window_s``, whatever
+the profiler recorded before the span opened or after it closed (a cell
+whose chip never idles reads 1.0 and not 1.00004: PR 35 was refused over
+that).  A file without the span is refused, as one in which no
+operation ran on a device is: there is one definition of the window.
 """
 
 from __future__ import annotations
 
 import glob
+import math
 import os
 import re
 from collections import defaultdict
@@ -33,6 +44,8 @@ Event = Tuple[str, float, float]          # (name, start_ns, end_ns)
 DEVICE_PLANE_PREFIX = "/device:TPU:"
 HOST_PLANE = "/host:CPU"
 OPS_LINE = "XLA Ops"
+#: the host span that bounds the traced slice (benchmarks/tracing.py)
+SLICE_SPAN = "bench.slice"
 #: device lines that enclose operations instead of being operations
 _ENCLOSING_LINES = ("Steps", "XLA Modules", "XLA TraceMe",
                     "Async XLA Ops", "TC Overlay", "Framework Name Scope",
@@ -52,8 +65,9 @@ def merge(intervals: Iterable[Interval]) -> List[Interval]:
 
 
 def busy_ns(intervals: Iterable[Interval]) -> float:
-    """Length of the union of ``intervals``."""
-    return sum(end - start for start, end in merge(intervals))
+    """Length of the union of ``intervals`` (``fsum``: a hundred
+    thousand operations that fill a window must not add up past it)."""
+    return math.fsum(end - start for start, end in merge(intervals))
 
 
 def idle_gaps(intervals: Iterable[Interval], window: Interval
@@ -86,6 +100,24 @@ def op_label(name: str) -> str:
         return name
     kind = re.sub(r"\.\d+", "", head.lstrip("%"))
     return f"{kind} {rest.split('{')[0].strip()}"[:120]
+
+
+def clip(events: Iterable[Event], window: Interval) -> List[Event]:
+    """The part of each event that lies inside ``window``; an event
+    wholly outside it is dropped."""
+    lo, hi = window
+    return [(name, max(start, lo), min(end, hi))
+            for name, start, end in events
+            if min(end, hi) > max(start, lo)]
+
+
+def slice_window(host_events: Iterable[Event]) -> Optional[Interval]:
+    """The ``bench.slice`` span among the host's events, or ``None``.
+    A slice writes one; of several (two slices into one directory) the
+    last is the one whose file this is."""
+    found = [(start, end) for name, start, end in host_events
+             if name == SLICE_SPAN and end > start]
+    return max(found) if found else None
 
 
 def top_ops(events: Iterable[Event], top: int = 10
@@ -162,13 +194,11 @@ def find_trace(trace_dir: str) -> str:
     return max(found, key=os.path.getmtime)
 
 
-def reduce_trace(path: str, window_s: Optional[float] = None,
-                 top: int = 10) -> Dict[str, object]:
-    """Reduce one trace file.  ``window_s`` is the traced window as the
-    host timed it (start_trace returned → stop_trace called); without
-    it the window is the span of the device events.  ``busy_s`` is the
-    mean over the devices that ran anything, ``idle_share`` follows
-    from it, ``device_ops`` and ``idle_gaps`` are the ``breakdown``."""
+def read_planes(path: str) -> Tuple[List[Event], List[List[Event]],
+                                    List[str]]:
+    """Of one trace file: every event of the ``/host:CPU`` plane, the
+    operation events of each device plane that has any, and the names
+    of all its planes."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
@@ -182,26 +212,45 @@ def reduce_trace(path: str, window_s: Optional[float] = None,
         elif plane.name == HOST_PLANE:
             for line in plane.lines:
                 host_events.extend(_line_events(line))
+    return host_events, per_device, [p.name for p in data.planes]
+
+
+def reduce_trace(path: str, top: int = 10) -> Dict[str, object]:
+    """Reduce one trace file.  The window is the file's ``bench.slice``
+    span and every device's events are clipped to it; a file without
+    the span, or with no device operation inside it, is refused.
+    ``busy_s`` is the mean over the devices that ran anything in the
+    window, ``idle_share`` follows from it, ``device_ops`` and
+    ``idle_gaps`` are the ``breakdown``, the fullest device's."""
+    host_events, per_device, planes = read_planes(path)
     if not per_device:
         raise ValueError(f"{path}: no operation ran on a device "
-                         f"(planes: {[p.name for p in data.planes]})")
+                         f"(planes: {planes})")
+    window = slice_window(host_events)
+    if window is None:
+        raise ValueError(f"{path}: no {SLICE_SPAN} span on the "
+                         f"{HOST_PLANE} plane: the slice's window is "
+                         "unknown")
+    # the span covers every gap whole: it would name them all
+    host_events = [ev for ev in host_events if ev[0] != SLICE_SPAN]
+    per_device = [evs for evs in (clip(evs, window) for evs in per_device)
+                  if evs]
+    if not per_device:
+        raise ValueError(f"{path}: no operation ran on a device inside "
+                         f"the {SLICE_SPAN} span")
     busy = [busy_ns((s, e) for _, s, e in evs) / 1e9 for evs in per_device]
-    spans = [(min(s for _, s, _ in evs), max(e for _, _, e in evs))
-             for evs in per_device]
-    span_s = max(hi - lo for lo, hi in spans) / 1e9
-    window = float(window_s) if window_s else span_s
+    window_s = (window[1] - window[0]) / 1e9
     busy_s = sum(busy) / len(busy)
     # the breakdown is the fullest device's: on one chip, the chip's
     fullest = max(range(len(busy)), key=busy.__getitem__)
     events = per_device[fullest]
-    gaps = idle_gaps(((s, e) for _, s, e in events), spans[fullest])
+    gaps = idle_gaps(((s, e) for _, s, e in events), window)
     return {
         "path": path,
         "devices": len(per_device),
         "busy_s": busy_s,
-        "window_s": window,
-        "device_span_s": span_s,
-        "idle_share": max(0.0, 1.0 - busy_s / window),
+        "window_s": window_s,
+        "idle_share": 1.0 - busy_s / window_s,
         "op_events": sum(len(evs) for evs in per_device),
         "device_ops": [[n, s] for n, s in top_ops(events, top)],
         "idle_gaps": [[n, s] for n, s in top_gaps(gaps, host_events, top)],

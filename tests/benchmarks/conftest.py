@@ -1,4 +1,5 @@
-"""Shared by the benchmark's tests: the stream tier's cells.
+"""Shared by the benchmark's tests: the stream tier's cells, and the
+trace the profiler overran (``overrun_trace``, at the end).
 
 ``stream_cells.json`` lists the two MobileNetV2 cells in the manifest's
 shape WITHOUT bounds: they are not in ``BENCHMARK.json`` (the
@@ -12,6 +13,7 @@ entries to ``BENCHMARK.json`` with bounds from its own runs.
 
 import json
 import os
+import re
 
 import pytest
 
@@ -33,3 +35,35 @@ def stream_manifest(tmp_path_factory):
     path = tmp_path_factory.mktemp("stream_cells") / "manifest.json"
     path.write_text(json.dumps(doc))
     return Manifest(str(path), root=ROOT)
+
+
+OVERRUN = os.path.join(ROOT, "tests", "benchmarks", "fixtures",
+                       "slice_overrun.xspace.textproto")
+
+
+@pytest.fixture()
+def xplane_file(tmp_path):
+    """Writes an XSpace given in text form as a ``.xplane.pb`` file."""
+    def write(text, name="vm.xplane.pb"):
+        from jax.profiler import ProfileData
+
+        path = tmp_path / name
+        path.write_bytes(ProfileData.text_proto_to_serialized_xspace(text))
+        return str(path)
+    return write
+
+
+@pytest.fixture()
+def overrun_trace(xplane_file):
+    """``fixtures/slice_overrun`` as a file, with its ``bench.slice``
+    span moved to ``[lo, hi)`` us and ``more`` planes appended."""
+    def make(lo_us=100, hi_us=400, more="", name="vm.xplane.pb"):
+        with open(OVERRUN, encoding="utf-8") as fh:
+            text = fh.read()
+        line = re.compile(
+            r"offset_ps: \d+ duration_ps: \d+ \}  # bench.slice")
+        assert len(line.findall(text)) == 1
+        text = line.sub(f"offset_ps: {lo_us * 10**6} duration_ps: "
+                        f"{(hi_us - lo_us) * 10**6} }}", text)
+        return xplane_file(text + more, name)
+    return make

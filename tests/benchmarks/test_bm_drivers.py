@@ -18,15 +18,21 @@ TOY = os.path.join(ROOT, "tests", "benchmarks", "toy", "manifest.json")
 CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
 
-def rehearse(monkeypatch, capsys, cell, seconds="1.5"):
+def drive(monkeypatch, capsys, cell, seconds="1.5"):
+    """The whole run but the harness's look for a chip."""
     monkeypatch.setattr(harness, "device_or_exit", lambda chips: {
         "platform": "cpu", "kind": "TPU v5 lite", "count": 1})
     assert harness.main(["--manifest", TOY, "--workload", cell,
                          "--seed", "3", "--seconds", seconds,
                          "--trace", "0"]) == 0
     lines = capsys.readouterr().out.strip().splitlines()
-    detail, last = json.loads(lines[0]), json.loads(lines[-1])
-    assert set(last) == CONTRACT_KEYS
+    return json.loads(lines[0]), json.loads(lines[-1])
+
+
+def rehearse(monkeypatch, capsys, cell, seconds="1.5"):
+    detail, last = drive(monkeypatch, capsys, cell, seconds)
+    # and, last, what the check compared, where the driver names it
+    assert CONTRACT_KEYS <= set(last) <= CONTRACT_KEYS | {"compared"}
     assert last["correct"] is True, detail["checks"]
     assert last["failed"] == 0 and last["attempted"] > 0
     assert detail["checks"]["compiles_in_window"] == 0
@@ -38,6 +44,11 @@ def test_token_driver_closed_loop(monkeypatch, capsys):
     detail, last = rehearse(monkeypatch, capsys, "toy_lm.closed")
     assert set(last["metrics"]) == {"tok_s", "setup_s"}
     assert last["metrics"]["tok_s"]["value"] > 0
+    assert list(last)[-1] == "compared"
+    assert last["compared"]["near_top_share"] == {"value": 1.0,
+                                                  "limit": ">= 1.0"}
+    assert {got["value"] for name, got in last["compared"].items()
+            if got["limit"] == "== 0"} == {0}
     checks = detail["checks"]
     # float32 toy against the float32 reference: every sampled token is
     # the reference's own argmax
@@ -82,3 +93,25 @@ def test_stream_query_driver(monkeypatch, capsys):
     assert checks["checked"] == 2
     # 3 cameras x 4 frames/s x (0.5 s ramp + 2 s)
     assert last["attempted"] == pytest.approx(30, abs=3)
+
+
+@pytest.mark.parametrize("cell", ["toy_lm.closed", "toy_lm.open"])
+def test_a_token_altered_where_it_is_produced_reads_not_correct(
+        monkeypatch, capsys, cell):
+    """The timed path broken underneath a whole run: every token the
+    engine hands to the element is the one after the chip's own.  The
+    lengths, the vocabulary and the compile count all still pass; the
+    comparison with the reference is what fails the run."""
+    from nnstreamer_tpu.llm.engine import DecodeEngine
+
+    collect = DecodeEngine.collect
+    monkeypatch.setattr(
+        DecodeEngine, "collect",
+        lambda self: [(sess, (tok + 1) % 61) for sess, tok in collect(self)])
+    detail, last = drive(monkeypatch, capsys, cell)
+    assert last["correct"] is False
+    assert last["failed"] == 0 and last["attempted"] > 0
+    share = last["compared"]["near_top_share"]
+    assert share["limit"] == ">= 1.0" and share["value"] < 0.5
+    assert {got["value"] for name, got in last["compared"].items()
+            if got["limit"] == "== 0"} == {0}

@@ -114,9 +114,12 @@ BREACHES = {
     "reduced names the hidden size":
         lambda d: d["configs"][0].update(reduced=["hidden_size"]),
     "unknown moves": lambda d: d["per_layer"][1].update(moves="fps"),
+    "layer metric in a cell the manifest has not":
+        lambda d: d["per_layer"][1].update(workloads=["gpt2m.nowhere"]),
     "layer metric where its end-to-end metric is not":
         lambda d: d["per_layer"][1].update(
             workloads=["gpt2m.steady_short"]),
+    "one cell": lambda d: d.update(workloads=d["workloads"][:1]),
     "no setup_s": lambda d: d["end_to_end"].pop(),
     "pair twice": lambda d: d["workloads"].append(
         dict(d["workloads"][0], name="again")),

@@ -117,7 +117,7 @@ def test_cell_reports_the_family_blind_metrics_and_its_own(manifest):
     layer = {m["name"] for m in manifest.metrics(CELL, "per_layer")}
     assert layer == {
         "compiles_in_window", "decode_step_ms", "lanes_per_step",
-        "decode_step_roofline", "device_idle_share",
+        "decode_step_roofline", "step_mfu", "device_idle_share",
         "decode_thread_off_device_share", "step_gap_ms",
         "step_gap_engine_ms", "hybrid_state_device_share",
         "shared_kv_attn_roofline", "ssm_ms_per_step"}

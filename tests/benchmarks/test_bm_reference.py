@@ -84,3 +84,40 @@ def test_mobilenet_reference_equals_the_program_in_float32():
     assert got.shape == want.shape == (1001,)
     span = float(want.max() - want.min())
     assert span > 0 and np.abs(got - want).max() < 1e-4 * span
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_the_control_one_precision_lower_reads_under_the_toys_limit(
+        lm, seed):
+    """The control of the token cells' comparison, at a size a test can
+    hold: the same model with its weights rounded to the precision below
+    the one the (float32) toy states, bfloat16, in the program's place.
+    Its greedy streams, judged by the float32 reference, fall under the
+    toy's limit (every token within 1e-3 of the top: share 1.0) on every
+    seed, where the program's own streams read exactly 1.0.  At the
+    cells' own sizes the control is float8-rounded weights under
+    bfloat16 serving, read on the chip (PERF.md section 6, PR 29)."""
+    import jax
+    import jax.numpy as jnp
+
+    from nnstreamer_tpu.models.streamformer_lm import generate
+
+    cfg, params = lm
+    lower = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16).astype(a.dtype), params)
+    model = {"head_dim": cfg.head_dim, "max_seq": 64}
+    share = {}
+    for name, weights in (("program", params), ("control", lower)):
+        total = {"tokens": 0, "near_top": 0}
+        for k in range(4):
+            prompt = np.random.default_rng([seed, k]).integers(
+                0, 61, 9).astype(np.int32)
+            got = ref_lm.served_tokens_near_top(
+                params, model, prompt, generate(weights, cfg, prompt, 40),
+                slack=1e-3)
+            for key in total:
+                total[key] += got[key]
+        assert total["tokens"] == 160
+        share[name] = total["near_top"] / total["tokens"]
+    assert share["program"] == 1.0
+    assert share["control"] < 1.0

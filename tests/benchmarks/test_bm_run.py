@@ -166,6 +166,9 @@ def test_device_readers_take_the_traced_slice():
         pytest.approx(100 * least * 2.3 / 2.0)
     assert trace["roofline_bound"] == "bytes"
     assert trace["least_ms_per_step"] == pytest.approx(least * 1e3)
+    # the whole step's share of the peak counts steps in the trace
+    # file (test_bm_spans.py): this trace names none
+    assert read(m, run, "layer_metrics", "step_mfu") is None
     # a family with no cost functions has no roofline
     run.cost = None
     assert read(m, run, "layer_metrics", "decode_step_roofline") is None
@@ -237,6 +240,18 @@ def test_last_line_has_exactly_the_contracts_keys():
         assert isinstance(metric["value"], float)
 
 
+def test_last_line_ends_in_what_was_compared_beside_its_limit():
+    m, run = token_run()
+    compared = {"near_top_share": {"value": 0.97, "limit": ">= 0.9"},
+                "compiles_in_window": {"value": 0, "limit": "== 0"}}
+    line = json.loads(json.dumps(harness.result_line(
+        True, run, {}, dict(DEVICE), compared)))
+    assert list(line)[-1] == "compared" and line["compared"] == compared
+    # a driver whose check names none adds no key
+    assert "compared" not in harness.result_line(True, run, {},
+                                                 dict(DEVICE), {})
+
+
 def test_traced_last_line_adds_busy_window_and_breakdown():
     trace = {"busy_s": 2.0, "window_s": 2.5, "idle_share": 0.2,
              "device_ops": [["fusion.1", 1.5]], "idle_gaps": [["x", 0.5]],
@@ -249,6 +264,34 @@ def test_traced_last_line_adds_busy_window_and_breakdown():
     assert line["device"]["window_s"] == 2.5
     assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
     assert len(line["breakdown"]["device_ops"]) <= 10
+
+
+@pytest.mark.parametrize("window_us", [(100, 400), (205, 255), (50, 190)])
+def test_last_line_of_an_overrun_trace_keeps_busy_inside_the_window(
+        overrun_trace, window_us):
+    """The trace file is one the profiler overran on both sides of the
+    ``bench.slice`` span (``fixtures/slice_overrun``), through the
+    reducer and the readers to the line the driver parses: ``busy_s``
+    above 0 and at most ``window_s``, the rule PR 35 was refused by."""
+    from benchmarks import xplane
+
+    lo, hi = window_us
+    trace = xplane.reduce_trace(overrun_trace(lo, hi))
+    trace["counters"] = {"steps": 0, "step_tokens": 0, "samples": []}
+    m, run = token_run(trace)
+    line = json.loads(json.dumps(harness.result_line(
+        True, run, {}, dict(DEVICE))))
+    device = line["device"]
+    assert 0 < device["busy_s"] <= device["window_s"]
+    assert device["window_s"] == pytest.approx((hi - lo) * 1e-6)
+    idle = read(m, run, "layer_metrics", "device_idle_share")
+    assert idle == pytest.approx(
+        100 * (1 - device["busy_s"] / device["window_s"]))
+    assert 0.0 <= idle < 100.0
+    assert sum(s for _, s in line["breakdown"]["device_ops"]) >= \
+        device["busy_s"]
+    assert sum(s for _, s in line["breakdown"]["idle_gaps"]) == \
+        pytest.approx(device["window_s"] - device["busy_s"])
 
 
 def test_a_stream_cut_at_the_windows_end_is_not_a_failure():

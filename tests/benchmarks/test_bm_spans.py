@@ -200,6 +200,27 @@ def test_reader_on_the_hand_worked_trace(trace_path, name, want):
                                               "llm.decode.sample"]
 
 
+def test_step_mfu_counts_the_traces_whole_steps_over_their_interval(
+        trace_path):
+    """Two whole steps of two lanes span [90, 890) us of the trace: the
+    steps, their lanes and the interval are the trace's; the positions
+    attended are the pool's samples over the slice."""
+    from benchmarks.cost import streamformer_lm as cost
+
+    peaks = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    m, run = run_with({"path": trace_path, "counters": {"samples": [
+        (1.0, 2, 2, 300), (1.1, 2, 2, 500), (1.2, 2, 0, 0)]}})
+    run.peaks, run.cost = peaks, cost
+    flops, _ = cost.decode_step_cost(run.config["model"], 2, 400)
+    assert read(m, run, "step_mfu") == pytest.approx(
+        100 * flops * 2 / (800e-6 * peaks["flops_per_s"]))
+    # no lane decoding in any sample, or a family without cost functions
+    run.trace["counters"]["samples"] = [(1.2, 2, 0, 0)]
+    assert read(m, run, "step_mfu") is None
+    run.cost = None
+    assert read(m, run, "step_mfu") is None
+
+
 @pytest.mark.parametrize("name", READERS)
 def test_reader_without_a_trace_a_path_or_a_file_reads_nothing(
         tmp_path, name):

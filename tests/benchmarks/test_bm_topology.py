@@ -44,6 +44,7 @@ def described(topo):
 
     from benchmarks.manifest import Manifest
     from nnstreamer_tpu.filter.framework import FilterProperties
+    from nnstreamer_tpu.llm.pool import dense_pool_shape
     from nnstreamer_tpu.models.streamformer_lm import config_from_custom
     from nnstreamer_tpu.parallel.train_step import init_params
 
@@ -63,8 +64,8 @@ def described(topo):
         lambda x: on_chip(x.shape, x.dtype),
         jax.eval_shape(lambda: init_params(cfg, 0)))
     slots = config["element"]["slots"]
-    pool = on_chip((slots + 1, cfg.layers, cfg.max_seq, cfg.heads,
-                    cfg.head_dim), cfg.dtype)
+    # the pool as the element reserves it: the shape is the program's
+    pool = on_chip(dense_pool_shape(cfg, slots), cfg.dtype)
     yield {"cfg": cfg, "config": config, "params": params, "pool": pool,
            "i32": lambda *shape: on_chip(shape, jnp.int32)}
     jax.config.update("jax_enable_compilation_cache", was)
@@ -99,26 +100,26 @@ def test_decode_step_at_every_lane_fits_the_chip(described):
                                             + plan["kv_pool_bytes"])
     # the pool is donated: the step updates it in place
     assert stats.alias_size_in_bytes >= plan["kv_pool_bytes"]
-    assert resident(stats) < USABLE_BYTES
-    # and it is a real size: over half the chip while a step runs
-    assert resident(stats) > USABLE_BYTES // 2
+    # what the cell holds while a 32-lane step runs: the weights and
+    # the pool (6.56 GB; the file's two numbers), and temporaries that
+    # no longer copy the pool (0.09 GB since PR 26, where the old
+    # five-dimensional pool cost 2.2 times itself)
+    assert (plan["weights_bytes"] + plan["kv_pool_bytes"]
+            <= resident(stats) < USABLE_BYTES)
+    assert stats.temp_size_in_bytes < plan["kv_pool_bytes"] // 8
 
 
 def test_longest_prefill_fits_the_chip(described):
     import jax
 
-    from nnstreamer_tpu.models.streamformer_lm import prefill_kv
+    from nnstreamer_tpu.models.streamformer_lm import prefill_pooled
 
     d = described
     cfg = d["cfg"]
 
     def prefill(params, k_pool, v_pool, tokens, slot, true_len):
-        logits, ks, vs = prefill_kv(params, tokens, cfg)
-        k_pool = jax.lax.dynamic_update_slice(k_pool, ks[None],
-                                              (slot, 0, 0, 0, 0))
-        v_pool = jax.lax.dynamic_update_slice(v_pool, vs[None],
-                                              (slot, 0, 0, 0, 0))
-        return logits[true_len - 1], k_pool, v_pool
+        return prefill_pooled(params, k_pool, v_pool, tokens, slot,
+                              true_len, cfg)
 
     compiled = jax.jit(prefill, donate_argnums=(1, 2)).lower(
         d["params"], d["pool"], d["pool"], d["i32"](cfg.max_seq),

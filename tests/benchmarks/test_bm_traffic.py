@@ -153,3 +153,29 @@ def test_cameras_get_a_phase_inside_one_period_and_their_own_frames():
     assert a.nbytes == 4 * 150528
     assert (a == traffic.camera_frames(8, 0, 4, (224, 224, 3))).all()
     assert (a != traffic.camera_frames(8, 1, 4, (224, 224, 3))).any()
+
+
+@pytest.mark.parametrize("seed", [1, 3600600001, 2**31 + 7])
+def test_the_latency_cell_offers_every_seed_the_same_work(seed):
+    """``gpt2m.steady_short`` (PR 36): evenly paced arrivals at four
+    fifths of the swept knee, the same arrival times and the same
+    multiset of prompt lengths whatever the seed, which only orders
+    them; what differs between two runs is the system, not the load."""
+    m = Manifest(os.path.join(ROOT, "BENCHMARK.json"))
+    mix = m.traffic("steady_short")
+    rate, knee = mix["arrivals"]["rate_per_s"], mix["knee"]["rate_per_s"]
+    assert mix["arrivals"]["process"] == "constant"
+    assert rate == pytest.approx(0.8 * knee)
+    seconds = float(m.doc["run_seconds"])
+    base = traffic.open_token_requests(mix, 0, seconds)
+    got = traffic.open_token_requests(mix, seed, seconds)
+    assert len(got) == len(base) == round(rate * seconds)
+    assert [r["due"] for r in got] == [r["due"] for r in base]
+    assert np.diff([r["due"] for r in got]) == pytest.approx(
+        np.full(len(got) - 1, 1.0 / rate))
+    assert sorted(r["prompt_len"] for r in got) == sorted(
+        r["prompt_len"] for r in base)
+    assert [r["prompt_len"] for r in got] != [r["prompt_len"]
+                                              for r in base]
+    # the p95 rests on hundreds of requests
+    assert len(got) // 20 >= 100
