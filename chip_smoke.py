@@ -19,7 +19,9 @@ Phases, each a plain function that raises on the first wrong answer:
 3. llm      — ``tensor_query_serversrc ! tensor_llm !
               tensor_query_serversink`` at the bench LM's full width,
               four concurrent ``TokenStreamClient``s, dense pool then
-              paged pool (chunked prefill + prefix cache).
+              paged pool (chunked prefill + prefix cache); then its
+              second family (``arch:sambay_lm``) and its third
+              (``arch:dsv3_lm``), each at a small size.
 4. mesh     — only where four chips are visible: the flagship filter
               with ``custom=mesh:dp=4``.
 
@@ -62,6 +64,20 @@ HYBRID_CUSTOM = ("arch:sambay_lm,vocab:8192,dim:512,heads:8,kv_heads:4,"
                  "head_dim:64,mlp:2048,layers:8,window:128,d_state:16,"
                  "d_conv:4,expand:2,dt_rank:32,max_seq:2048,"
                  "dtype:bfloat16")
+
+#: the third family (arch:dsv3_lm: latent attention over a cache of
+#: latents, 32 sigmoid-routed experts of which this chip holds 8 and
+#: computes only the chosen, a shared expert, YaRN) at a small size: the
+#: grouped product's kernel, the absorbed decode and the 3-chunk prefill
+#: show on a fresh machine before the 8.6 GB warm-up of
+#: benchmarks/configs/gigachat31_702b_a36b.json
+LATENT_CUSTOM = ("arch:dsv3_lm,vocab:8192,dim:512,heads:8,q_lora_rank:192,"
+                 "kv_lora_rank:128,qk_nope_head_dim:64,qk_rope_head_dim:64,"
+                 "v_head_dim:96,mlp:1024,expert_mlp:256,experts:32,"
+                 "experts_held:8,expert_rank:0,n_group:4,topk_group:2,"
+                 "experts_per_tok:4,dense_layers:1,layers:3,"
+                 "rope_theta:100000,rope_factor:16,rope_original_max:256,"
+                 "max_seq:2048,chunk:512,dtype:bfloat16")
 
 #: flash kernel vs naive float32 attention, bf16 inputs: max abs error of
 #: the forward output (values are O(1)), and max error of a gradient
@@ -551,6 +567,8 @@ def main() -> int:
     paged = phase_llm(4622, page_size=16, shared_prefix=64)
     hybrid = phase_llm(4623, custom=HYBRID_CUSTOM)
     hybrid.pop("streams")
+    latent = phase_llm(4624, custom=LATENT_CUSTOM)
+    latent.pop("streams")
     # the two prefill paths round differently in bf16: reported, not
     # asserted
     pairs = [(a, b) for da, pa in zip(dense.pop("streams"),
@@ -559,7 +577,7 @@ def main() -> int:
     paged["tokens_equal_dense"] = (
         f"{sum(a == b for a, b in pairs)}/{len(pairs)}")
     phases.update(llm_dense=dense, llm_paged=paged, llm_hybrid=hybrid,
-                  mesh=phase_mesh())
+                  llm_latent=latent, mesh=phase_mesh())
     summary.update(
         phases=phases,
         setup_s_total=round(sum(ph.get("setup_s", 0.0)

@@ -302,10 +302,7 @@ class TensorLLM(Element):
         if not asked:
             return None
         return (f"{self.name}: arch:{family.name} cannot serve "
-                f"{' / '.join(asked)}: its sessions keep recurrent rows "
-                "and rings beside one layer's keys, which no page table "
-                "names and no page's content hash vouches for (prefix "
-                "reuse over recurrent state needs snapshots); it "
+                f"{' / '.join(asked)}: {family.unpaged_why}; it "
                 "prefills in fixed chunks of its own and serves from "
                 "the dense slot pool — drop the property")
 
@@ -477,6 +474,11 @@ class TensorLLM(Element):
         for g in getattr(self, "_obs_gauges", ()):
             REGISTRY.unregister(g)
         self._obs_gauges = []
+        engine = getattr(self, "engine", None)
+        if engine is not None:
+            # the loop has ended: the one place the element itself reads
+            # what a family counts inside its state (``state_counters``)
+            self.final_report = engine.report()
         self.engine = None
         self.pool = None
 

@@ -959,4 +959,13 @@ class DecodeEngine:
             out["prefill_chunks"] = self.prefill_chunks_total
         if self.paged:
             out["paged"] = self.pool.stats()
+        counters = getattr(self.family, "state_counters", None)
+        if counters is not None:
+            # a host read of a few floats of the state: a report's, on
+            # request, never the loop's
+            try:
+                out["state_counters"] = counters(self.cfg,
+                                                 self.pool.arrays)
+            except RuntimeError:
+                pass        # donated to a step in flight: not now
         return out

@@ -11,11 +11,15 @@ of its model module:
     whether it serves the block-paged arena (``page-size > 0``), and with
     it interleaved prefill (``prefill-chunk``) and prefix reuse
     (``prefix-cache``); the element refuses those properties for a
-    family that does not
+    family that does not, and says why in the family's own words
+    (``unpaged_why``: ``sambay_lm``, ``dsv3_lm``)
 ``state_kinds``
     one name per array of ``init_state`` (arrays of one kind share a
     name): what ``bytes_by_kind`` of a pool and the element's gauges
-    report
+    report (``kv``; ``sambay_lm`` also ``ring``, ``conv``, ``ssm``;
+    ``dsv3_lm`` ``latent`` and ``route_stats``, its routing counters,
+    which ride in the state so that a step updates them with no host
+    read)
 ``config_from_custom(custom) -> cfg``
     the family's grammar; raises ``ValueError`` on a key it does not know
 ``init_params(cfg, seed) -> params``
@@ -32,6 +36,10 @@ of its model module:
 ``prefill(params, state, tokens, slot, true_len, cfg, flash) -> (last, state)``
 ``prefill_chunk(params, state, tokens, slot, start, true_len, last, cfg)
 -> (logits, state)``
+``state_counters(cfg, state) -> dict`` (optional)
+    counters the family keeps inside its state, read to the host: what
+    ``DecodeEngine.report()`` adds under ``state_counters``, on request
+    and never inside the loop
 ``decode_step_paged(params, state, tokens, pos, tables, cfg, page_size)``
 ``prefill_chunk_paged(params, state, tokens, table, start, true_len, cfg,
 page_size, scratch)``
@@ -48,6 +56,7 @@ DEFAULT = "streamformer_lm"
 FAMILIES = {
     "streamformer_lm": "nnstreamer_tpu.models.streamformer_lm",
     "sambay_lm": "nnstreamer_tpu.models.sambay_lm",
+    "dsv3_lm": "nnstreamer_tpu.models.dsv3_lm",
 }
 
 

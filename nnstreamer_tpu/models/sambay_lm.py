@@ -675,6 +675,10 @@ class _Family:
     #: no block-paged arena, no interleaved prefill, no prefix reuse:
     #: a page of keys says nothing of the recurrent state beside it
     paged = False
+    unpaged_why = ("its sessions keep recurrent rows and rings beside one "
+                   "layer's keys, which no page table names and no page's "
+                   "content hash vouches for (prefix reuse over recurrent "
+                   "state needs snapshots)")
     state_kinds = STATE_KINDS
     config_from_custom = staticmethod(config_from_custom)
     init_params = staticmethod(init_params)

@@ -69,8 +69,9 @@ def test_chip_smoke_ends_on_the_verdict_line(monkeypatch, tmp_path,
     assert verdict == {"ok": True, "device": device}
     assert type(verdict["device"]["count"]) is int
     assert set(detail["phases"]) == {"kernels", "stream", "llm_dense",
-                                     "llm_paged", "llm_hybrid", "mesh"}
-    assert detail["setup_s_total"] == 6.0
+                                     "llm_paged", "llm_hybrid",
+                                     "llm_latent", "mesh"}
+    assert detail["setup_s_total"] == 7.0
 
 
 def test_cache_dir_is_the_environments_when_set(monkeypatch, tmp_path):
