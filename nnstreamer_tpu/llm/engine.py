@@ -342,6 +342,12 @@ class DecodeEngine:
         #: dropped and counts as no token
         self.steps_ahead = 0
         self.lanes_discarded = 0
+        #: cache positions the real lanes of every dispatched step
+        #: attended (each lane its ``pos + 1``), and what their slots
+        #: reserve (``max_seq`` a lane): the share of the reserved
+        #: positions a step that follows its lanes' contexts reads
+        self.attended_positions = 0
+        self.reserved_positions = 0
         self.last_fill = 0
         self.ewma_step_s = 0.0
         self.compiles = 0
@@ -870,8 +876,10 @@ class DecodeEngine:
                                "older before a third is dispatched")
         t0 = self._clock()
         ahead = len(self._flights)
+        attended = sum(s.pos for s in sessions) + len(sessions)
         prev = self.phases.enter("decode", step=self.steps_total + ahead,
-                                 lanes=len(sessions), ahead=ahead)
+                                 lanes=len(sessions), ahead=ahead,
+                                 attended=attended)
         try:
             out = self._launch(sessions)
         finally:
@@ -880,6 +888,8 @@ class DecodeEngine:
             s.pos += 1
             s.in_flight += 1
         self.steps_ahead += ahead
+        self.attended_positions += attended
+        self.reserved_positions += len(sessions) * self.cfg.max_seq
         self._flights.append((out, list(sessions), t0))
 
     def collect(self) -> List[Tuple[Session, int]]:
@@ -946,6 +956,8 @@ class DecodeEngine:
             "steps": self.steps_total,
             "steps_ahead": self.steps_ahead,
             "lanes_discarded": self.lanes_discarded,
+            "attended_positions": self.attended_positions,
+            "reserved_positions": self.reserved_positions,
             "prefills": self.prefills_total,
             "mean_fill": round(self.step_tokens
                                / max(1, self.steps_total), 2),

@@ -22,7 +22,12 @@ a head's no-position query is carried into the latent space (``q_lat =
 q_nope W_kvb,k^T``), scores and the weighted sum run over the cached
 rows as they lie (one ``(heads, row) x (T, row)^T`` product a lane and
 one ``(heads, T) x (T, row)`` product back), and the values are
-expanded after the sum.  The two must agree; the tests hold them to it.
+expanded after the sum.  On a TPU that is one kernel a layer
+(``ops/latent_decode.py``): it walks each lane's slot in the pool block
+by block up to the lane's own position, once, and keeps scores and
+softmax in VMEM; elsewhere the lanes' rows are gathered and the same
+arithmetic runs as XLA's.  The two must agree; the tests hold them to
+it.
 
 **Experts.**  The layer is told which experts it holds
 (``experts_held`` of them, the ``expert_rank``-th share of
@@ -56,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.latent_decode import latent_decode_attention
 from .sambay_lm import _draw, _key, _mm
 from .streamformer_lm import _slot_rows
 
@@ -66,15 +72,14 @@ ROUTE_STATS = ("steps", "pairs_routed_here", "held_experts_reached",
                "largest_count_at_one_expert")
 #: query positions a prefill chunk scores at a time
 _QBLOCK = 256
-#: whether the grouped products run through the megablox kernel: None =
-#: where the default backend is a TPU (a test that compiles for a
-#: described chip sets it)
+#: whether the step's two kernels run — the megablox grouped products
+#: of the routed experts and the decode attention over the pool
+#: (``ops/latent_decode.py``) — or XLA's forms of both: None = where the
+#: default backend is a TPU (a test that compiles for a described chip
+#: sets it)
 GROUPED_KERNEL = None
-#: rows of a block of the kernel
+#: rows of a block of the grouped kernel
 _GROUPED_ROWS = 64
-#: a decode step attends over the pool where it lies, without a gather,
-#: once its lanes are this share of the pool's slots
-IN_PLACE_SHARE = 0.75
 _FLOATS = ("routed_scaling_factor", "rope_theta", "rope_factor",
            "beta_fast", "beta_slow", "mscale", "mscale_all_dim")
 
@@ -403,42 +408,37 @@ def _absorb(q_nope, q_pe, lyr, cfg):
     return jnp.concatenate([q_lat, q_pe, fill], -1).astype(cfg.dtype)
 
 
-def _attn_absorbed(q_rows, rows, valid, lyr, cfg):
-    """The absorbed path: ONE query a lane over that lane's cached rows,
-    left as they lie (``(B, T, row_held)``).  ``q_rows (B, H,
-    row_held)``, ``valid (B, T)``; the weighted sum is taken over the
-    rows themselves and the values expanded after it.  Returns ``(B, H
-    * v)`` before ``w_o``."""
-    dt = cfg.dtype
-    _, wv = _w_kvb(lyr, cfg)
+def _kernels() -> bool:
+    """Whether the step's kernels run (:data:`GROUPED_KERNEL`)."""
+    if GROUPED_KERNEL is None:
+        return jax.default_backend() == "tpu"
+    return GROUPED_KERNEL
+
+
+def _attn_absorbed(q_rows, rows, pos, cfg):
+    """The absorbed path as XLA's: ONE query a lane over that lane's
+    GATHERED rows (``(B, T, row_held)``) up to its position.  ``q_rows
+    (B, H, row_held)``, ``pos (B,)``.  Returns the weighted sum of the
+    rows themselves, ``(B, H, row_held)`` float32 — what
+    :func:`~nnstreamer_tpu.ops.latent_decode.latent_decode_attention`
+    returns from the pool where it lies."""
+    valid = jnp.arange(rows.shape[1])[None, :] <= pos[:, None]
     s = jnp.einsum("bhr,btr->bht", q_rows, rows,
                    preferred_element_type=jnp.float32)
     p = jax.nn.softmax(jnp.where(valid[:, None, :],
                                  s * softmax_scale(cfg), -jnp.inf), axis=-1)
-    o_rows = jnp.einsum("bht,btr->bhr", p.astype(dt), rows,
-                        preferred_element_type=jnp.float32)
+    return jnp.einsum("bht,btr->bhr", p.astype(cfg.dtype), rows,
+                      preferred_element_type=jnp.float32)
+
+
+def _values(o_rows, lyr, cfg):
+    """The heads' values EXPANDED from the weighted sums of latents
+    ``o_rows (B, H, row_held)``: ``(B, H * v)`` before ``w_o``."""
+    _, wv = _w_kvb(lyr, cfg)
     o = jnp.einsum("bhc,chv->bhv",
-                   o_rows[..., :cfg.kv_lora_rank].astype(dt), wv,
+                   o_rows[..., :cfg.kv_lora_rank].astype(cfg.dtype), wv,
                    preferred_element_type=jnp.float32)
     return o.reshape(o.shape[0], -1)
-
-
-def _attn_in_place(q_rows, layer_rows, pos, slots, lyr, cfg):
-    """The absorbed path over ONE layer of the pool where it lies, no
-    gather: each lane's queries are laid at its SLOT's index (``(slots +
-    1, H, row_held)``, zeros where no lane is), every slot's rows are
-    attended by the queries at its index, and each lane takes its slot's
-    result back.  For a step whose lanes are most of the slots: it reads
-    the layer's rows twice, where a gather reads them, writes them and
-    has them read twice.  A slot without a lane attends its position 0
-    and is dropped; padding lanes share the scratch slot and are
-    dropped by the caller."""
-    s1 = layer_rows.shape[0]
-    q_slot = jnp.zeros((s1,) + q_rows.shape[1:], q_rows.dtype).at[
-        slots].set(q_rows)
-    pos_slot = jnp.zeros((s1,), pos.dtype).at[slots].set(pos)
-    seen = jnp.arange(cfg.max_seq)[None, :] <= pos_slot[:, None]
-    return _attn_absorbed(q_slot, layer_rows, seen, lyr, cfg)[slots]
 
 
 def _swiglu(y, w_gate_up, w_down):
@@ -667,13 +667,15 @@ def decode_step(params: Dict[str, Any], state: Tuple[jnp.ndarray, ...],
                 ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, ...]]:
     """One decode step over ``B`` lanes, each at its own position in its
     own slot (padding lanes: the scratch slot, position 0; they are left
-    out of ``route_stats``).  Every layer writes its lane's latent row,
-    gathers each lane's rows once (``_slot_rows``) and attends by the
-    absorbed path.  Returns ``(logits (B, vocab) f32, state')``."""
+    out of ``route_stats``).  Every layer writes its lane's latent row
+    and attends by the absorbed path: on a TPU the kernel over the pool
+    where it lies, each lane's slot up to its position (``slots`` is the
+    gather); elsewhere the lanes' rows gathered (``_slot_rows``) and
+    XLA's products over every reserved position.  Returns ``(logits (B,
+    vocab) f32, state')``."""
     pool, stats = state
     real = slots < pool.shape[1] - 1
-    in_place = IN_PLACE_SHARE * pool.shape[1] <= slots.shape[0]
-    seen = jnp.arange(cfg.max_seq)[None, :] <= pos[:, None]
+    kernel = _kernels()
     with jax.named_scope("sflm.embed"):
         x = params["embed"][tokens].astype(jnp.float32)
     for i, lyr in enumerate(params["layers"]):
@@ -685,20 +687,14 @@ def decode_step(params: Dict[str, Any], state: Tuple[jnp.ndarray, ...],
         with jax.named_scope("sflm.kv_write"):
             pool = pool.at[i, slots, pos].set(
                 _latent_rows(y, lyr, pos, cfg))
-        if in_place:
-            with jax.named_scope("sflm.mla_attn"):
-                o = _attn_in_place(q_rows, pool[i], pos, slots, lyr, cfg)
-                x = x + _mm(o, lyr["w_o"])
-        else:
-            with jax.named_scope("sflm.kv_read"):
-                # the barrier keeps the attention's say over layouts
-                # out of the gather (streamformer_lm's
-                # decode_step_pooled; PERF.md section 6, PR 26)
-                rows = jax.lax.optimization_barrier(
-                    _slot_rows(pool, i, slots))
-            with jax.named_scope("sflm.mla_attn"):
-                o = _attn_absorbed(q_rows, rows, seen, lyr, cfg)
-                x = x + _mm(o, lyr["w_o"])
+        with jax.named_scope("sflm.mla_attn"):
+            if kernel:
+                o_rows = latent_decode_attention(
+                    q_rows, pool, i, slots, pos, softmax_scale(cfg))
+            else:
+                o_rows = _attn_absorbed(
+                    q_rows, _slot_rows(pool, i, slots), pos, cfg)
+            x = x + _mm(_values(o_rows, lyr, cfg), lyr["w_o"])
         x, chosen = _ffn(x, lyr, cfg)
         if chosen is not None:
             stats = count_routes(stats, chosen, real, i, cfg)

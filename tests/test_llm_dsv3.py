@@ -25,6 +25,7 @@ from nnstreamer_tpu.llm.engine import DecodeEngine  # noqa: E402
 from nnstreamer_tpu.llm.family import family_of_custom  # noqa: E402
 from nnstreamer_tpu.llm.pool import KVCachePool  # noqa: E402
 from nnstreamer_tpu.models import dsv3_lm as dm  # noqa: E402
+from nnstreamer_tpu.ops import latent_decode  # noqa: E402
 
 MODEL = {"arch": "dsv3_lm", "vocab": 257, "dim": 64, "heads": 4,
          "q_lora_rank": 24, "kv_lora_rank": 16, "qk_nope_head_dim": 8,
@@ -87,6 +88,24 @@ def _dirty(cfg, slots):
     """A pool no session has cleared: every row holds something."""
     rows, stats = dm.init_state(cfg, slots)
     return rows + 3, stats
+
+
+def _path(kernels, monkeypatch):
+    """Take one of the decode step's two paths, both on the CPU: XLA's
+    forms, or the chip's — the decode attention over the pool where it
+    lies and the megablox grouped products — in Pallas' interpret mode,
+    the attention in blocks of 16 positions so that a slot of 64 is four
+    of them."""
+    monkeypatch.setattr(dm, "GROUPED_KERNEL", kernels)
+    if kernels:
+        from jax.experimental.pallas.ops.tpu import megablox
+
+        monkeypatch.setattr(latent_decode, "BLOCK_T", 16)
+        monkeypatch.setattr(dm, "latent_decode_attention", partial(
+            latent_decode.latent_decode_attention, interpret=True))
+        # ``_grouped`` imports the kernel where it calls it
+        monkeypatch.setattr(megablox, "gmm",
+                            partial(megablox.gmm, interpret=True))
 
 
 # -- the model's functions against the reference -------------------------
@@ -160,20 +179,21 @@ def test_chunked_prefill_equals_the_whole(world, plen):
     assert np.abs(logits - world["ref"][plen - 1]).max() < TOL
 
 
-@pytest.mark.parametrize("plen, lanes, share", [
-    (21, 1, 9.0), (3, 4, 9.0), (12, 8, 9.0),     # rows gathered
-    (21, 3, 0.75), (12, 8, 0.75)])               # the pool where it lies
+@pytest.mark.parametrize("plen, lanes, kernels", [
+    (21, 1, False), (3, 4, False), (12, 8, False),   # XLA, rows gathered
+    (21, 3, True), (12, 8, True)])          # the kernels, interpreted
 def test_absorbed_decode_equals_plain_attention_at_every_position(
-        world, plen, lanes, share, monkeypatch):
+        world, plen, lanes, kernels, monkeypatch):
     """Prefill (plain path) then decode (ABSORBED path: queries carried
     into the latent space, scores and sum over the cached rows, values
     expanded after the sum) equals the reference, which expands keys and
     values at every position — the lane of interest among padding lanes,
-    with the lanes' rows gathered and with the pool attended in place."""
+    with the lanes' rows gathered and with the kernel walking the pool
+    where it lies (the lane crosses three of its blocks' edges)."""
     cfg, tokens = world["cfg"], world["tokens"]
-    monkeypatch.setattr(dm, "IN_PLACE_SHARE", share)
     slots = 3
     _, state = _prefill(world, _dirty(cfg, slots), 2, tokens[:plen])
+    _path(kernels, monkeypatch)
     step = jax.jit(partial(dm.decode_step, cfg=cfg))
     for p in range(plen, T):
         tok = np.zeros((lanes,), np.int32)
@@ -187,10 +207,10 @@ def test_absorbed_decode_equals_plain_attention_at_every_position(
     assert float(state[1][0, 0]) == T - plen
 
 
-@pytest.mark.parametrize("share", [9.0, 0.75])
-def test_lanes_at_their_own_positions_do_not_mix(world, share, monkeypatch):
+@pytest.mark.parametrize("kernels", [False, True])
+def test_lanes_at_their_own_positions_do_not_mix(world, kernels,
+                                                 monkeypatch):
     cfg = world["cfg"]
-    monkeypatch.setattr(dm, "IN_PLACE_SHARE", share)
     rng = np.random.default_rng(4)
     seqs = [rng.integers(0, cfg.vocab, n + 1).astype(np.int32)
             for n in (13, 5, 20, 9)]
@@ -198,6 +218,7 @@ def test_lanes_at_their_own_positions_do_not_mix(world, share, monkeypatch):
     for slot, seq in enumerate(seqs):
         _, state = _prefill(world, state, slot, seq[:-1])
     order = np.array([2, 0, 3, 1])
+    _path(kernels, monkeypatch)
     logits, _ = dm.decode_step(
         world["params"], state,
         jnp.asarray([seqs[s][-1] for s in order], jnp.int32),

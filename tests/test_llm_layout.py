@@ -137,11 +137,11 @@ def test_dense_prefill_keeps_the_pool_still(described, padded_t):
 USABLE_BYTES = int(15.75 * 2 ** 30)
 
 
-@pytest.fixture(scope="module")
-def hybrid(topo, described):
-    """The engine over ``phi4_mini_flash``'s configuration
-    (``arch:sambay_lm``): weights and the three kinds of pooled state as
-    shapes on the described chip (``described`` keeps the cache off)."""
+def _family_on_chip(described, name):
+    """The engine over ``benchmarks/configs/<name>.json`` (a family
+    behind ``arch:``): weights and pooled state as shapes on the
+    described chip, the engine built over a small pool and no weights
+    (its closures are made on first use, over ``cfg``)."""
     import jax
 
     from nnstreamer_tpu.llm.engine import DecodeEngine
@@ -149,7 +149,7 @@ def hybrid(topo, described):
     from nnstreamer_tpu.llm.pool import KVCachePool
 
     with open(os.path.join(ROOT, "benchmarks", "configs",
-                           "phi4_mini_flash.json")) as fh:
+                           f"{name}.json")) as fh:
         config = json.load(fh)
     family, own = family_of_custom({k: str(v)
                                     for k, v in config["model"].items()})
@@ -164,13 +164,20 @@ def hybrid(topo, described):
     small = family.config_from_custom(dict(own, max_seq="512"))
     engine = DecodeEngine({}, small, KVCachePool(small, 1, family=family),
                           capacity=1)
-    engine.cfg = cfg        # the closures are made on first use, over cfg
+    engine.cfg = cfg
     return {"engine": engine, "cfg": cfg, "config": config,
             "params": shapes(lambda: family.init_params(cfg, 0)),
             "state": shapes(lambda: family.init_state(
                 cfg, config["element"]["slots"])),
             "sampled": on_chip(config["element"]["slots"] + 1),
             "on_chip": on_chip}
+
+
+@pytest.fixture(scope="module")
+def hybrid(topo, described):
+    """``phi4_mini_flash`` (``arch:sambay_lm``): weights and the three
+    kinds of pooled state (``described`` keeps the cache off)."""
+    return _family_on_chip(described, "phi4_mini_flash")
 
 
 def _resident(stats) -> int:
@@ -218,3 +225,51 @@ def test_hybrid_prefill_chunk_fits_beside_the_pools(hybrid):
         "pool_bytes"]
     assert stats.temp_size_in_bytes < 1.0e9            # found: 0.30 GB
     assert _resident(stats) < USABLE_BYTES
+
+
+# -- the third family, at GigaChat3.1-702B-A36B's widths -----------------
+@pytest.fixture(scope="module")
+def latent(topo, described):
+    """``gigachat31_702b_a36b`` (``arch:dsv3_lm``) with the chip's
+    branch taken (``GROUPED_KERNEL``: the default backend is the CPU
+    here): the decode attention is the kernel of
+    ``ops/latent_decode.py`` over the pool where it lies."""
+    from nnstreamer_tpu.models import dsv3_lm
+
+    kernel, dsv3_lm.GROUPED_KERNEL = dsv3_lm.GROUPED_KERNEL, True
+    yield _family_on_chip(described, "gigachat31_702b_a36b")
+    dsv3_lm.GROUPED_KERNEL = kernel
+
+
+@pytest.mark.parametrize("lanes", [1, 8, 64])
+def test_latent_decode_step_attends_the_pool_where_it_lies(latent, lanes):
+    """Every lane count runs the kernel: five custom calls beside the
+    grouped products', each handed the WHOLE donated pool between one
+    layer's scatter and the next's — and the pool stays still: aliased,
+    no ``copy`` of its shape or of one layer's (a slice feeding a custom
+    call is one), temporaries far under one layer's rows (0.68 GB), no
+    float32 scores of every reserved position (the XLA form's ``f32[65,
+    64,8192]``, 136 MB a layer)."""
+    d = latent
+    cfg, slots = d["cfg"], d["config"]["element"]["slots"]
+    vec = d["on_chip"](lanes)
+    compiled = d["engine"]._step_fn(lanes).lower(
+        d["params"], d["state"], d["sampled"], vec, vec).compile()
+    stats = compiled.memory_analysis()
+    layer_rows = (slots + 1) * cfg.max_seq * cfg.row_held * 2
+    assert layer_rows == 681574400
+    assert stats.alias_size_in_bytes >= cfg.layers * layer_rows
+    assert stats.temp_size_in_bytes < layer_rows / 4   # found: 0.01 GB
+    assert _resident(stats) < USABLE_BYTES
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        >= cfg.layers + 2 * cfg.expert_layers
+    assert "latent_decode_attention" in text
+    pool = f"{cfg.layers},{slots + 1},{cfg.max_seq},{cfg.row_held}"
+    one = f"{slots + 1},{cfg.max_seq},{cfg.row_held}"
+    assert f"bf16[{pool}]" in text
+    assert not re.search(
+        r"= bf16\[(%s|%s)\]\S* copy\(" % (pool, one), text)
+    assert "remat_" not in text
+    assert f"f32[{slots + 1},{cfg.heads},{cfg.max_seq}]" not in text
+    assert f"f32[{lanes},{cfg.heads},{cfg.max_seq}]" not in text

@@ -133,6 +133,27 @@ def test_one_decode_span_with_a_step_per_engine_step(round_trip):
     assert steps[0][3]["lanes"] == 2
 
 
+def test_a_decode_span_says_how_many_positions_its_lanes_attend(
+        round_trip):
+    """``attended`` = the sum over the step's lanes of ``pos + 1``, a
+    host integer known at dispatch: prompts of 3 and 9 tokens make the
+    first step attend 4 + 10 positions, and every step a lane one more;
+    the engine totals them beside what the lanes' slots reserve."""
+    spans, counts = round_trip
+    steps = [s[3] for s in spans if s[0] == "llm.decode" and "step" in s[3]]
+    assert steps[0]["attended"] == (3 + 1) + (9 + 1)
+    for was, now in zip(steps, steps[1:]):
+        if was["lanes"] == now["lanes"] == 2:
+            assert now["attended"] == was["attended"] + 2
+    assert all(s["attended"] >= s["lanes"] for s in steps)
+    report = counts["report"]
+    assert report["attended_positions"] == sum(s["attended"]
+                                               for s in steps)
+    assert report["reserved_positions"] == 48 * sum(s["lanes"]
+                                                    for s in steps)
+    assert 0 < report["attended_positions"] < report["reserved_positions"]
+
+
 def test_every_step_but_the_first_of_a_busy_run_is_dispatched_ahead(
         round_trip):
     """Both streams are resident before the first step and one goes on
