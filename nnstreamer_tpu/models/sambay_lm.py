@@ -25,10 +25,11 @@ plain reference this file is tested against.
 What a session keeps (the three kinds of state :func:`init_state` lays
 side by side, one row per slot): layer h + 1's keys and values by
 position (``kv``: ``(1, slots + 1, max_seq, kv_heads * head_dim)``, the
-dense pool's own layout, read through ``_slot_rows``); a ring of
-``window`` positions for each windowed layer, written at ``pos mod
-window`` (``ring``); the convolution tail and the float32 SSM state of
-each Mamba layer (``conv``, ``ssm``: fixed rows, nothing by position).
+dense pool's own layout, which the decode step reads where it lies on a
+TPU, ``ops/shared_kv_decode.py``, and through ``_slot_rows`` elsewhere);
+a ring of ``window`` positions for each windowed layer, written at
+``pos mod window`` (``ring``); the convolution tail and the float32 SSM
+state of each Mamba layer (``conv``, ``ssm``: fixed rows, nothing by position).
 The SSM state is held ``(d_state, d_inner)``: the wide axis on the
 lanes.  A slot a longer session left behind starts clean without being
 cleared: stale keys are masked by position as in every pool, and the
@@ -55,12 +56,18 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.shared_kv_decode import shared_kv_decode_attention
 from .streamformer_lm import _slot_rows
 
 #: names of the arrays of :func:`init_state`, in order
 STATE_KINDS = ("kv", "kv", "ring", "ring", "conv", "ssm")
 #: query positions a prefill chunk's full attention scores at a time
 _QBLOCK = 64
+#: whether the decode step reads layer h + 1's rows through the kernel of
+#: ``ops/shared_kv_decode.py`` or XLA's gathered form: None = where the
+#: default backend is a TPU (a test that compiles for a described chip
+#: sets it)
+SHARED_KV_KERNEL = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -312,30 +319,45 @@ def _head_maps(cfg):
             jax.nn.one_hot(pair, cfg.kv_heads // 2, dtype=jnp.float32))
 
 
-def _diff_attn_rows(q, k_rows, v_rows, valid, lyr, i: int, cfg):
-    """Differential attention of ONE query a lane over that lane's
-    cached rows, the rows left as they lie (``(B, T, kv_heads * hd)``,
-    lane-dense): each query head is laid into the columns of its key
-    head, so the scores are one ``(heads, row) x (T, row)^T`` product a
-    lane and the read-out one ``(heads, T) x (T, row)`` product, of
-    which a head keeps the columns of its value pair.  ``q (B, heads *
-    hd)``, ``valid (B, T)``; returns ``(B, heads * hd)`` before
-    ``w_o``."""
-    hd, b = cfg.head_dim, q.shape[0]
-    to_key, to_pair = _head_maps(cfg)
-    qm = jnp.einsum("bhd,hk->bhkd",
-                    q.reshape(b, cfg.heads, hd).astype(cfg.dtype),
-                    to_key).reshape(b, cfg.heads, cfg.kv_row)
-    s = jnp.einsum("bhr,btr->bht", qm, k_rows,
-                   preferred_element_type=jnp.float32) / math.sqrt(hd)
-    p = jax.nn.softmax(jnp.where(valid[:, None, :], s, -jnp.inf), axis=-1)
-    wide = jnp.einsum("bht,btr->bhr", p.astype(cfg.dtype), v_rows,
-                      preferred_element_type=jnp.float32)
+def _query_rows(q, cfg):
+    """``q (B, heads * hd)`` with each query head laid into the columns
+    of its key head: ``(B, heads, kv_heads * hd)`` in ``cfg.dtype``, so
+    a lane's scores are one ``(heads, row) x (T, row)^T`` product."""
+    b = q.shape[0]
+    to_key, _ = _head_maps(cfg)
+    q3 = q.reshape(b, cfg.heads, cfg.head_dim).astype(cfg.dtype)
+    return jnp.einsum("bhd,hk->bhkd", q3, to_key).reshape(
+        b, cfg.heads, cfg.kv_row)
+
+
+def _pair_out(wide, lyr, i: int, cfg):
+    """The read-out ``wide (B, heads, kv_heads * hd)`` float32 (every
+    head's weighted sum of whole V rows), of which a head keeps the
+    columns of its value pair, through the pairs' difference and sub-norm:
+    ``(B, heads * hd)`` before ``w_o``."""
+    hd, b = cfg.head_dim, wide.shape[0]
+    _, to_pair = _head_maps(cfg)
     a = jnp.einsum("bhge,hg->bhe",
                    wide.reshape(b, cfg.heads, cfg.kv_heads // 2, 2 * hd),
                    to_pair).reshape(b, cfg.heads // 2, 2, 2 * hd)
     o = _sub_norm(a[:, :, 0], a[:, :, 1], lyr, i, cfg)
     return o.reshape(b, cfg.heads * hd)
+
+
+def _diff_attn_rows(q, k_rows, v_rows, valid, lyr, i: int, cfg):
+    """Differential attention of ONE query a lane over that lane's
+    cached rows, the rows left as they lie (``(B, T, kv_heads * hd)``,
+    lane-dense): the scores are one ``(heads, row) x (T, row)^T``
+    product a lane (:func:`_query_rows`) and the read-out one ``(heads,
+    T) x (T, row)`` product (:func:`_pair_out`).  ``q (B, heads * hd)``,
+    ``valid (B, T)``; returns ``(B, heads * hd)`` before ``w_o``."""
+    s = jnp.einsum("bhr,btr->bht", _query_rows(q, cfg), k_rows,
+                   preferred_element_type=jnp.float32) / math.sqrt(
+                       cfg.head_dim)
+    p = jax.nn.softmax(jnp.where(valid[:, None, :], s, -jnp.inf), axis=-1)
+    wide = jnp.einsum("bht,btr->bhr", p.astype(cfg.dtype), v_rows,
+                      preferred_element_type=jnp.float32)
+    return _pair_out(wide, lyr, i, cfg)
 
 
 def _split_qkv(y, lyr, cfg):
@@ -476,10 +498,12 @@ def _ring_after(ring, rows, start, end, cfg):
     return jnp.where((p >= start)[:, None], rows[take], ring)
 
 
-def _cross_decoder(x, m, k_rows, v_rows, valid, params, cfg):
+def _cross_decoder(x, m, attend, params, cfg):
     """Layers ``h + 2 ..`` and the head for one position of each of
     ``B`` lanes: ``x (B, dim)``, ``m (B, d_inner)`` layer h's scan
-    output there, the cache's rows as gathered."""
+    output there, ``attend(q, lyr, i)`` the caller's reading of the
+    cache's rows (differential attention, ``(B, heads * hd)`` before
+    ``w_o``)."""
     for i in range(cfg.yoco + 2, cfg.layers):
         lyr = params["layers"][i]
         if layer_kind(i, cfg) == "gmu":
@@ -490,7 +514,7 @@ def _cross_decoder(x, m, k_rows, v_rows, valid, params, cfg):
                 q = _mm(_ln(x, lyr["ln1"], cfg.eps), lyr["w_q"]) \
                     + lyr["b_q"]
             with jax.named_scope("sflm.cross_attn"):
-                o = _diff_attn_rows(q, k_rows, v_rows, valid, lyr, i, cfg)
+                o = attend(q, lyr, i)
                 x = x + _mm(o, lyr["w_o"]) + lyr["b_o"]
         x = _mlp(x, lyr, cfg)
     return _logits(x, params, cfg)
@@ -590,8 +614,10 @@ def prefill_chunk(params: Dict[str, Any], state: Tuple[jnp.ndarray, ...],
         one = lambda a: jax.lax.dynamic_slice_in_dim(   # noqa: E731
             a, true_len - 1, 1)
         valid = jnp.arange(cfg.max_seq)[None, :] < end
-        return _cross_decoder(one(x), one(m), k_all[None], v_all[None],
-                              valid, params, cfg)[0]
+        return _cross_decoder(
+            one(x), one(m), lambda q, lyr, i: _diff_attn_rows(
+                q, k_all[None], v_all[None], valid, lyr, i, cfg),
+            params, cfg)[0]
 
     logits = jax.lax.cond(
         last, tail, lambda: jnp.zeros((cfg.vocab,), jnp.float32))
@@ -599,16 +625,44 @@ def prefill_chunk(params: Dict[str, Any], state: Tuple[jnp.ndarray, ...],
 
 
 # -- serving: one token a lane -------------------------------------------
+def _shared_rows(kpool, vpool, slots, pos, cfg):
+    """The decode step's reading of layer h + 1's rows, ``attend(q, lyr,
+    i)`` as :func:`_cross_decoder` takes it, for the full layer and every
+    cross-attention layer.  On a TPU (:data:`SHARED_KV_KERNEL`) the
+    kernel over both pools where they lie, each lane's slot up to its
+    position (``slots`` is the gather), once a reading; elsewhere the
+    lanes' rows gathered once (``_slot_rows``, every reserved position)
+    and XLA's products over them."""
+    kernel = SHARED_KV_KERNEL
+    if kernel is None:
+        kernel = jax.default_backend() == "tpu"
+    if kernel:
+        def attend(q, lyr, i):
+            wide = shared_kv_decode_attention(
+                _query_rows(q, cfg), kpool, vpool, slots, pos,
+                1.0 / math.sqrt(cfg.head_dim))
+            return _pair_out(wide, lyr, i, cfg)
+        return attend
+    with jax.named_scope("sflm.kv_read"):
+        # the barrier keeps the attention's say over layouts out of the
+        # gather (streamformer_lm's decode_step_pooled; PERF.md section 6)
+        k_rows, v_rows = jax.lax.optimization_barrier(
+            (_slot_rows(kpool, 0, slots), _slot_rows(vpool, 0, slots)))
+    seen = jnp.arange(cfg.max_seq)[None, :] <= pos[:, None]
+    return lambda q, lyr, i: _diff_attn_rows(q, k_rows, v_rows, seen, lyr,
+                                             i, cfg)
+
+
 def decode_step(params: Dict[str, Any], state: Tuple[jnp.ndarray, ...],
                 tokens: jnp.ndarray, pos: jnp.ndarray, slots: jnp.ndarray,
                 cfg: SambaYConfig
                 ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, ...]]:
     """One decode step over ``B`` lanes, each at its own position in its
     own slot (padding lanes: the scratch slot, position 0).  Layer ``h +
-    1``'s rows are written once, gathered once (``_slot_rows``, every
-    reserved position of each lane) and read by the full layer and by
-    every cross-attention layer.  Returns ``(logits (B, vocab) f32,
-    state')``."""
+    1``'s rows are written once and read by the full layer and by every
+    cross-attention layer (:func:`_shared_rows`: on a TPU each reading
+    walks each lane's rows up to its position where they lie).  Returns
+    ``(logits (B, vocab) f32, state')``."""
     kpool, vpool, rk, rv, conv, ssm = state
     w, h = cfg.window, cfg.yoco
     with jax.named_scope("sflm.embed"):
@@ -617,8 +671,7 @@ def decode_step(params: Dict[str, Any], state: Tuple[jnp.ndarray, ...],
     at = pos % w
     in_ring = ((jnp.arange(w)[None, :] <= pos[:, None])
                | (pos[:, None] >= w))
-    seen = jnp.arange(cfg.max_seq)[None, :] <= pos[:, None]
-    m = k_rows = v_rows = None
+    m = attend = None
     for i in range(h + 2):
         lyr = params["layers"][i]
         kind, j = layer_kind(i, cfg), i // 2
@@ -653,19 +706,12 @@ def decode_step(params: Dict[str, Any], state: Tuple[jnp.ndarray, ...],
                 with jax.named_scope("sflm.kv_write"):
                     kpool = kpool.at[0, slots, pos].set(k)
                     vpool = vpool.at[0, slots, pos].set(v)
-                with jax.named_scope("sflm.kv_read"):
-                    # the barrier keeps the attention's say over
-                    # layouts out of the gather (streamformer_lm's
-                    # decode_step_pooled; PERF.md section 6, PR 26)
-                    k_rows, v_rows = jax.lax.optimization_barrier(
-                        (_slot_rows(kpool, 0, slots),
-                         _slot_rows(vpool, 0, slots)))
+                attend = _shared_rows(kpool, vpool, slots, pos, cfg)
                 with jax.named_scope("sflm.full_attn"):
-                    o = _diff_attn_rows(q, k_rows, v_rows, seen, lyr, i,
-                                        cfg)
+                    o = attend(q, lyr, i)
                     x = x + _mm(o, lyr["w_o"]) + lyr["b_o"]
         x = _mlp(x, lyr, cfg)
-    logits = _cross_decoder(x, m, k_rows, v_rows, seen, params, cfg)
+    logits = _cross_decoder(x, m, attend, params, cfg)
     return logits, (kpool, vpool, rk, rv, conv, ssm)
 
 

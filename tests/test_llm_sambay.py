@@ -23,6 +23,7 @@ from nnstreamer_tpu.llm.engine import DecodeEngine  # noqa: E402
 from nnstreamer_tpu.llm.family import family_of_custom, get_family  # noqa: E402
 from nnstreamer_tpu.llm.pool import KVCachePool  # noqa: E402
 from nnstreamer_tpu.models import sambay_lm as sm  # noqa: E402
+from nnstreamer_tpu.ops import shared_kv_decode  # noqa: E402
 
 #: every kind of layer and the 4/5/6 hand-over; window 8, chunk 8
 MODEL = {"arch": "sambay_lm", "vocab": 257, "dim": 64, "heads": 8,
@@ -190,6 +191,61 @@ def test_a_reused_slot_starts_clean(world):
                                one(0))
         assert np.array_equal(np.asarray(la), np.asarray(lb))
         assert np.abs(np.asarray(la)[0] - world["ref"][p]).max() < TOL
+
+
+def _decode_path(kernel, cfg, monkeypatch):
+    """The decode step with one of its two readings of the cache, both
+    on the CPU: XLA's over the gathered rows, or the chip's kernel
+    (``ops/shared_kv_decode.py``) in Pallas' interpret mode, in blocks
+    of 16 positions so that a slot of 64 is four of them."""
+    monkeypatch.setattr(sm, "SHARED_KV_KERNEL", kernel)
+    if kernel:
+        monkeypatch.setattr(shared_kv_decode, "BLOCK_T", 16)
+        monkeypatch.setattr(sm, "shared_kv_decode_attention", partial(
+            shared_kv_decode.shared_kv_decode_attention, interpret=True))
+    return jax.jit(partial(sm.decode_step, cfg=cfg))
+
+
+def test_the_kernel_serves_the_tokens_the_gathered_form_serves(
+        world, monkeypatch):
+    """Three sessions at positions in different blocks of their slots,
+    one of them in a slot a longer session left (its rows past the new
+    position stale), and a padding lane, decode six greedy tokens each:
+    through the kernel the same tokens, logits and pools as through
+    XLA's gathered form."""
+    cfg, params = world["cfg"], world["params"]
+    rng = np.random.default_rng(8)
+    state = _dirty(cfg, 3)
+    _, state = _prefill(world, state, 1, rng.integers(0, cfg.vocab, 40))
+    slots = np.array([2, 3, 0, 1], np.int32)     # the last lane: padding
+    first, pos = [], []
+    for slot, n in ((2, 17), (0, 30), (1, 5)):
+        logits, state = _prefill(world, state, slot,
+                                 rng.integers(0, cfg.vocab, n))
+        first.append(int(logits.argmax()))
+        pos.append(n)
+    lanes = [0, 3, 1, 2]                          # lane -> session order
+
+    def serve(kernel):
+        step = _decode_path(kernel, cfg, monkeypatch)
+        st, toks = state, np.array(first + [0], np.int32)[lanes]
+        at = np.array(pos + [0], np.int32)[lanes]
+        out = []
+        for _ in range(6):
+            logits, st = step(params, st, jnp.asarray(toks),
+                              jnp.asarray(at), jnp.asarray(slots))
+            out.append(np.asarray(logits))
+            toks = out[-1].argmax(-1).astype(np.int32)
+            at = np.where(slots < 3, at + 1, 0).astype(np.int32)
+        return np.stack(out), st
+
+    want, want_state = serve(False)
+    got, got_state = serve(True)
+    real = slots < 3
+    assert np.array_equal(got.argmax(-1)[:, real], want.argmax(-1)[:, real])
+    assert np.abs(got - want).max() < TOL
+    for a, b in zip(got_state, want_state):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
 BRANCHES = {
