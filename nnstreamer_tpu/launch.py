@@ -288,6 +288,9 @@ def main(argv=None) -> int:
         if args.jax_trace:
             import jax
 
+            from .obs import devtrace
+
+            devtrace.bind()   # the wire's and GC's spans join the trace
             jax.profiler.start_trace(args.jax_trace)
         publisher = None
         if args.push_metrics:

@@ -60,6 +60,12 @@ from ..tensor.caps_util import tensors_template_caps
 
 #: request header length: [prompt_len, max_new_tokens, stop_token]
 REQ_HEADER = 3
+#: why a request was shed: the pool's hard boundaries (``no_slot``; the
+#: paged arena's page commitment, ``no_page``), its occupancy
+#: watermarks (``policy``), a drain, or an ``admit-timeout-ms`` that ran
+#: out waiting for a slot.  ``reject`` (malformed or over-length, never
+#: retried) is counted beside them in :meth:`TensorLLM.report`
+SHED_CAUSES = ("no_slot", "no_page", "policy", "drain", "admit_timeout")
 
 
 class _Request:
@@ -309,6 +315,7 @@ class TensorLLM(Element):
     # -- lifecycle -------------------------------------------------------
     def start(self):
         from ..filter.framework import FilterProperties
+        from ..obs import devtrace
         from ..obs.clock import mono_ns
         from ..utils.platform import enable_compile_cache
         from .engine import DecodeEngine
@@ -376,7 +383,22 @@ class TensorLLM(Element):
         self.rejected_total = 0
         self.evicted_total = 0
         self.sessions_total = 0
+        #: refusals by cause (:data:`SHED_CAUSES` and ``reject``)
+        self.refused = dict.fromkeys(SHED_CAUSES + ("reject",), 0)
+        # the stall record (report()["stall"]): the longest loop
+        # iteration and the phase that held most of it, the pending
+        # list's peak, chain()'s back-pressure waits
+        self._longest_ns = 0
+        self._longest_phase = ""
+        self._pending_peak = 0
+        self._blocked_ns = 0
+        self._blocked_n = 0
         self._register_gauges()
+        # wire and GC spans land on the device trace's clock beside the
+        # decode thread's phases, when a session records
+        devtrace.bind()
+        self._gc = devtrace.GcWatch(mono_ns)
+        self._gc.install()
         self._thread = threading.Thread(target=self._decode_loop,
                                         daemon=True,
                                         name=f"llm-decode:{self.name}")
@@ -450,14 +472,16 @@ class TensorLLM(Element):
                      / max(1, pool.prefix_hits + pool.prefix_misses)),
                 ))
         names = ["nns_llm_tokens_total", "nns_llm_sessions_total",
-                 "nns_llm_shed_total", "nns_llm_evicted_total",
-                 "nns_llm_rejected_total"]
+                 "nns_llm_evicted_total", "nns_llm_rejected_total"]
         if getattr(eng, "paged", False):
             from .tokenobs import PAGES_RECLAIMED_TOTAL
 
             names.append(PAGES_RECLAIMED_TOTAL)
         self._obs_counters = {
             n: REGISTRY.counter(n, **labels) for n in names}
+        self._shed_counters = {
+            why: REGISTRY.counter("nns_llm_shed_total", cause=why, **labels)
+            for why in SHED_CAUSES}
         self._ctr_tokens = 0    # counter mirror of engine.tokens_total
         self._ctr_reclaimed = 0  # mirror of pool.pages_reclaimed
 
@@ -471,16 +495,38 @@ class TensorLLM(Element):
         if thread is not None:
             thread.join(timeout=30)
             self._thread = None
+        watch = getattr(self, "_gc", None)
+        if watch is not None:
+            watch.remove()
         for g in getattr(self, "_obs_gauges", ()):
             REGISTRY.unregister(g)
         self._obs_gauges = []
-        engine = getattr(self, "engine", None)
-        if engine is not None:
+        if getattr(self, "engine", None) is not None:
             # the loop has ended: the one place the element itself reads
             # what a family counts inside its state (``state_counters``)
-            self.final_report = engine.report()
+            self.final_report = self.report()
         self.engine = None
         self.pool = None
+
+    def report(self) -> Dict[str, Any]:
+        """:meth:`DecodeEngine.report`, and what the element counts
+        over its life: ``refused`` by cause; ``gc``, Python's
+        collections by generation (count, pause, the longest); and
+        ``stall``: the longest decode-loop iteration (its idle wait
+        left out) with the phase that held most of it, the pending
+        list's peak (read when the loop takes it), and the time
+        ``chain()`` spent blocked on back-pressure.  ``final_report``
+        at ``stop`` is this."""
+        out = self.engine.report()
+        out["refused"] = dict(self.refused)
+        out["gc"] = self._gc.report()
+        out["stall"] = {
+            "longest_iteration_ms": round(self._longest_ns / 1e6, 3),
+            "longest_iteration_phase": self._longest_phase,
+            "pending_peak": self._pending_peak,
+            "chain_blocked_ms": round(self._blocked_ns / 1e6, 3),
+            "chain_blocks": self._blocked_n}
+        return out
 
     def unblock(self):
         with self._cv:
@@ -565,9 +611,13 @@ class TensorLLM(Element):
             # bounded pending: backpressure the serving thread (and
             # through it the serversrc's bounded queue, whose admission
             # sheds at ITS watermarks) rather than queue unbounded
-            self._cv.wait_for(
-                lambda: len(self._pending) < self._depth
-                or self._stopping)
+            if len(self._pending) >= self._depth and not self._stopping:
+                t0 = self._mono_ns()
+                self._cv.wait_for(
+                    lambda: len(self._pending) < self._depth
+                    or self._stopping)
+                self._blocked_ns += self._mono_ns() - t0
+                self._blocked_n += 1
             if self._stopping:
                 return FlowReturn.EOS
             self._pending.append(req)
@@ -616,7 +666,9 @@ class TensorLLM(Element):
         eng = self.engine
         pool = self.pool
         rr = 0                         # round-robin cursor
+        mark = eng.phases.totals_ns()
         while True:
+            mark = self._note_iteration(mark)
             with self._cv:
                 if self._stopping:
                     return
@@ -628,6 +680,8 @@ class TensorLLM(Element):
                     if self._stopping:
                         return
                 taken, self._pending = self._pending, []
+                if len(taken) > self._pending_peak:
+                    self._pending_peak = len(taken)
                 self._cv.notify_all()   # free chain() backpressure slots
             self._prune_sessions()
             requeue = self._admit(taken)
@@ -661,6 +715,20 @@ class TensorLLM(Element):
                 if not self._pending and pool.live == 0:
                     self._cv.notify_all()   # EOS/drain waiters
 
+    def _note_iteration(self, was: Dict[str, int]) -> Dict[str, int]:
+        """The stall record's longest iteration: the phase totals since
+        ``was`` (the last iteration's start), its idle wait left out;
+        returns the totals now, the next iteration's start."""
+        now = self.engine.phases.totals_ns()
+        busy = (sum(now.values()) - now["idle"]) \
+            - (sum(was.values()) - was["idle"])
+        if busy > self._longest_ns:
+            self._longest_ns = busy
+            self._longest_phase = max(
+                (p for p in now if p != "idle"),
+                key=lambda p: now[p] - was[p])
+        return now
+
     # -- admission -------------------------------------------------------
     def _admit(self, reqs: List[_Request]) -> List[_Request]:
         """Admit / shed / requeue pending requests.  Returns the
@@ -670,9 +738,12 @@ class TensorLLM(Element):
         requeue: List[_Request] = []
         for req in reqs:
             # waited_us: chain() → the one decode thread taking the
-            # request, the wait for the running step included
+            # request, the wait for the running step included; cid and
+            # seq join it to the request's wire spans (-1: no wire)
             prev = eng.phases.enter(
-                "admit", waited_us=int((self._now() - req.born_s) * 1e6))
+                "admit", waited_us=int((self._now() - req.born_s) * 1e6),
+                cid=req.extra.get("query_client_id", -1),
+                seq=req.extra.get("query_seq", -1))
             try:
                 if req.prompt is None \
                         or len(req.prompt) + req.max_new \
@@ -682,26 +753,24 @@ class TensorLLM(Element):
                     # stop-token answer, not a shed
                     eng.phases.note(outcome="reject")
                     self.rejected_total += 1
+                    self.refused["reject"] += 1
                     self._obs_counters["nns_llm_rejected_total"].inc()
                     if self._tok_obs is not None:
                         self._tok_obs.on_refused(req.qos, "reject")
                     self._emit(req.extra, req.stop_token, 0, last=True)
                     continue
-                verdict = pool.admit(req.qos,
-                                     no_slot_retry_s=eng
-                                     .retry_after_hint(),
-                                     prompt=req.prompt,
-                                     max_new=req.max_new)
+                verdict, why = pool.admit_verdict(
+                    req.qos, no_slot_retry_s=eng.retry_after_hint(),
+                    prompt=req.prompt, max_new=req.max_new)
                 if verdict is not None:
-                    if self._admit_timeout > 0 \
-                            and self._now() - req.born_s \
-                            < self._admit_timeout \
-                            and not pool.admission.draining:
-                        eng.phases.note(outcome="requeue")
-                        requeue.append(req)
-                    else:
-                        eng.phases.note(outcome="shed")
-                        self._shed(req, verdict)
+                    if self._admit_timeout > 0 and why != "drain":
+                        if self._now() - req.born_s < self._admit_timeout:
+                            eng.phases.note(outcome="requeue")
+                            requeue.append(req)
+                            continue
+                        why = "admit_timeout"
+                    eng.phases.note(outcome="shed", cause=why)
+                    self._shed(req, verdict, why)
                     continue
                 eng.phases.note(outcome="admit")
                 sess = pool.acquire(req.key, qos=req.qos,
@@ -746,9 +815,11 @@ class TensorLLM(Element):
                 eng.phases.enter(prev)
         return requeue
 
-    def _shed(self, req: _Request, retry_after_s: float) -> None:
+    def _shed(self, req: _Request, retry_after_s: float,
+              cause: str) -> None:
         self.shed_total += 1
-        self._obs_counters["nns_llm_shed_total"].inc()
+        self.refused[cause] += 1
+        self._shed_counters[cause].inc()
         if self._tok_obs is not None:
             # counted, never observed: a fast shed must not flatter
             # the admitted-traffic TTFT distribution

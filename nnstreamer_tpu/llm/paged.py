@@ -41,7 +41,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 from collections import OrderedDict
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -241,6 +241,16 @@ class PagedKVCachePool:
         hard boundaries: the session-count bound and the page
         commitment bound (this request's worst-case private pages, net
         of its prefix hit, against what the arena still has)."""
+        return self.admit_verdict(qos, no_slot_retry_s, prompt,
+                                  max_new)[0]
+
+    def admit_verdict(self, qos: str, no_slot_retry_s: float = 0.25,
+                      prompt: Optional[np.ndarray] = None,
+                      max_new: int = 0) -> Tuple[Optional[float], str]:
+        """:meth:`admit` with the cause of a refusal: ``drain``,
+        ``policy`` (the watermarks over page commitment), ``no_slot``
+        (the session-count bound) or ``no_page`` (the page commitment
+        bound); ``(None, "")`` admits."""
         plen = int(np.asarray(prompt).shape[0]) if prompt is not None \
             else 1
         with self._lock:
@@ -250,14 +260,16 @@ class PagedKVCachePool:
             hashes = chain_hashes(prompt, self.page_size) \
                 if prompt is not None else []
             hit, resurrect = self._match(hashes, plen)
-        verdict = self.admission.admit(qos or "silver", depth, self.pages)
+        verdict, why = self.admission.verdict(qos or "silver", depth,
+                                              self.pages)
         if verdict is not None:
-            return verdict
+            return verdict, why
+        if n_live >= self.slots:
+            return max(float(no_slot_retry_s), 0.01), "no_slot"
         need = self._need_pages(plen, max_new) - hit
-        if n_live >= self.slots \
-                or usable - resurrect - self._reserved < need:
-            return max(float(no_slot_retry_s), 0.01)
-        return None
+        if usable - resurrect - self._reserved < need:
+            return max(float(no_slot_retry_s), 0.01), "no_page"
+        return None, ""
 
     def acquire(self, key, qos: str = "silver",
                 extra: Optional[Dict[str, Any]] = None,

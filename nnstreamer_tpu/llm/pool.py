@@ -158,15 +158,25 @@ class SlotPool:
         ``max_new`` are accepted for pool-interface parity (the paged
         pool admits on page commitment) and ignored here: a dense slot
         costs ``max_seq`` regardless of what the session uses."""
+        return self.admit_verdict(qos, no_slot_retry_s, prompt,
+                                  max_new)[0]
+
+    def admit_verdict(self, qos: str, no_slot_retry_s: float = 0.25,
+                      prompt=None, max_new: int = 0
+                      ) -> Tuple[Optional[float], str]:
+        """:meth:`admit` with the cause of a refusal: ``drain``,
+        ``policy`` (the occupancy watermarks) or ``no_slot`` (the hard
+        boundary); ``(None, "")`` admits."""
         with self._lock:
             depth = len(self._live)
             free = bool(self._free)
-        verdict = self.admission.admit(qos or "silver", depth, self.slots)
+        verdict, why = self.admission.verdict(qos or "silver", depth,
+                                              self.slots)
         if verdict is not None:
-            return verdict
+            return verdict, why
         if not free:
-            return max(float(no_slot_retry_s), 0.01)
-        return None
+            return max(float(no_slot_retry_s), 0.01), "no_slot"
+        return None, ""
 
     def acquire(self, key, qos: str = "silver",
                 extra: Optional[Dict[str, Any]] = None,

@@ -274,19 +274,28 @@ class AdmissionController:
 
     def admit(self, qos: str, depth: int,
               capacity: int) -> Optional[float]:
+        return self.verdict(qos, depth, capacity)[0]
+
+    def verdict(self, qos: str, depth: int,
+                capacity: int) -> Tuple[Optional[float], str]:
+        """:meth:`admit` with the cause of a refusal: ``(None, "")``
+        admits; a shed names what refused it — ``drain``, ``policy``
+        (the shed policy's watermarks) or ``rate`` (the token
+        bucket)."""
         drain_until = self._drain_until
         if drain_until is not None:
             # drain retry-after: clients should come back after the
             # replacement had time to take over (≥ remaining drain)
-            return max(0.1, drain_until - self._drain_clock() + 0.5)
+            return max(0.1, drain_until - self._drain_clock() + 0.5), \
+                "drain"
         # policy first, bucket second: a policy-shed request must not
         # burn a token, or shed floods would starve the capacity the
         # bucket is supposed to guarantee the admitted tiers
         verdict = self.policy.decide(qos, depth, capacity)
         if verdict is not None:
-            return verdict
+            return verdict, "policy"
         if self.bucket is not None:
             ok, wait = self.bucket.take()
             if not ok:
-                return max(wait, 0.01)
-        return None
+                return max(wait, 0.01), "rate"
+        return None, ""

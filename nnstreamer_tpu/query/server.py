@@ -16,9 +16,10 @@ from __future__ import annotations
 import queue as _queue
 import socket
 import threading
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 from ..analysis.sanitizer import make_condition, make_lock
+from ..obs import devtrace
 from ..obs.clock import wall_us
 from ..obs.span import TraceContext
 from ..pipeline.caps import Caps
@@ -44,6 +45,18 @@ DEFAULT_QUEUE_DEPTH = 256
 #: draining replies for this long is a zombie and gets evicted, instead
 #: of wedging the serving pipeline thread inside reply()
 DEFAULT_SEND_TIMEOUT = 5.0
+#: why the server answered a DATA frame with ``T_SHED``: its admission
+#: policy's queue-depth watermarks, its token bucket (``capacity-rps``),
+#: a full queue, a drain, or the serving element downstream
+#: (``shed_frame``: the token element's slot admission)
+REFUSALS = ("watermark", "rate", "queue_full", "drain", "element")
+#: why an answer never left for its client (``_reply`` or
+#: ``shed_frame`` returned False): the connection was already gone, the
+#: bounded send timed out and evicted the client, or the socket failed.
+#: A client sees none of these but as a timeout of its own
+LOSSES = ("client_gone", "send_timeout", "os_error")
+#: the admission controller's name for a cause, where the wire's differs
+_WIRE_CAUSE = {"policy": "watermark"}
 
 
 class QueryServer:
@@ -129,9 +142,14 @@ class QueryServer:
                                 port=str(self.port), qos=c)
             for c in QOS_CLASSES}
         self._m_shed = {
-            c: REGISTRY.counter("nns_query_server_shed_total",
-                                port=str(self.port), qos=c)
-            for c in QOS_CLASSES}
+            (c, why): REGISTRY.counter("nns_query_server_shed_total",
+                                       port=str(self.port), qos=c,
+                                       cause=why)
+            for c in QOS_CLASSES for why in REFUSALS}
+        #: refusals and lost answers by cause, plain integers under
+        #: ``_lock`` (:meth:`counters`)
+        self.refused: Dict[str, int] = dict.fromkeys(REFUSALS, 0)
+        self.lost: Dict[str, int] = dict.fromkeys(LOSSES, 0)
         self._m_shed_rate = REGISTRY.gauge(
             "nns_query_server_shed_rate", fn=self._shed_rate,
             port=str(self.port))
@@ -147,11 +165,18 @@ class QueryServer:
         return shed / max(1, shed + admitted)
 
     def counters(self) -> Dict[str, Dict[str, int]]:
-        """Point-in-time admit/shed counts by QoS class (test/verdict
-        surface; the live metrics ride the registry)."""
+        """Point-in-time admit/shed counts by QoS class, the refusals by
+        cause (:data:`REFUSALS`) and the answers lost by cause
+        (:data:`LOSSES`) — test/verdict surface; the live metrics ride
+        the registry, the shed counter labelled by ``cause`` too."""
+        shed = dict.fromkeys(QOS_CLASSES, 0)
+        for (c, _), m in self._m_shed.items():
+            shed[c] += m.value
+        with self._lock:
+            refused, lost = dict(self.refused), dict(self.lost)
         return {"admitted": {c: m.value
                              for c, m in self._m_admitted.items()},
-                "shed": {c: m.value for c, m in self._m_shed.items()}}
+                "shed": shed, "refused": refused, "lost": lost}
 
     def set_caps_string(self, caps: str) -> None:
         self._caps_str = caps
@@ -179,21 +204,28 @@ class QueryServer:
             threading.Thread(target=self._client_loop, args=(cid, conn),
                              daemon=True, name=f"query-client-{cid}").start()
 
-    def _admit_frame(self, cid: int, msg: Message) -> Optional[float]:
-        """Admission decision for one DATA frame: ``None`` admits, a
-        float sheds with that retry-after hint (seconds).  Header-only:
-        runs BEFORE the payload is decoded into tensors, so a shed
-        request's slab goes straight back to the pool."""
+    def _admit_frame(self, cid: int, msg: Message
+                     ) -> Tuple[Optional[float], str]:
+        """Admission decision for one DATA frame: ``(None, "")`` admits,
+        a float sheds with that retry-after hint (seconds), beside its
+        cause (:data:`REFUSALS`).  Header-only: runs BEFORE the payload
+        is decoded into tensors, so a shed request's slab goes straight
+        back to the pool."""
         if self.admission is None:
-            return None
+            return None, ""
         qos = self._qos.get(cid, DEFAULT_QOS)
-        return self.admission.admit(qos, self.incoming.qsize(),
-                                    self.queue_depth)
+        verdict, why = self.admission.verdict(qos, self.incoming.qsize(),
+                                              self.queue_depth)
+        return verdict, _WIRE_CAUSE.get(why, why)
 
     def _send_shed(self, conn, slock, cid: int, seq: int,
-                   retry_after_s: float) -> None:
+                   retry_after_s: float, cause: str) -> None:
         qos = self._qos.get(cid, DEFAULT_QOS)
-        self._m_shed[qos].inc()
+        self._m_shed[qos, cause].inc()
+        self._count(self.refused, cause)
+        if devtrace.recording():
+            devtrace.instant("query.refused", cause=cause, cid=cid,
+                             seq=seq)
         with slock:
             send_msg(conn, Message(
                 T_SHED, client_id=cid, seq=seq, epoch_us=wall_us(),
@@ -204,6 +236,11 @@ class QueryServer:
         # here would escape the except-OSError below
         slock = self._send_locks.get(cid) or make_lock("query.send")
         pool = default_pool()
+        # the server's part of the dial: this thread's start to the
+        # first message after the capability handshake (in a cell of a
+        # connection per request, the first DATA header)
+        dial = (devtrace.open_span("query.handshake", cid=cid)
+                if devtrace.recording() else None)
         try:
             while not self._stop.is_set():
                 try:
@@ -214,6 +251,9 @@ class QueryServer:
                     break
                 if msg is None or msg.type == T_BYE:
                     break
+                if dial is not None and msg.type != T_HELLO:
+                    dial, opened = None, dial
+                    devtrace.close_span(opened, seq=msg.seq)
                 if msg.type == T_HELLO:
                     # capability handshake: record the client's QoS
                     # declaration (``qos=<class>`` token —
@@ -254,33 +294,16 @@ class QueryServer:
                         collector.ingest(bytes(msg.payload or b""))
                     continue
                 if msg.type == T_DATA:
-                    # admission BEFORE tensor decode: a shed frame's
-                    # pooled payload slab releases immediately instead
-                    # of pinning memory through the serving pipeline
-                    retry_after = self._admit_frame(cid, msg)
-                    if retry_after is not None:
-                        if msg.lease is not None:
-                            msg.payload = b""
-                            msg.lease.release()
-                        self._send_shed(conn, slock, cid, msg.seq,
-                                        retry_after)
-                        continue
-                    buf = TensorBuffer(tensors=decode_tensors(msg.payload),
-                                       pts=msg.pts, lease=msg.lease)
-                    buf.extra["query_client_id"] = cid
-                    buf.extra["query_seq"] = msg.seq
-                    buf.extra["nns_class"] = qos = self._qos.get(
-                        cid, DEFAULT_QOS)
-                    if msg.trace_id:
-                        # restore the client's trace context: spans this
-                        # buffer produces in the serving pipeline record
-                        # under the client's trace id (obs/span.py)
-                        buf.extra["nns_trace"] = TraceContext(
-                            msg.trace_id, msg.span_id, msg.origin_us)
-                    self._enqueue(conn, slock, cid, qos, buf)
+                    # header received → in the queue (or refused)
+                    with (devtrace.span("query.ingress", cid=cid,
+                                        seq=msg.seq)
+                          if devtrace.recording() else devtrace.NO_SPAN):
+                        self._ingress(conn, slock, cid, msg)
         except OSError:
             pass   # link reset under us (recv, or a handshake/pong send)
         finally:
+            if dial is not None:
+                devtrace.close_span(dial)
             with self._lock:
                 self._clients.pop(cid, None)
                 self._send_locks.pop(cid, None)
@@ -289,6 +312,35 @@ class QueryServer:
                 # connection ever made is a slow leak on a long server
                 self._span_cursors.pop(cid, None)
             conn.close()
+
+    def _ingress(self, conn, slock, cid: int, msg: Message) -> None:
+        """One DATA frame: admission, then its tensors into the queue."""
+        # admission BEFORE tensor decode: a shed frame's pooled payload
+        # slab releases immediately instead of pinning memory through
+        # the serving pipeline
+        retry_after, why = self._admit_frame(cid, msg)
+        if retry_after is not None:
+            if msg.lease is not None:
+                msg.payload = b""
+                msg.lease.release()
+            self._send_shed(conn, slock, cid, msg.seq, retry_after, why)
+            return
+        buf = TensorBuffer(tensors=decode_tensors(msg.payload),
+                           pts=msg.pts, lease=msg.lease)
+        buf.extra["query_client_id"] = cid
+        buf.extra["query_seq"] = msg.seq
+        buf.extra["nns_class"] = qos = self._qos.get(cid, DEFAULT_QOS)
+        if msg.trace_id:
+            # restore the client's trace context: spans this buffer
+            # produces in the serving pipeline record under the
+            # client's trace id (obs/span.py)
+            buf.extra["nns_trace"] = TraceContext(
+                msg.trace_id, msg.span_id, msg.origin_us)
+        self._enqueue(conn, slock, cid, qos, buf)
+
+    def _count(self, table: Dict[str, int], cause: str) -> None:
+        with self._lock:
+            table[cause] += 1
 
     def _dec_inflight(self) -> None:
         with self._drain_cv:
@@ -329,7 +381,8 @@ class QueryServer:
                     buf.lease = None   # buffer dies here: drop its slab
                     self._send_shed(conn, slock, cid,
                                     buf.extra.get("query_seq", 0),
-                                    retry_after_s=0.25)
+                                    retry_after_s=0.25,
+                                    cause="queue_full")
                     return
                 continue
             self._m_admitted[qos].inc()
@@ -395,12 +448,15 @@ class QueryServer:
                 conn = self._clients.get(cid)
                 slock = self._send_locks.get(cid)
             if conn is None:
+                self._count(self.lost, "client_gone")
                 return False
             if slock is None:
                 slock = make_lock("query.send")   # teardown race
-            self._send_shed(conn, slock, cid, seq, retry_after_s)
+            self._send_shed(conn, slock, cid, seq, retry_after_s,
+                            "element")
             return True
         except OSError:
+            self._count(self.lost, "os_error")
             return False
         finally:
             self._dec_inflight()
@@ -418,6 +474,7 @@ class QueryServer:
             conn = self._clients.get(cid)
             slock = self._send_locks.get(cid)
         if conn is None:
+            self._count(self.lost, "client_gone")
             return False
         seq = buf.extra.get("query_seq", 0)
         ctx = buf.extra.get("nns_trace") or TraceContext()
@@ -429,11 +486,15 @@ class QueryServer:
                 # reply stamps: echo the trace context, carry this
                 # host's wall clock so the client estimates the offset
                 # (obs/clock.py) from the very frames it already sends
-                send_tensors(conn, T_REPLY, buf, client_id=cid,
-                             seq=seq, pts=buf.pts or 0,
-                             epoch_us=wall_us(),
-                             trace_id=ctx.trace_id, span_id=ctx.span_id,
-                             origin_us=ctx.origin_us)
+                with (devtrace.span("query.reply", cid=cid, seq=seq,
+                                    bytes=buf.nbytes())
+                      if devtrace.recording() else devtrace.NO_SPAN):
+                    send_tensors(conn, T_REPLY, buf, client_id=cid,
+                                 seq=seq, pts=buf.pts or 0,
+                                 epoch_us=wall_us(),
+                                 trace_id=ctx.trace_id,
+                                 span_id=ctx.span_id,
+                                 origin_us=ctx.origin_us)
                 if trace_msg is not None:
                     send_msg(conn, trace_msg)
             return True
@@ -441,12 +502,14 @@ class QueryServer:
             # the bounded send path fired: this client stopped draining
             # its socket.  Evict it — a zombie peer must cost one send
             # timeout, not one timeout per reply forever.
+            self._count(self.lost, "send_timeout")
             self._m_evicted.inc()
             with self._lock:
                 self._clients.pop(cid, None)
             shutdown_close(conn)
             return False
         except OSError:
+            self._count(self.lost, "os_error")
             return False
 
     def drain(self, deadline: float = 5.0) -> bool:
@@ -764,6 +827,9 @@ class TensorQueryServerSrc(Source):
                     enq, mono_ns() if deq_ns is None else deq_ns)
         return buf
 
+    #: the open ``query.chain`` span of the frame being pushed
+    _chain_span = None
+
     def create(self) -> Optional[TensorBuffer]:
         if getattr(self, "_xbatch", 1) > 1:
             return self._create_batched()
@@ -772,8 +838,25 @@ class TensorQueryServerSrc(Source):
                 buf = self.server.incoming.get(timeout=0.1)
             except _queue.Empty:
                 continue
+            if devtrace.recording():
+                # out of the queue → taken by the element downstream
+                # (its chain() returned: for tensor_llm, the request is
+                # in its pending list); closed in push()
+                self._chain_span = devtrace.open_span(
+                    "query.chain", cid=buf.extra.get("query_client_id"),
+                    seq=buf.extra.get("query_seq"))
             return self._note_admission(buf)
         return None
+
+    def push(self, buf: TensorBuffer, pad=None) -> FlowReturn:
+        opened = self._chain_span
+        if opened is None:
+            return super().push(buf, pad)
+        self._chain_span = None
+        try:
+            return super().push(buf, pad)
+        finally:
+            devtrace.close_span(opened)
 
     @staticmethod
     def _frame_sig(buf: TensorBuffer):
