@@ -23,7 +23,11 @@ plane into a continuous-batching token stream server:
   max-new / disconnect).  The loop keeps ONE STEP IN FLIGHT: tokens are
   sampled on the chip and stay there, so step k is dispatched before
   the host has read step k-1, whose tokens it then reads and pushes
-  while the chip works (``_decode_loop_inner``).
+  while the chip works (``_decode_loop_inner``).  A dense whole-prompt
+  prefill is sent the same way: its first token is read after step
+  k-1's, before the wait on step k, and the stream may already ride
+  step k.  An admission pass hands out only the slots that were free
+  when it began; the requests behind them wait for the next pass.
 - **streaming egress**: per-token ``[1, 1] int32`` frames flow to the
   serversink carrying the request's extras (client id, wire seq, QoS,
   trace context), ``pts`` = token index, and ``extra["nns_more"]`` on
@@ -381,6 +385,9 @@ class TensorLLM(Element):
         self._sent_ns = 0                    # the step in flight's dispatch
         self.shed_total = 0
         self.rejected_total = 0
+        #: requests an admission pass left pending, unjudged, because
+        #: the slots free when it began were spent
+        self.admit_deferred = 0
         self.evicted_total = 0
         self.sessions_total = 0
         #: refusals by cause (:data:`SHED_CAUSES` and ``reject``)
@@ -510,7 +517,8 @@ class TensorLLM(Element):
 
     def report(self) -> Dict[str, Any]:
         """:meth:`DecodeEngine.report`, and what the element counts
-        over its life: ``refused`` by cause; ``gc``, Python's
+        over its life: ``refused`` by cause; ``admit_deferred``, the
+        requests an admission pass left for the next; ``gc``, Python's
         collections by generation (count, pause, the longest); and
         ``stall``: the longest decode-loop iteration (its idle wait
         left out) with the phase that held most of it, the pending
@@ -519,6 +527,7 @@ class TensorLLM(Element):
         at ``stop`` is this."""
         out = self.engine.report()
         out["refused"] = dict(self.refused)
+        out["admit_deferred"] = self.admit_deferred
         out["gc"] = self._gc.report()
         out["stall"] = {
             "longest_iteration_ms": round(self._longest_ns / 1e6, 3),
@@ -685,6 +694,11 @@ class TensorLLM(Element):
                 self._cv.notify_all()   # free chain() backpressure slots
             self._prune_sessions()
             requeue = self._admit(taken)
+            if requeue:
+                # back at the head at once: an EOS or drain waiter must
+                # not find the list empty while they wait for a slot
+                with self._cv:
+                    self._pending[:0] = requeue
             # the lanes of step k are chosen before step k-1's tokens
             # are known, from what the host does know: a stream that
             # the step in flight brings to its granted length is left
@@ -708,9 +722,6 @@ class TensorLLM(Element):
             # decodable sessions the loop spins here chunk after chunk
             # — full prefill throughput when there is no one to starve)
             self._advance_prefills()
-            if requeue:
-                with self._cv:
-                    self._pending[:0] = requeue
             with self._cv:
                 if not self._pending and pool.live == 0:
                     self._cv.notify_all()   # EOS/drain waiters
@@ -731,12 +742,25 @@ class TensorLLM(Element):
 
     # -- admission -------------------------------------------------------
     def _admit(self, reqs: List[_Request]) -> List[_Request]:
-        """Admit / shed / requeue pending requests.  Returns the
-        requests still inside their admit-timeout window (no slot yet,
-        not shed by policy)."""
+        """Admit / shed / requeue pending requests.  Returns, in order,
+        the requests still inside their admit-timeout window (no slot
+        yet, not shed by policy) and those the pass did not judge.
+
+        A pass hands out only the slots that were free when it began:
+        the requests behind them wait, unjudged, for the next pass,
+        which comes after a step and its collect have freed the slots
+        of finished streams (``admit_deferred``).  So only a pass that
+        began with no free slot sheds ``no_slot``: a backlog that
+        built up while the thread stalled is served, not shed for the
+        slots it has itself just filled."""
         eng, pool = self.engine, self.pool
         requeue: List[_Request] = []
-        for req in reqs:
+        free, granted = pool.slots - pool.live, 0
+        for i, req in enumerate(reqs):
+            if free and granted == free:
+                self.admit_deferred += len(reqs) - i
+                requeue.extend(reqs[i:])
+                break
             # waited_us: chain() → the one decode thread taking the
             # request, the wait for the running step included; cid and
             # seq join it to the request's wire spans (-1: no wire)
@@ -776,6 +800,7 @@ class TensorLLM(Element):
                 sess = pool.acquire(req.key, qos=req.qos,
                                     extra=req.extra, prompt=req.prompt,
                                     max_new=req.max_new)
+                granted += 1
                 sess.max_new = req.max_new
                 sess.stop_token = req.stop_token
                 sess.truncated = req.truncated
@@ -796,21 +821,26 @@ class TensorLLM(Element):
                     # last chunk lands
                     continue
                 t0 = self._mono_ns()
-                first = eng.prefill(sess, req.prompt)
+                first = eng.dispatch_prefill(
+                    sess, req.prompt,
+                    cid=req.extra.get("query_client_id", -1),
+                    seq=req.extra.get("query_seq", -1))
                 tracer = self._tracer()
                 if tracer is not None:
                     ctx = req.extra.get("nns_trace")
                     if ctx is not None and ctx.trace_id:
-                        # the session's one-time prompt cost, in the
-                        # CLIENT's merged timeline (obs/attrib.py
-                        # llm-prefill state)
+                        # the session's one-time prompt cost on this
+                        # thread, in the CLIENT's merged timeline
+                        # (obs/attrib.py llm-prefill state)
                         tracer.annotate_span("llm-prefill", t0,
                                              self._mono_ns(), seq=-1,
                                              trace_id=ctx.trace_id)
-                # the prefill's token is this session's first answer —
-                # emit it NOW (time-to-first-token is the prefill, not
-                # the prefill plus one bucket cycle)
-                self._finish_or_emit(sess, first)
+                # the prefill's token is this session's first answer:
+                # emitted where it is read — here where the engine
+                # waited for it, by the collect right after this pass
+                # (``_run_step``) where it was sent without waiting
+                if first is not None:
+                    self._finish_or_emit(sess, first)
             finally:
                 eng.phases.enter(prev)
         return requeue
@@ -867,8 +897,10 @@ class TensorLLM(Element):
     # -- stepping / egress -----------------------------------------------
     def _run_step(self, pick) -> None:
         """Dispatch step k over ``pick`` and, while the chip runs it,
-        collect step k-1 and push its tokens.  A slot a stream of step
-        k-1 gives up here is prefilled, by a later iteration, behind
+        collect what was sent before it — step k-1, then the prefills
+        this iteration's admission sent — and push their tokens in
+        that order: a first token never waits for step k.  A slot a
+        stream gives up here is prefilled, by a later iteration, behind
         step k: the device keeps the order they were sent in."""
         eng = self.engine
         waiting = eng.in_flight
@@ -876,23 +908,25 @@ class TensorLLM(Element):
         if pick:
             eng.dispatch(pick)
             self._sent_ns = now
-        if not waiting:
-            return
-        lanes = eng.collect()
-        t1 = self._mono_ns()
-        self._ctr_sync()
-        tracer = self._tracer()
-        if tracer is not None:
-            # the SHARED decode window (dispatch to collect), once per
-            # resident trace — the cross-stream device-invoke convention
-            # (per-token wall-clock truth, not a 1/n share)
-            for sess, _ in lanes:
-                ctx = sess.extra.get("nns_trace")
-                if ctx is not None and ctx.trace_id:
-                    tracer.annotate_span("llm-decode", t0, t1, seq=-1,
-                                         trace_id=ctx.trace_id)
-        for sess, tok in lanes:
-            self._finish_or_emit(sess, tok)
+        for _ in range(waiting):
+            stepped = not eng.prefill_next
+            lanes = eng.collect()
+            t1 = self._mono_ns()
+            self._ctr_sync()
+            tracer = self._tracer()
+            if tracer is not None and stepped:
+                # the SHARED decode window (dispatch to collect), once
+                # per resident trace — the cross-stream device-invoke
+                # convention (per-token wall-clock truth, not a 1/n
+                # share)
+                for sess, _ in lanes:
+                    ctx = sess.extra.get("nns_trace")
+                    if ctx is not None and ctx.trace_id:
+                        tracer.annotate_span("llm-decode", t0, t1,
+                                             seq=-1,
+                                             trace_id=ctx.trace_id)
+            for sess, tok in lanes:
+                self._finish_or_emit(sess, tok)
 
     def _finish_or_emit(self, sess, tok: int) -> None:
         """Emit one token frame for ``sess``; release its slot when the

@@ -29,7 +29,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -179,13 +179,16 @@ class PhaseClock:
         if self._span is not None:
             self._span.set_metadata(**kw)
 
-    def child(self, part: str):
+    def child(self, part: str, state: Optional[str] = None, **kw):
         """``llm.<state>.<part>``: an annotation to nest, with ``with``,
-        inside the open phase (nothing on a clock that does not
-        annotate)."""
+        inside the open phase — of the open phase's name, or of
+        ``state``'s where the part is another phase's work done inside
+        this one (a prefill's token read by a collect); ``kw`` are its
+        arguments.  Nothing on a clock that does not annotate."""
         if self._annotation is None:
             return contextlib.nullcontext()
-        return self._annotation(f"{SPANS[self._state]}.{part}")
+        return self._annotation(
+            f"{SPANS[state or self._state]}.{part}", **kw)
 
     def totals_ns(self) -> Dict[str, int]:
         """Integer per-state totals INCLUDING the in-progress state's
@@ -215,6 +218,18 @@ class PhaseClock:
                            for p, v in ns.items()},
             "conserved_pct": round(100.0 * attributed / total, 3),
         }
+
+
+class _Flight(NamedTuple):
+    """Work sent to the device whose tokens the host has not read."""
+    out: Any                    # the tokens, still on the device
+    sessions: List[Session]     # a step's lanes; a prefill's one session
+    t0: float                   # when it was sent (the engine's clock)
+    #: a dense prefill's: the number of the step dispatched after it,
+    #: whose wait its token must not follow; ``None`` for a step
+    ahead_of: Optional[int] = None
+    #: a dense prefill's arguments for the span of its token's read
+    trace: Optional[Dict[str, Any]] = None
 
 
 def quantize_prompt(t: int, max_seq: int) -> int:
@@ -272,7 +287,10 @@ class DecodeEngine:
     so a step needs nothing of the step before from the host:
     :meth:`dispatch` sends step k without waiting for it and
     :meth:`collect` reads the ``B`` int32 of the oldest step in flight.
-    :meth:`step` is the two in a row.
+    :meth:`step` is the two in a row.  The dense whole-prompt prefill
+    joins the same pipeline (:meth:`dispatch_prefill`): sent without
+    waiting, its first token read by :meth:`collect` in the order the
+    work was sent, while the next step may already consume it.
     """
 
     def __init__(self, params, cfg, pool: KVCachePool,
@@ -302,10 +320,13 @@ class DecodeEngine:
         self._device = device
         self._sampled = jax.device_put(
             np.zeros((pool.slots + 1,), np.int32), device)
-        #: steps dispatched and not yet collected, oldest first:
-        #: (device tokens, sessions, when it was dispatched).  One, and
-        #: a second between a dispatch and the collect that follows it
-        self._flights: collections.deque = collections.deque(maxlen=2)
+        #: work sent and not yet collected, in the order it was sent
+        #: (:class:`_Flight`): at most two steps — one, and a second
+        #: between a dispatch and the collect that follows it — and the
+        #: dense prefills sent between them, one a slot at most: a
+        #: prefill holds its session's slot until it is collected
+        # nnslint: allow(unbounded-queue)
+        self._flights: collections.deque = collections.deque()
         self._collected_s = 0.0
         self.cfg = cfg
         self.pool = pool
@@ -336,6 +357,11 @@ class DecodeEngine:
         self.steps_total = 0
         self.prefills_total = 0
         self.prefill_chunks_total = 0
+        #: dense prefills sent without waiting for their token, and of
+        #: those the ones sent while other work was in flight on the
+        #: device (the overlap the pipeline buys)
+        self.prefills_async = 0
+        self.prefills_behind = 0
         #: steps dispatched while the step before was uncollected, and
         #: lanes stepped after their stream had ended (a stop token or an
         #: eviction the host learnt of a step late): their output is
@@ -647,14 +673,13 @@ class DecodeEngine:
         prompt position's logits — :func:`generate`'s semantics), which
         the executable also left in the session's row of ``_sampled``
         for its first step.  Synchronous: it waits for that one int32,
-        behind whatever step is in flight.
+        behind whatever is in flight (:meth:`dispatch_prefill` does
+        not).
 
         ``prefill_mode="step"`` decodes the prompt token-by-token
         through the pooled step instead (the decode-without-prefill
         path the verifier warns about: correct, but T GEMV steps and no
         flash win)."""
-        import jax.numpy as jnp
-
         prev = self.phases.enter("prefill")
         try:
             if self.prefill_mode == "step":
@@ -666,32 +691,73 @@ class DecodeEngine:
                     first = self._advance_chunk(sess)
                     if first is not None:
                         return first
-            t = int(prompt.shape[0])
             if self.chunk_len > 0:
                 first = self._prefill_chunks(sess, prompt)
             else:
-                padded = quantize_prompt(t, self.cfg.max_seq)
-                self.phases.note(padded=padded)
-                buf = np.zeros((padded,), np.int32)
-                buf[:t] = prompt
-                fn = self._prefill_fn(padded)
-                cold = self._enter_cold()
-                try:
-                    with self.phases.child("dispatch"):
-                        out, self._sampled, self.pool.arrays = fn(
-                            self.params, self.pool.arrays, self._sampled,
-                            jnp.asarray(buf), jnp.int32(sess.slot),
-                            jnp.int32(t))
-                    with self.phases.child("wait"):
-                        first = int(out)
-                finally:
-                    if cold is not None:
-                        self.phases.enter(cold)
-            sess.pos = t
+                out = self._send_prefill(sess, prompt)
+                with self.phases.child("wait"):
+                    first = int(out)
+            sess.pos = int(prompt.shape[0])
             self._prefilled(sess)
             return first
         finally:
             self.phases.enter(prev)
+
+    def dispatch_prefill(self, sess: Session, prompt: np.ndarray,
+                         **trace) -> Optional[int]:
+        """:meth:`prefill` without the wait where the engine can: the
+        dense whole-prompt prefill (no ``chunk_len``, not paged, not
+        ``prefill_mode="step"``) is sent and left in flight, and
+        ``None`` comes back.  Its first token is already in the
+        session's row of ``_sampled``, so the session may ride the next
+        step (``pos`` is the prompt's length, the token counted in its
+        ``in_flight``); :meth:`collect` reads the token in the order
+        the work was sent, so before the wait on any step dispatched
+        after it.  ``trace`` are arguments for the span of that read
+        (the request's keys).  Every other path waits, as
+        :meth:`prefill` does, and returns the token."""
+        if self.prefill_mode == "step" or self.paged or self.chunk_len:
+            return self.prefill(sess, prompt)
+        behind = bool(self._flights)
+        prev = self.phases.enter("prefill")
+        try:
+            out = self._send_prefill(sess, prompt)
+        finally:
+            self.phases.enter(prev)
+        sess.pos = int(prompt.shape[0])
+        sess.in_flight += 1
+        sess.last_step_s = self._clock()
+        self.prefills_total += 1
+        self.prefills_async += 1
+        self.prefills_behind += behind
+        self._flights.append(_Flight(
+            out, [sess], sess.last_step_s,
+            ahead_of=self.steps_total + self._steps_out(), trace=trace))
+        return None
+
+    def _send_prefill(self, sess: Session, prompt: np.ndarray):
+        """Send the dense prefill: the prompt padded to its power of
+        two, installed into ``sess``'s slot, its first token written to
+        the session's row of ``_sampled``.  Returns that token, still
+        on the device."""
+        import jax.numpy as jnp
+
+        t = int(prompt.shape[0])
+        padded = quantize_prompt(t, self.cfg.max_seq)
+        self.phases.note(padded=padded)
+        buf = np.zeros((padded,), np.int32)
+        buf[:t] = prompt
+        fn = self._prefill_fn(padded)
+        cold = self._enter_cold()
+        try:
+            with self.phases.child("dispatch"):
+                out, self._sampled, self.pool.arrays = fn(
+                    self.params, self.pool.arrays, self._sampled,
+                    jnp.asarray(buf), jnp.int32(sess.slot), jnp.int32(t))
+        finally:
+            if cold is not None:
+                self.phases.enter(cold)
+        return out
 
     def _prefilled(self, sess: Session) -> None:
         self.prefills_total += 1
@@ -862,8 +928,18 @@ class DecodeEngine:
 
     @property
     def in_flight(self) -> int:
-        """Steps dispatched and not yet collected."""
+        """Steps and dense prefills sent and not yet collected."""
         return len(self._flights)
+
+    @property
+    def prefill_next(self) -> bool:
+        """The oldest work in flight is a dense prefill: the next
+        :meth:`collect` reads its session's first token."""
+        return bool(self._flights) and \
+            self._flights[0].ahead_of is not None
+
+    def _steps_out(self) -> int:
+        return sum(1 for f in self._flights if f.ahead_of is None)
 
     def dispatch(self, sessions: Sequence[Session]) -> None:
         """Send one continuous-batching decode step over ``sessions`` (≤
@@ -871,11 +947,11 @@ class DecodeEngine:
         waiting for it: each lane consumes the token the chip sampled
         for its stream last and advances its cache position.  The
         step's tokens are :meth:`collect`'s to read."""
-        if len(self._flights) > 1:
+        ahead = self._steps_out()
+        if ahead > 1:
             raise RuntimeError("two steps in flight: collect() the "
                                "older before a third is dispatched")
         t0 = self._clock()
-        ahead = len(self._flights)
         attended = sum(s.pos for s in sessions) + len(sessions)
         prev = self.phases.enter("decode", step=self.steps_total + ahead,
                                  lanes=len(sessions), ahead=ahead,
@@ -890,15 +966,21 @@ class DecodeEngine:
         self.steps_ahead += ahead
         self.attended_positions += attended
         self.reserved_positions += len(sessions) * self.cfg.max_seq
-        self._flights.append((out, list(sessions), t0))
+        self._flights.append(_Flight(out, list(sessions), t0))
 
     def collect(self) -> List[Tuple[Session, int]]:
-        """Wait for the oldest step in flight, read its ``B`` int32 and
-        return its lanes, each with the NEXT token of its stream (the
-        caller emits it and decides stop-token/max-new completion).  A
-        lane whose session the pool took back while the step ran is
-        left out and counted in ``lanes_discarded``."""
-        out, sessions, t0 = self._flights.popleft()
+        """Wait for the oldest work in flight and read its tokens.  A
+        step's ``B`` int32: its lanes, each with the NEXT token of its
+        stream (the caller emits it and decides stop-token/max-new
+        completion); a lane whose session the pool took back while the
+        step ran is left out and counted in ``lanes_discarded``.  A
+        dense prefill's one int32: its session with its FIRST token
+        (:meth:`prefill_next`), or nothing where the pool took the
+        session back."""
+        flight = self._flights.popleft()
+        if flight.ahead_of is not None:
+            return self._collect_prefill(flight)
+        out, sessions, t0 = flight.out, flight.sessions, flight.t0
         prev = self.phases.enter("decode")
         with self.phases.child("wait"):
             tokens = np.asarray(out).tolist()
@@ -924,6 +1006,27 @@ class DecodeEngine:
         self.phases.enter(prev)
         return lanes
 
+    def _collect_prefill(self, flight: _Flight) -> List[Tuple[Session,
+                                                                int]]:
+        """Read a dense prefill's first token: a piece of ``decode``
+        (the thread waits on the device, as for a step) holding
+        ``llm.prefill.wait`` with the number of the step sent after it
+        and the request's keys."""
+        (sess,) = flight.sessions
+        trace = flight.trace or {}
+        prev = self.phases.enter("decode", **trace)
+        try:
+            with self.phases.child("wait", state="prefill",
+                                   step=flight.ahead_of, **trace):
+                first = int(flight.out)
+        finally:
+            self.phases.enter(prev)
+        sess.in_flight -= 1
+        if sess.released:
+            return []
+        self.tokens_total += 1
+        return [(sess, first)]
+
     def step(self, sessions: Sequence[Session]) -> List[int]:
         """:meth:`dispatch` then :meth:`collect`: one synchronous step,
         the next token per session — for callers that run no step
@@ -931,7 +1034,7 @@ class DecodeEngine:
         if not sessions:
             return []
         if self._flights:
-            raise RuntimeError("step() with a step in flight: collect() "
+            raise RuntimeError("step() with work in flight: collect() "
                                "it first")
         self.dispatch(sessions)
         return [tok for _, tok in self.collect()]
@@ -959,6 +1062,8 @@ class DecodeEngine:
             "attended_positions": self.attended_positions,
             "reserved_positions": self.reserved_positions,
             "prefills": self.prefills_total,
+            "prefills_async": self.prefills_async,
+            "prefills_behind": self.prefills_behind,
             "mean_fill": round(self.step_tokens
                                / max(1, self.steps_total), 2),
             "ewma_step_ms": round(self.ewma_step_s * 1e3, 3),

@@ -15,6 +15,7 @@ by construction.
 
 import gc
 import os
+import re
 import threading
 import time
 
@@ -1625,6 +1626,295 @@ class TestOneStepInFlight:
         assert sess is a and a.in_flight == 0 and eng.in_flight == 0
         assert eng.steps_total == 2 and eng.steps_ahead == 1
         assert eng.step_tokens == 2
+
+
+#: prompts up to 760 tokens (``steady_short``'s longest) need a cache of
+#: 1 024 positions and a request frame that holds them
+CUSTOM_WIDE = ("vocab:61,dim:32,heads:4,head_dim:8,mlp:64,layers:2,"
+               "max_seq:1024,dtype:float32")
+FRAME_WIDE = 3 + 760
+
+
+def _play_wide(props):
+    """The standalone element over ``CUSTOM_WIDE``; its streams by tag
+    as ``(pts, token, more)`` lists."""
+    p = parse_launch(
+        "appsrc name=src caps=other/tensors,format=static,num_tensors=1,"
+        f"dimensions={FRAME_WIDE},types=int32,framerate=0/1 ! "
+        f"tensor_llm name=llm custom={CUSTOM_WIDE} seed=0 {props} ! "
+        "tensor_sink name=out")
+    got = {}
+    p.get("out").connect("new-data", lambda b: got.setdefault(
+        b.extra["tag"], []).append(
+            (b.pts, int(np.asarray(b.tensors[0]).reshape(-1)[0]),
+             b.extra)))
+    p.play()
+    return p, got
+
+
+def _push(p, tag, prompt, max_new, stop=-1, qos="gold"):
+    """One request, of the class the benchmark's clients declare: gold
+    meets no occupancy watermark, only the hard no-slot boundary."""
+    buf = TensorBuffer(tensors=[encode_request(
+        prompt, max_new=max_new, stop_token=stop, frame_len=FRAME_WIDE)])
+    buf.extra["tag"] = tag
+    buf.extra["nns_class"] = qos
+    p.get("src").push_buffer(buf)
+
+
+class TestPrefillInFlight:
+    """The dense whole-prompt prefill is sent like a step: the element
+    does not wait for its token, reads it in the order the work was
+    sent, and the stream may ride the very next step."""
+
+    @pytest.fixture(scope="class")
+    def mix(self):
+        """Two long resident streams, then twelve short requests with
+        prompts of 8-760 tokens and answers of 1-4: one whose first
+        token is its stop token, one that stops mid-answer.  Each with
+        the stream the synchronous ``prefill`` then ``step`` serves."""
+        import llm_ahead
+
+        family, cfg, params = llm_ahead.world(CUSTOM_WIDE, 0)
+        eng = DecodeEngine(params, cfg,
+                           KVCachePool(cfg, 1, family=family), capacity=1)
+        rng = np.random.default_rng(11)
+        shapes = [(20, 160), (300, 120),                  # residents
+                  (8, 1), (760, 4), (9, 2), (200, 4), (511, 1), (16, 4),
+                  (63, 2), (640, 3), (100, 1), (33, 4), (700, 4),
+                  (17, 4)]
+        out = []
+        for tag, (plen, max_new) in enumerate(shapes):
+            while True:
+                prompt = rng.integers(0, cfg.vocab, plen).astype(np.int32)
+                want = llm_ahead.sync_stream(eng, prompt, max_new)
+                # [13] stops on its third token, seen there first
+                if tag != 13 or want[2] not in want[:2]:
+                    break
+            stop = -1
+            if tag == 5:
+                stop, want = want[0], want[:1]   # the first token stops
+            elif tag == 13:
+                stop, want = want[2], want[:3]
+            out.append((prompt, max_new, stop, want))
+        # the synchronous path is generate()'s, the longest prompts too
+        for tag in (1, 3, 12):
+            prompt, max_new, stop, want = out[tag]
+            assert generate(params, cfg, prompt,
+                            max_new).tolist() == want
+        return out
+
+    def _serve(self, mix):
+        """The residents first; the short requests at once, while the
+        residents stream.  Returns the streams, the element's report and
+        the decode thread's log of sends, step waits and emits."""
+        p, got = _play_wide("slots=6 batch=6 max-new-tokens=256 "
+                            "admit-timeout-ms=60000")
+        try:
+            llm = p.get("llm")
+            eng = llm.engine
+            log = []
+
+            def number():          # the step the next dispatch sends
+                return eng.steps_total + eng._steps_out()
+
+            def wrap(obj, name, note):
+                real = getattr(obj, name)
+
+                def call(*a, **kw):
+                    log.append(note(*a, **kw))
+                    return real(*a, **kw)
+                setattr(obj, name, call)
+
+            wrap(eng, "dispatch", lambda sessions: (
+                "dispatch", number(), [s.extra["tag"] for s in sessions]))
+            wrap(eng, "dispatch_prefill", lambda sess, prompt, **_: (
+                "prefill", sess.extra["tag"], number()))
+            wrap(eng, "collect", lambda: (
+                ("read", None) if eng.prefill_next
+                else ("wait", eng.steps_total)))
+            wrap(llm, "_emit", lambda extra, tok, index, last: (
+                "emit", extra["tag"], index))
+            for tag in (0, 1):
+                _push(p, tag, *mix[tag][:3])
+            assert wait_until(lambda: len(got.get(0, ())) >= 3
+                              and len(got.get(1, ())) >= 3, timeout=120)
+            for tag in range(2, len(mix)):
+                _push(p, tag, *mix[tag][:3])
+            p.get("src").end_of_stream()
+            p.wait(timeout=240)
+            return got, llm.report(), log
+        finally:
+            p.stop()
+
+    @pytest.fixture(scope="class")
+    def served(self, mix):
+        return self._serve(mix)
+
+    def test_streams_are_the_synchronous_paths(self, mix, served):
+        got, report, _ = served
+        for tag, (_, _, _, want) in enumerate(mix):
+            frames = got[tag]
+            assert [t for _, t, _ in frames] == want, tag
+            assert [q for q, _, _ in frames] == list(range(len(want)))
+            assert [bool(e.get("nns_more")) for _, _, e in frames] == [
+                True] * (len(want) - 1) + [False]
+        assert report["tokens"] == sum(len(m[3]) for m in mix)
+        # the stream whose first token stops it, and the one that stops
+        # mid-answer, each rode one step whose token was dropped
+        assert report["lanes_discarded"] == 2
+        assert report["refused"]["no_slot"] == 0
+
+    def test_a_one_token_answer_never_rides_a_step(self, mix, served):
+        _, _, log = served
+        once = {tag for tag, m in enumerate(mix) if m[1] == 1}
+        assert len(once) == 3
+        rode = {tag for e in log if e[0] == "dispatch" for tag in e[2]}
+        assert not rode & once
+        # ... and every other short answer rode one
+        assert set(range(2, len(mix))) - once <= rode
+
+    def test_a_first_token_is_emitted_before_the_next_steps_wait(
+            self, mix, served):
+        _, _, log = served
+        sent = [(i, e[1], e[2]) for i, e in enumerate(log)
+                if e[0] == "prefill"]
+        assert len(sent) == len(mix)
+        for i, tag, step in sent:
+            first = log.index(("emit", tag, 0))
+            assert first > i
+            if ("wait", step) in log:
+                assert first < log.index(("wait", step)), tag
+        # the short requests' tokens were read behind the residents'
+        # steps: a wait on step k-1, then a prefill's read, both before
+        # the next dispatch
+        kinds = "".join({"dispatch": "d", "wait": "w", "read": "r"}.get(
+            e[0], "") for e in log)
+        assert re.search("w[^d]*r", kinds)
+
+    def test_the_counters_count_what_they_say(self, mix, served):
+        _, report, log = served
+        assert report["prefills"] == report["prefills_async"] == len(mix)
+        # every short prompt was sent while a resident's step was in
+        # flight; the first resident's, at least, into an idle device
+        assert len(mix) - 2 <= report["prefills_behind"] < len(mix)
+        assert report["admit_deferred"] >= 0
+
+    def test_the_engine_counts_and_reads_in_send_order(self):
+        import llm_ahead
+
+        family, cfg, params = llm_ahead.world(CUSTOM_WIDE, 0)
+        prompt = np.asarray([3, 1, 4, 1, 5], np.int32)
+        alone = DecodeEngine(params, cfg,
+                             KVCachePool(cfg, 1, family=family),
+                             capacity=1)
+        want = llm_ahead.sync_stream(alone, prompt, 3)
+        assert alone.report()["prefills_async"] == 0     # prefill() waits
+        pool = KVCachePool(cfg, 3, family=family)
+        eng = DecodeEngine(params, cfg, pool, capacity=3)
+        a, b, c = (pool.acquire(k) for k in "abc")
+        assert eng.dispatch_prefill(a, prompt) is None   # an idle device
+        assert (a.pos, a.in_flight, eng.in_flight) == (5, 1, 1)
+        assert eng.dispatch_prefill(b, prompt) is None   # behind a's
+        eng.dispatch([a, b])       # on the first tokens, still on chip
+        assert eng.prefill_next
+        assert eng.collect() == [(a, want[0])]
+        assert eng.collect() == [(b, want[0])]
+        assert not eng.prefill_next
+        assert eng.collect() == [(a, want[1]), (b, want[1])]
+        assert eng.dispatch_prefill(c, prompt) is None   # idle again
+        pool.release("c")          # gone before its token was read
+        assert eng.collect() == [] and c.in_flight == 0
+        report = eng.report()
+        assert (report["prefills"], report["prefills_async"],
+                report["prefills_behind"]) == (3, 3, 1)
+        assert report["tokens"] == 4 and report["steps"] == 1
+
+    def test_other_prefill_paths_still_wait(self):
+        """The paged arena's prefill is not sent ahead: the engine
+        chooses from its own pool, and waits as ``prefill`` does."""
+        cfg = _cfg()
+        pool = PagedKVCachePool(cfg, pages=24, page_size=4, slots=2)
+        eng = DecodeEngine(init_params(cfg, 1), cfg, pool, capacity=2)
+        prompt = np.asarray([3, 1, 4, 1, 5], np.int32)
+        sess = pool.acquire("a", prompt=prompt, max_new=4)
+        first = eng.dispatch_prefill(sess, prompt)
+        assert first == generate(eng.params, cfg, prompt, 1).tolist()[0]
+        assert eng.in_flight == 0 and sess.in_flight == 0
+        assert eng.report()["prefills_async"] == 0
+
+
+class TestAdmissionPass:
+    """An admission pass hands out only the slots that were free when
+    it began; a pass that began with none sheds ``no_slot`` as before."""
+
+    def test_a_stalled_backlog_waits_for_slots_and_is_served(self):
+        """2 x slots requests of 1-4 tokens land in the pending list at
+        once, as behind a stall of the decode thread: the first pass
+        admits four and leaves the rest; they are admitted as steps end
+        streams, none shed (all of them at ``admit-timeout-ms=0``)."""
+        import llm_ahead
+
+        family, cfg, params = llm_ahead.world(CUSTOM_WIDE, 0)
+        alone = DecodeEngine(params, cfg,
+                             KVCachePool(cfg, 1, family=family),
+                             capacity=1)
+        rng = np.random.default_rng(5)
+        news = [1, 2, 3, 4, 1, 2, 3, 4]
+        reqs = []
+        for max_new in news:
+            prompt = rng.integers(0, cfg.vocab, 40).astype(np.int32)
+            reqs.append((prompt, max_new,
+                         llm_ahead.sync_stream(alone, prompt, max_new)))
+        p, got = _play_wide("slots=4 batch=4")
+        try:
+            llm = p.get("llm")
+            real = llm._prune_sessions
+            entered, gate = threading.Event(), threading.Event()
+
+            def stalled():
+                entered.set()
+                gate.wait(60)
+                real()
+            llm._prune_sessions = stalled    # the loop stops after taking
+            assert entered.wait(30)
+            for tag, (prompt, max_new, _) in enumerate(reqs):
+                _push(p, tag, prompt, max_new)
+            assert wait_until(lambda: len(llm._pending) == len(reqs))
+            llm._prune_sessions = real
+            gate.set()
+            p.get("src").end_of_stream()
+            p.wait(timeout=180)
+            report = llm.report()
+        finally:
+            p.stop()
+        for tag, (_, _, want) in enumerate(reqs):
+            assert [t for _, t, _ in got[tag]] == want, tag
+        assert report["refused"]["no_slot"] == 0 and llm.shed_total == 0
+        assert report["stall"]["pending_peak"] == len(reqs)
+        assert report["admit_deferred"] > 0
+
+    def test_a_full_pool_of_live_streams_sheds_a_newcomer_at_once(self):
+        """Every slot held by a long stream: the newcomer meets a pass
+        that began with no free slot and is shed there, with a
+        retry-after hint, while the streams run on."""
+        p, got = _play_wide("slots=2 batch=2 max-new-tokens=900")
+        try:
+            llm = p.get("llm")
+            for tag in (0, 1):
+                _push(p, tag, np.asarray([1, 2, 3], np.int32), 900)
+            assert wait_until(lambda: len(got.get(0, ())) >= 3
+                              and len(got.get(1, ())) >= 3, timeout=60)
+            _push(p, 2, np.asarray([4], np.int32), 4)
+            assert wait_until(lambda: 2 in got, timeout=30)
+            streamed = min(len(got[0]), len(got[1]))
+            (_, _, extra), = got[2]
+            assert extra["nns_llm_shed"] > 0           # retry-after
+            assert streamed < 900                      # at once
+            assert llm.refused["no_slot"] == 1
+            assert llm.admit_deferred == 0
+        finally:
+            p.stop()
 
 
 # ---------------------------------------------------------------------------

@@ -199,7 +199,10 @@ def test_children_lie_inside_a_span_of_their_parents_name(round_trip):
         "llm.decode.operands", "llm.decode.dispatch", "llm.decode.wait",
         "llm.decode.sample", "llm.prefill.dispatch", "llm.prefill.wait"}
     for name, lo, hi, _ in children:
-        parent = name.rsplit(".", 1)[0]
+        # a dense prefill is sent without waiting: its token is read by
+        # a collect, so its wait lies in a piece of ``llm.decode``
+        parent = ("llm.decode" if name == "llm.prefill.wait"
+                  else name.rsplit(".", 1)[0])
         assert any(t[0] == parent and t[1] <= lo and hi <= t[2]
                    for t in top), name
     for part in ("operands", "dispatch", "wait", "sample"):
@@ -207,6 +210,16 @@ def test_children_lie_inside_a_span_of_their_parents_name(round_trip):
                    if s[0] == f"llm.decode.{part}") == counts["steps"]
     assert sum(1 for s in children
                if s[0] == "llm.prefill.dispatch") == counts["prefills"]
+    # ... each read before the wait on the step sent after it, which it
+    # names, with the request's keys (none without a wire)
+    reads = [s for s in children if s[0] == "llm.prefill.wait"]
+    assert len(reads) == counts["prefills"]
+    steps = [s[3]["step"] for s in spans
+             if s[0] == "llm.decode" and "step" in s[3]]
+    waits = [s for s in children if s[0] == "llm.decode.wait"]
+    for _, _, hi, stats in reads:
+        assert (stats["cid"], stats["seq"]) == (-1, -1)
+        assert hi <= waits[stats["step"] - steps[0]][1]
 
 
 def test_first_piece_of_an_admit_carries_the_wait_and_the_verdict(
