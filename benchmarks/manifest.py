@@ -30,6 +30,8 @@ _LAYER = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 _WIDTH = re.compile(r"(hidden|intermediate|latent|state|proj|_dim$|_rank$"
                     r"|head_size|head_dim|expansion|experts_per_tok"
                     r"|^dim$|^mlp$|^width$)")
+#: ... unless it counts layers: ``num_hidden_layers`` is a depth
+_DEPTH = re.compile(r"(^|_)layers$")
 
 
 class ManifestError(ValueError):
@@ -91,7 +93,7 @@ def validate(doc: Dict[str, Any]) -> None:
         files.add(c["file"])
         _need(isinstance(c["reduced"], list), "reduced: a list")
         for key in c["reduced"]:
-            _need(not _WIDTH.search(key),
+            _need(bool(_DEPTH.search(key)) or not _WIDTH.search(key),
                   f"reduced names a width: {key!r}")
         _need(len(c["why"]) <= 200, "why: at most 200 characters")
 

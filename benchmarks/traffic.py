@@ -14,9 +14,11 @@ A later PR adds a mix by adding a data file.  What a file may say:
 ``prompt_len`` / ``max_new``
                a length distribution: ``fixed`` (``value``),
                ``uniform_int`` (``min``, ``max``) or ``lognormal``
-               (``median``, ``sigma``, clipped to ``min``..``max``;
-               ``stratified`` draws the same quantile grid for every
-               seed and lets the seed permute it)
+               (``median``, ``sigma``, clipped to ``min``..``max``).
+               ``stratified`` (either of the last two) draws the same
+               quantile grid for every seed and lets the seed permute
+               it; in a closed loop the grid spans the clients, and
+               each round of requests, one a client, draws it again
 ``sharing``    ``prefix_len`` tokens shared by the requests of one of
                ``groups`` groups (absent = nothing shared)
 ``cameras`` / ``fps``
@@ -39,7 +41,7 @@ import numpy as np
 #: sub-streams of one seed, so arrivals, lengths and tokens stay
 #: independent of each other and of how many of each are drawn
 _ARRIVALS, _PROMPT_LEN, _MAX_NEW, _TOKENS, _PREFIX, _GROUP, _PHASE, \
-    _FRAMES = range(8)
+    _FRAMES, _ROUND = range(9)
 
 
 def rng_for(seed: int, *stream: int) -> np.random.Generator:
@@ -109,6 +111,10 @@ def draw_lengths(spec: Dict[str, Any], n: int,
     if lo > hi:
         raise ValueError(f"length range {lo}..{hi} is empty")
     if dist == "uniform_int":
+        if spec.get("stratified", False):
+            # the midpoints of n equal shares of lo..hi, in seeded order
+            grid = lo + np.floor((np.arange(n) + 0.5) * (hi - lo + 1) / n)
+            return [int(v) for v in rng.permutation(grid)]
         return [int(v) for v in rng.integers(lo, hi + 1, n)]
     if dist == "lognormal":
         median, sigma = float(spec["median"]), float(spec["sigma"])
@@ -164,12 +170,19 @@ def closed_token_request(traffic: Dict[str, Any], seed: int, client: int,
                          k: int) -> Dict[str, Any]:
     """The ``k``-th request of closed-loop ``client``.  Request ids are
     ``client + k * clients``: unique, and derivable on either side."""
-    rid = client + k * int(traffic["clients"])
-    plen = draw_lengths(traffic["prompt_len"], 1,
-                        rng_for(seed, _PROMPT_LEN, rid))[0]
-    new = draw_lengths(traffic["max_new"], 1,
-                       rng_for(seed, _MAX_NEW, rid))[0]
-    return _token_request(traffic, seed, rid, plen, new)
+    clients = int(traffic["clients"])
+    rid = client + k * clients
+
+    def length(spec: Dict[str, Any], stream: int) -> int:
+        if spec.get("stratified", False):
+            # round k's grid over the clients, the same for every seed
+            return draw_lengths(spec, clients,
+                                rng_for(seed, _ROUND, stream, k))[client]
+        return draw_lengths(spec, 1, rng_for(seed, stream, rid))[0]
+
+    return _token_request(traffic, seed, rid,
+                          length(traffic["prompt_len"], _PROMPT_LEN),
+                          length(traffic["max_new"], _MAX_NEW))
 
 
 def prompt_tokens(req: Dict[str, Any], vocab: int) -> np.ndarray:

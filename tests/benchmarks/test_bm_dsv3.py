@@ -67,22 +67,20 @@ def manifest():
 
 # -- the configuration and its cell --------------------------------------
 def test_benchmark_json_validates_with_the_new_cell(manifest):
-    assert [w["name"] for w in manifest.doc["workloads"]][-1] == CELL
-    assert manifest.doc["configs"][-1]["name"] == CONFIG
+    # by name, wherever they stand: a later cell is appended, not pinned
+    # out (the full check's budget is test_bm_manifest.py's, at 24 cells)
+    assert manifest.cell(CELL)["config"] == CONFIG
+    assert CONFIG in {c["name"] for c in manifest.doc["configs"]}
     assert manifest.doc["run_seconds"] == 50
-    # four cells, none on four chips, the full check inside its limit
-    cells = len(manifest.doc["workloads"])
-    assert cells == 4 and all(w["chips"] == 1
-                              for w in manifest.doc["workloads"])
-    assert (2 + 14 * cells) * (50 + 60) + 2 * 90 * cells + 1200 < 43200
+    assert manifest.cell(CELL)["chips"] == 1
 
 
 def test_configuration_keeps_every_published_width(manifest):
     cfg = manifest.config(CONFIG)
     entry = next(c for c in manifest.doc["configs"] if c["name"] == CONFIG)
     # the file names the cut by the source's keys, letter for letter;
-    # BENCHMARK.json spells the depth ``layers`` (manifest.py's _WIDTH
-    # matches 'hidden'), which the file also carries
+    # BENCHMARK.json spells the depth ``layers``, kept from before
+    # manifest.py's _WIDTH let a key that counts layers stand in reduced
     assert cfg["reduced"] == list(CUT)
     assert entry["reduced"] == ["layers"] + list(CUT)[1:]
     assert "_WIDTH" in cfg["reduced_why"]
@@ -189,7 +187,8 @@ def test_cell_reports_the_family_blind_metrics_and_its_own(manifest):
                  "moe_ms_per_step"):
         entry = next(m for m in manifest.doc["per_layer"]
                      if m["name"] == name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "tok_s"
+        # family-blind: another family's cell may report it too
+        assert CELL in entry["workloads"] and entry["moves"] == "tok_s"
         assert entry["layer"] == "model_math"
         assert callable(manifest.module("layer_metrics", name).read)
     # the accepted cells report what they reported

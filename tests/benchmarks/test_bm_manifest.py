@@ -103,44 +103,75 @@ def _bad(change):
     return doc
 
 
+def _the(doc, kind, name):
+    """The entry of ``doc[kind]`` called ``name``: by name, so an entry
+    appended later moves no breach onto another entry."""
+    return next(e for e in doc[kind] if e["name"] == name)
+
+
+CELL, CONFIG = "gpt2m.decode_saturate", "sflm_gpt2m"
 BREACHES = {
     "extra key": lambda d: d.update(notes="x"),
-    "bound over a tenth": lambda d: d["end_to_end"][0].update(bound=0.2),
+    "bound over a tenth":
+        lambda d: _the(d, "end_to_end", "tok_s").update(bound=0.2),
     "bound under a hundredth":
-        lambda d: d["end_to_end"][0].update(bound=0.001),
+        lambda d: _the(d, "end_to_end", "tok_s").update(bound=0.001),
     "run_seconds too long": lambda d: d.update(run_seconds=52),
     "reduced names a width":
-        lambda d: d["configs"][0].update(reduced=["head_dim"]),
+        lambda d: _the(d, "configs", CONFIG).update(reduced=["head_dim"]),
     "reduced names the hidden size":
-        lambda d: d["configs"][0].update(reduced=["hidden_size"]),
-    "unknown moves": lambda d: d["per_layer"][1].update(moves="fps"),
+        lambda d: _the(d, "configs", CONFIG).update(
+            reduced=["hidden_size"]),
+    "reduced names the intermediate size":
+        lambda d: _the(d, "configs", CONFIG).update(
+            reduced=["intermediate_size"]),
+    "reduced names a width beside the depth":
+        lambda d: _the(d, "configs", CONFIG).update(
+            reduced=["num_hidden_layers", "kv_lora_rank"]),
+    "unknown moves": lambda d: _the(
+        d, "per_layer", "decode_step_ms").update(moves="fps"),
     "layer metric in a cell the manifest has not":
-        lambda d: d["per_layer"][1].update(workloads=["gpt2m.nowhere"]),
+        lambda d: _the(d, "per_layer", "decode_step_ms").update(
+            workloads=["gpt2m.nowhere"]),
     "layer metric where its end-to-end metric is not":
-        lambda d: d["per_layer"][1].update(
+        lambda d: _the(d, "per_layer", "decode_step_ms").update(
             workloads=["gpt2m.steady_short"]),
-    "one cell": lambda d: d.update(workloads=d["workloads"][:1]),
-    "no setup_s": lambda d: d["end_to_end"].pop(),
+    "one cell": lambda d: d.update(workloads=[_the(d, "workloads", CELL)]),
+    "no setup_s": lambda d: d["end_to_end"].remove(
+        _the(d, "end_to_end", "setup_s")),
     "pair twice": lambda d: d["workloads"].append(
-        dict(d["workloads"][0], name="again")),
-    "name not plain": lambda d: d["workloads"][0].update(name="a b"),
-    "why too long": lambda d: d["workloads"][0].update(why="y" * 201),
+        dict(_the(d, "workloads", CELL), name="again")),
+    "name not plain":
+        lambda d: _the(d, "workloads", CELL).update(name="a b"),
+    "why too long":
+        lambda d: _the(d, "workloads", CELL).update(why="y" * 201),
     "config file outside paths":
-        lambda d: d["configs"][0].update(file="bench.py"),
+        lambda d: _the(d, "configs", CONFIG).update(file="bench.py"),
     "command leaves the repo":
         lambda d: d.update(command=["python3", "../x.py"]),
     "end-to-end from a program counter":
-        lambda d: d["end_to_end"][0].update(source="program_counter"),
+        lambda d: _the(d, "end_to_end", "tok_s").update(
+            source="program_counter"),
     "two cells on four chips":
         lambda d: [w.update(chips=4) for w in d["workloads"]],
     "config no cell uses": lambda d: d["configs"].append(
-        dict(d["configs"][0], name="idle",
+        dict(_the(d, "configs", CONFIG), name="idle",
              file="benchmarks/configs/mnv2_224.json")),
-    "roofline not in percent": lambda d: d["per_layer"][4].update(
-        unit="share"),
-    "layer with a space":
-        lambda d: d["per_layer"][0].update(layer="Jit exec"),
-    "layer too long": lambda d: d["per_layer"][0].update(layer="l" * 65),
+    "roofline not in percent": lambda d: _the(
+        d, "per_layer", "decode_step_roofline").update(unit="share"),
+    "layer with a space": lambda d: _the(
+        d, "per_layer", "compiles_in_window").update(layer="Jit exec"),
+    "layer too long": lambda d: _the(
+        d, "per_layer", "compiles_in_window").update(layer="l" * 65),
+}
+
+#: a key that counts layers is a depth, whatever width its name also
+#: mentions: a cut of it names the source's own key
+DEPTH_CUTS = {
+    "the depth by its source key": ["num_hidden_layers"],
+    "the depth beside the vocabulary":
+        ["num_hidden_layers", "vocab_size"],
+    "a count of prediction layers": ["num_nextn_predict_layers"],
 }
 
 
@@ -148,6 +179,24 @@ BREACHES = {
 def test_validator_refuses(breach):
     with pytest.raises(mf.ManifestError):
         mf.validate(_bad(BREACHES[breach]))
+
+
+@pytest.mark.parametrize("cut", sorted(DEPTH_CUTS))
+def test_validator_takes_a_depth_cut_by_its_source_key(cut):
+    mf.validate(_bad(lambda d: _the(d, "configs", CONFIG).update(
+        reduced=DEPTH_CUTS[cut])))
+
+
+def test_every_per_layer_metric_names_its_cells():
+    """A metric with no ``workloads`` list is owed by every cell that
+    reports what it moves, cells appended later too: the benchmark's check
+    refuses a new cell while such a metric stands."""
+    doc = load("BENCHMARK.json").doc
+    cells = {w["name"] for w in doc["workloads"]}
+    for entry in doc["per_layer"]:
+        where = entry.get("workloads")
+        assert isinstance(where, list) and where, entry["name"]
+        assert set(where) <= cells, entry["name"]
 
 
 def test_unknown_cell_and_missing_file_are_named():

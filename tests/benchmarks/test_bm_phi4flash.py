@@ -125,7 +125,8 @@ def test_cell_reports_the_family_blind_metrics_and_its_own(manifest):
                  "ssm_ms_per_step"):
         entry = next(m for m in manifest.doc["per_layer"]
                      if m["name"] == name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "tok_s"
+        # family-blind: another family's cell may report it too
+        assert CELL in entry["workloads"] and entry["moves"] == "tok_s"
 
 
 # -- the cost functions --------------------------------------------------

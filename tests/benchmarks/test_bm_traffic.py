@@ -84,6 +84,61 @@ def test_stratified_lengths_are_one_multiset_for_every_seed():
     assert np.mean(a) > np.median(a)                 # the heavy tail
 
 
+def test_stratified_uniform_lengths_are_one_even_grid_for_every_seed():
+    spec = {"dist": "uniform_int", "min": 1024, "max": 8192,
+            "stratified": True}
+    a = traffic.draw_lengths(spec, 32, traffic.rng_for(1, 1))
+    b = traffic.draw_lengths(spec, 32, traffic.rng_for(2 ** 31 + 7, 1))
+    assert sorted(a) == sorted(b) and a != b
+    # the midpoints of 32 shares of 7 169 lengths: 224 apart, centred
+    grid = sorted(a)
+    assert grid[0] == 1024 + 112 and grid[-1] == 8192 - 112
+    assert {y - x for x, y in zip(grid, grid[1:])} <= {224, 225}
+    assert sum(a) == 32 * (1024 + 8192) // 2
+    # one length at a time cannot be stratified: the midpoint
+    assert traffic.draw_lengths(spec, 1, traffic.rng_for(3, 1)) == [4608]
+
+
+@pytest.mark.parametrize("name", ["reason_saturate", "latent_saturate"])
+def test_closed_loop_stratified_mix_offers_one_multiset_a_round(name):
+    """Every seed and every round of requests, one a client, brings the
+    same prompt lengths, in another order; the seed changes no work."""
+    mix = Manifest(os.path.join(ROOT, "BENCHMARK.json")).traffic(name)
+    assert mix["prompt_len"]["stratified"] is True
+    clients = int(mix["clients"])
+
+    def round_of(seed, k):
+        reqs = [traffic.closed_token_request(mix, seed, c, k)
+                for c in range(clients)]
+        assert [r["id"] for r in reqs] == [c + k * clients
+                                           for c in range(clients)]
+        return [r["prompt_len"] for r in reqs]
+
+    first = round_of(3800100011, 0)
+    lo, hi = mix["prompt_len"]["min"], mix["prompt_len"]["max"]
+    assert sum(first) == clients * (lo + hi) // 2
+    assert lo < min(first) and max(first) < hi
+    for seed, k in ((3800100011, 1), (2 ** 31 + 5, 0), (17, 3)):
+        again = round_of(seed, k)
+        assert sorted(again) == sorted(first) and again != first
+    # a request rebuilt on the check's side is the one the client sent
+    assert traffic.closed_token_request(mix, 17, 5, 3) == \
+        traffic.closed_token_request(mix, 17, 5, 3)
+
+
+def test_closed_loop_drawn_lengths_are_as_before():
+    """A mix that does not stratify draws each request's lengths from
+    its own id's stream, as it always did."""
+    mix = Manifest(os.path.join(ROOT, "BENCHMARK.json")).traffic(
+        "decode_saturate")
+    assert not mix["prompt_len"].get("stratified")
+    req = traffic.closed_token_request(mix, 4, client=3, k=2)
+    rid = 3 + 2 * mix["clients"]
+    own = traffic.rng_for(4, traffic._PROMPT_LEN, rid)
+    assert req["prompt_len"] == traffic.draw_lengths(
+        mix["prompt_len"], 1, own)[0]
+
+
 @pytest.mark.parametrize("spec, lo, hi", [
     ({"dist": "fixed", "value": 256}, 256, 256),
     ({"dist": "uniform_int", "min": 16, "max": 48}, 16, 48),
